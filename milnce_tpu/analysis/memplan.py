@@ -20,13 +20,13 @@ The model (known approximations are documented in ANALYSIS.md):
 - **peak**: for each equation, bytes live while it executes = live set
   + the equation's own transient (outputs being materialized for plain
   primitives; the recursive peak of the body for scan/cond/while; the
-  body peak minus the already-counted operands for pjit / shard_map /
+  body peak minus the already-counted operands for jit / shard_map /
   custom_vjp nests, so a buffer crossing a nest boundary is counted
   once).
 - **sharding-aware**: a value sharded over mesh axes contributes
   ``bytes / prod(axis sizes)`` per chip.  Inside ``shard_map`` bodies
   shapes are already per-shard; at the jit level the divisors are read
-  off the shard_map equation's ``in_names``/``out_names`` — i.e. from
+  off the shard_map equation's ``in_specs``/``out_specs`` — i.e. from
   the entry's committed PartitionSpecs, the same specs the sharding-map
   hash in bench records is built from.
 - **donation-aware**: donated argument leaves free at last use, and a
@@ -43,9 +43,8 @@ Three rules ride on the planner (rule catalogue: analysis/rules.py):
 - **GL014 ineffective-or-missing-donation**: a large aliasable arg not
   donated on a grad-bearing entry, or a donated leaf whose buffer
   cannot be reused; findings name the buffer and its bytes.  The audit
-  honors the CPU donation gate (parallel/compat.py) and verifies the
-  TPU path still REQUESTS donation via
-  :func:`~milnce_tpu.parallel.compat.donation_argnums_for_backend`.
+  also verifies that each grad-bearing factory's production build
+  (``donate=True``) really REQUESTS the donation from ``jax.jit``.
 - **GL015 top-contributor-drift**: the top-3 peak contributors per
   entry are pinned BY NAME (``EXPECTED_TOP_CONTRIBUTORS``) so a
   silently rematerialized activation shows up as a named diff, not a
@@ -108,22 +107,24 @@ def aval_bytes(aval) -> int:
 
 
 def _is_literal(v) -> bool:
-    import jax
+    from jax.extend.core import Literal
 
-    return isinstance(v, jax.core.Literal)
+    return isinstance(v, Literal)
 
 
 def _is_dropvar(v) -> bool:
     return type(v).__name__ == "DropVar"
 
 
-def _names_divisor(names_entry, axis_sizes: dict) -> int:
-    """shard_map ``in_names``/``out_names`` entry ({dim: axes}) -> the
-    per-chip divisor prod(axis sizes).  Trailing-None-normalized specs
-    (sharding_map._dim_spec) and un-normalized ones land on the same
-    divisor here — the names map only carries sharded dims."""
+def _spec_divisor(spec, axis_sizes: dict) -> int:
+    """shard_map ``in_specs``/``out_specs`` entry (a PartitionSpec) ->
+    the per-chip divisor prod(axis sizes).  Trailing-None-normalized
+    specs (sharding_map._dim_spec) and un-normalized ones land on the
+    same divisor here — only sharded dims name an axis."""
     d = 1
-    for axes in (names_entry or {}).values():
+    for axes in spec:
+        if axes is None:
+            continue
         axes = axes if isinstance(axes, (tuple, list)) else (axes,)
         for a in axes:
             d *= int(axis_sizes.get(a, 1))
@@ -133,12 +134,12 @@ def _names_divisor(names_entry, axis_sizes: dict) -> int:
 def _nested(eqn):
     """(kind, [sub-jaxprs]) for equations that carry a body.
 
-    ``call`` bodies run once with the operands (pjit / custom_vjp /
+    ``call`` bodies run once with the operands (jit / custom_vjp /
     remat): their peak overlaps the operands already live outside.
     ``loop`` bodies run repeatedly over fresh slices (scan / while);
     ``branch`` picks one of several (cond)."""
     p, prm = eqn.primitive.name, eqn.params
-    if p == "pjit":
+    if p == "jit":
         return "call", [prm["jaxpr"]]
     if p in ("closed_call", "core_call", "remat", "remat2", "checkpoint"):
         j = prm.get("jaxpr") or prm.get("call_jaxpr")
@@ -167,7 +168,7 @@ def _div_prepass(jaxpr, invar_div):
     the initial live set (args + consts) must already be counted at
     per-chip size or an 8-way-sharded batch would inflate the entry
     peak 8x at step zero.  Divisors come from shard_map
-    ``in_names``/``out_names`` (the committed specs) and propagate
+    ``in_specs``/``out_specs`` (the committed specs) and propagate
     through ``call``-kind bodies in BOTH directions: a jit-level state
     arg that only a nested shard_map shards (jit(shard_map(step)) — the
     entry shape) still counts per-chip at the jit level.  Returns
@@ -180,11 +181,11 @@ def _div_prepass(jaxpr, invar_div):
         kind, bodies = _nested(eqn)
         if kind == "shard_map":
             sizes = dict(getattr(eqn.params["mesh"], "shape", {}) or {})
-            for v, names in zip(eqn.invars, eqn.params["in_names"]):
+            for v, spec in zip(eqn.invars, eqn.params["in_specs"]):
                 if not _is_literal(v):
-                    div[v] = max(div.get(v, 1), _names_divisor(names, sizes))
-            for v, names in zip(eqn.outvars, eqn.params["out_names"]):
-                div[v] = _names_divisor(names, sizes)
+                    div[v] = max(div.get(v, 1), _spec_divisor(spec, sizes))
+            for v, spec in zip(eqn.outvars, eqn.params["out_specs"]):
+                div[v] = _spec_divisor(spec, sizes)
         elif kind == "call" and bodies:
             sub = [1 if _is_literal(v) else div.get(v, 1)
                    for v in eqn.invars]
@@ -395,8 +396,9 @@ def donated_leaf_flags(args, donate_argnums) -> list:
 def plan_fn(fn, args, *, argnames, donate_argnums=(), entry="",
             mesh="") -> "MemPlan":
     """Trace ``fn(*args)`` and run the live-range walk with the entry's
-    donation intent applied (the TPU path's donation, even when the
-    entry itself was built donate=False for the CPU gate)."""
+    donation intent applied (the production build's donation, even
+    though the entry itself is built donate=False so the audit can
+    re-trace it)."""
     import jax
 
     closed = jax.make_jaxpr(fn)(*args)
@@ -577,7 +579,6 @@ def milnce_loss_plan_program(impl: str, b_global: int, k: int, d: int,
     from milnce_tpu.analysis.trace_invariants import _setup
     from milnce_tpu.losses.milnce import milnce_loss
     from milnce_tpu.losses.milnce_chunked import milnce_loss_chunked
-    from milnce_tpu.parallel.compat import shard_map
 
     _model, _opt, mesh, _state, _batch = _setup()
 
@@ -590,7 +591,7 @@ def milnce_loss_plan_program(impl: str, b_global: int, k: int, d: int,
     def value_and_grads(v, t):
         return jax.value_and_grad(local, argnums=(0, 1))(v, t)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         value_and_grads, mesh=mesh,
         in_specs=(P("data"), P("data")),
         out_specs=(P(), (P("data"), P("data"))), check_vma=False))
@@ -847,7 +848,7 @@ EXPECTED_PEAK_BYTES = {
     # strict inequality; PERF.md "Memory-efficient loss" has the
     # Bg=8192 what-if numbers).
     "train_step_milnce_chunked": 10612424,
-    "milnce_loss_dense": 2863940,
+    "milnce_loss_dense": 2374784,
     "milnce_loss_chunked": 703276,
     # elastic 4-way layout (ISSUE 20): pinned IDENTICAL to the 8-way
     # step — per-chip peak is a function of clips PER CHIP (2 at both
@@ -925,9 +926,9 @@ EXPECTED_TOP_CONTRIBUTORS = {
     # are one (B_local, chunk*K) = [64, 320] streamed block — the
     # tentpole's scaling claim, pinned by name
     "milnce_loss_dense": (
+        "convert_element_type float32[64,2560]",
         "exp float32[64,2560]",
-        "broadcast_in_dim float32[64,2560]",
-        "scatter-add float32[64,512,5]"),
+        "reshape float32[64,2560]"),
     "milnce_loss_chunked": (
         "exp float32[64,320]",
         "reshape float32[8,320,16]",
@@ -1070,7 +1071,7 @@ def _check_gl015(name: str, plan: MemPlan) -> CheckResult:
 
 def traced_donated_invar_count(fn, args) -> int:
     """Flattened invars the traced program actually marks donated —
-    read off the top-level pjit equation's ``donated_invars``, i.e.
+    read off the top-level jit equation's ``donated_invars``, i.e.
     what the factory REALLY passed to ``jax.jit``, not what a registry
     claims it passes."""
     import jax
@@ -1078,49 +1079,36 @@ def traced_donated_invar_count(fn, args) -> int:
     closed = jax.make_jaxpr(fn)(*args)
     total = 0
     for eqn in _open(closed).eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             total += sum(bool(d) for d in
                          eqn.params.get("donated_invars", ()))
     return total
 
 
 @functools.lru_cache(maxsize=None)
-def _tpu_donation_wired(name: str):
+def _donation_wired(name: str):
     """(donated_invars_traced, donated_leaves_expected) for a
-    grad-bearing entry's PRODUCTION build (donate=True) under a
-    forced-TPU donation gate.
+    grad-bearing entry's PRODUCTION build (donate=True).
 
     This is the half of GL014 the registry cannot vouch for: the
     entry's factory must actually WIRE the donation intent into
-    ``jax.jit`` on accelerator backends.  We swap the factory's
-    ``donation_argnums`` binding for the pure TPU-keyed rule
-    (parallel/compat.donation_argnums_for_backend), build with
-    ``donate=True``, and count ``donated_invars`` in the traced pjit —
-    a factory that dropped its ``donate_argnums=`` plumbing traces
-    zero donated invars here and fails the check, while the plain
-    registry round-trip would have stayed green."""
-    from milnce_tpu.parallel.compat import donation_argnums_for_backend
-    from milnce_tpu.train import step as step_mod
-
+    ``jax.jit``.  We build with ``donate=True`` and count
+    ``donated_invars`` in the traced jit — a factory that dropped its
+    ``donate_argnums=`` plumbing traces zero donated invars here and
+    fails the check, while the plain registry round-trip would have
+    stayed green."""
     spec = _entries()[name]
-    real = step_mod.donation_argnums
-    step_mod.donation_argnums = functools.partial(
-        donation_argnums_for_backend, "tpu")
-    try:
-        fn, args = spec.build(donate=True)
-        traced = traced_donated_invar_count(fn, args)
-    finally:
-        step_mod.donation_argnums = real
+    fn, args = spec.build(donate=True)
+    traced = traced_donated_invar_count(fn, args)
     expected = sum(donated_leaf_flags(args, spec.donate_argnums))
     return traced, expected
 
 
 def _check_gl014(name: str, spec: MemEntry) -> list:
     """The donation audit: jaxpr-level effectiveness findings plus the
-    backend-gate half — the CPU build legitimately drops donation
-    (parallel/compat.py), but every grad-bearing entry's factory must
-    still wire the request into ``jax.jit`` on the TPU path (verified
-    against the TRACED program, not the registry's claim)."""
+    wiring half — every grad-bearing entry's factory must pass the
+    request to ``jax.jit`` (verified against the TRACED program, not
+    the registry's claim)."""
     out = []
     closed, labels, donated = _traced_entry(name)
     found = _donation_findings_jaxpr(closed, labels, donated,
@@ -1129,16 +1117,15 @@ def _check_gl014(name: str, spec: MemEntry) -> list:
         name, "GL014-donation", not found,
         "; ".join(found[:4]) if found else ""))
     if spec.grad_bearing:
-        traced, expected = _tpu_donation_wired(name)
+        traced, expected = _donation_wired(name)
         ok = expected > 0 and traced == expected
         out.append(CheckResult(
-            name, "GL014-tpu-donation-requested", bool(ok),
+            name, "GL014-donation-requested", bool(ok),
             "" if ok else
-            f"production build (donate=True) under the TPU donation "
-            f"gate traces {traced} donated invars, expected {expected} "
-            f"(the {spec.donate_argnums} state tree) — the factory "
-            "dropped its donate_argnums plumbing, or the CPU gate "
-            "leaked into the TPU program"))
+            f"production build (donate=True) traces {traced} donated "
+            f"invars, expected {expected} (the {spec.donate_argnums} "
+            "state tree) — the factory dropped its donate_argnums "
+            "plumbing"))
     return out
 
 

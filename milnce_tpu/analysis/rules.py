@@ -290,10 +290,9 @@ _RULE_LIST = (
                   "unchanged) is dead weight that reads like a "
                   "protection; an undonated large aliasable arg is the "
                   "regression GL003 cannot see once jit sites hide "
-                  "behind factories.  The audit honors the CPU gate "
-                  "(parallel/compat.donation_argnums buys nothing on "
-                  "CPU and double-frees on old jax) while verifying the "
-                  "TPU path still REQUESTS donation.",
+                  "behind factories.  The audit also verifies that "
+                  "each factory's production build REQUESTS the "
+                  "donation from jax.jit.",
         example="jax.jit(step, donate_argnums=(1,))  # arg 1 is returned "
                 "unchanged",
         fix="donate the consumed state (train/step.py "

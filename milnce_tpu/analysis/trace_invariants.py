@@ -39,6 +39,9 @@ from dataclasses import dataclass
 # reduction's signature primitive (train/step.py _reduce_grads_2d).
 COLLECTIVES = ("psum", "all_gather", "psum_scatter", "reduce_scatter",
                "all_to_all", "ppermute", "pbroadcast")
+# under check_vma=True a psum traces as ``psum_invariant`` — the same
+# collective, counted under the one name
+_PRIMITIVE_ALIASES = {"psum_invariant": "psum"}
 
 # Pinned per-entry collective multisets for the 8-way data mesh (absent
 # primitive = expected 0).  Derived by tracing on the tiny entry config;
@@ -46,8 +49,9 @@ COLLECTIVES = ("psum", "all_gather", "psum_scatter", "reduce_scatter",
 # communication-structure change re-pins the number in the same commit.
 #
 # Reading the milnce step: 2 all_gathers (video+text negatives ride ICI
-# once each); the 26 psums are the scalar loss reduction, the leaf-wise
-# grad psum, and the pmean-lowered BatchNorm stat merges; the 2
+# once each); the 78 psums are the scalar loss reduction, the grad
+# psum (jax binds one psum per LEAF of a reduced tree: 53 param leaves),
+# and the 24 pmean-lowered BatchNorm stat merges; the 2
 # reduce_scatters are the AD transposes of the loss's embedding gathers
 # (every grad-bearing step has them — they were always in the program,
 # uncounted until ISSUE 6 added reduce_scatter to COLLECTIVES for the
@@ -55,20 +59,20 @@ COLLECTIVES = ("psum", "all_gather", "psum_scatter", "reduce_scatter",
 # sdtw_3 trades one psum for a third all_gather (clip start-times feed
 # the alignment; the start gather carries no gradient).
 EXPECTED_COLLECTIVES = {
-    "train_step_milnce": {"all_gather": 2, "psum": 26,
+    "train_step_milnce": {"all_gather": 2, "psum": 78,
                           "reduce_scatter": 2},
     # the finite-update guard (ISSUE 3) must add NO collectives and no
     # host sync: its all-finite check runs on the already-psum'd
     # (replicated) grads and the skip is a jnp.where select — the pin
     # being IDENTICAL to the unguarded step is the invariant
-    "train_step_milnce_guarded": {"all_gather": 2, "psum": 26,
+    "train_step_milnce_guarded": {"all_gather": 2, "psum": 78,
                                   "reduce_scatter": 2},
     # the obs span instrumentation (ISSUE 5) wraps the step DISPATCH in
     # a host-side recorder (train/loop.py `rec.span("step")`); it must
     # add NO collectives, no transfers, no sync — the pin being
     # IDENTICAL to the uninstrumented step is the tentpole invariant,
     # and the entry also EXECUTES it under transfer_guard("disallow")
-    "train_step_milnce_instrumented": {"all_gather": 2, "psum": 26,
+    "train_step_milnce_instrumented": {"all_gather": 2, "psum": 78,
                                        "reduce_scatter": 2},
     # curriculum step (ISSUE 16): ONE step_fn serves every stage; each
     # stage's (frames, resolution, batch) shape is its own jit entry,
@@ -77,7 +81,7 @@ EXPECTED_COLLECTIVES = {
     # single-stage step (shapes scale tensors, never communication
     # structure), and within a stage the cache never grows (zero
     # recompiles; entering stage 2 adds exactly one entry)
-    "train_step_curriculum": {"all_gather": 2, "psum": 26,
+    "train_step_curriculum": {"all_gather": 2, "psum": 78,
                               "reduce_scatter": 2},
     # chunked MIL-NCE (ISSUE 12): the streaming loss must keep the DENSE
     # step's exact communication structure — the same 2 negative
@@ -87,7 +91,7 @@ EXPECTED_COLLECTIVES = {
     # scan-reduction-free check on these entries).  The pins being
     # IDENTICAL to train_step_milnce / train_step_milnce_2d is the
     # invariant, exactly like the guarded/instrumented twins above.
-    "train_step_milnce_chunked": {"all_gather": 2, "psum": 26,
+    "train_step_milnce_chunked": {"all_gather": 2, "psum": 78,
                                   "reduce_scatter": 2},
     "train_step_milnce_chunked_2d": {"all_gather": 22, "psum": 78,
                                      "reduce_scatter": 22},
@@ -98,11 +102,11 @@ EXPECTED_COLLECTIVES = {
     # size (4-way vs 8-way only changes shard extents) — and pinning it
     # per layout is what makes a topology change's communication plan a
     # deliberate re-pin instead of an accident.
-    "train_step_milnce@4way": {"all_gather": 2, "psum": 26,
+    "train_step_milnce@4way": {"all_gather": 2, "psum": 78,
                                "reduce_scatter": 2},
-    "train_step_sdtw3": {"all_gather": 3, "psum": 25,
+    "train_step_sdtw3": {"all_gather": 3, "psum": 77,
                          "reduce_scatter": 2},
-    "grad_cache_step_milnce": {"all_gather": 2, "psum": 26,
+    "grad_cache_step_milnce": {"all_gather": 2, "psum": 78,
                                "reduce_scatter": 2},
     # 2-D (data, model) FSDP step on the 4x2 grid (ISSUE 6): 22
     # all_gathers = 20 sharded-param materializations before the forward
@@ -178,24 +182,25 @@ class CheckResult:
 def iter_eqns(jaxpr):
     """Every eqn in a (possibly nested) jaxpr, including the inner jaxprs
     of pjit / shard_map / scan / custom_vjp / pallas_call params."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for eqn in jaxpr.eqns:
         yield eqn
         for p in eqn.params.values():
             vals = p if isinstance(p, (list, tuple)) else [p]
             for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, ClosedJaxpr):
                     yield from iter_eqns(v.jaxpr)
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, Jaxpr):
                     yield from iter_eqns(v)
 
 
 def collective_counts(jaxpr) -> dict:
     out: dict[str, int] = {}
     for eqn in iter_eqns(jaxpr):
-        if eqn.primitive.name in COLLECTIVES:
-            out[eqn.primitive.name] = out.get(eqn.primitive.name, 0) + 1
+        name = _PRIMITIVE_ALIASES.get(eqn.primitive.name, eqn.primitive.name)
+        if name in COLLECTIVES:
+            out[name] = out.get(name, 0) + 1
     return out
 
 
@@ -207,14 +212,14 @@ def scan_collective_counts(jaxpr) -> dict:
     bytes (the structure behind the ga=8 throughput hole BENCH_NOTES.md
     records).  Sibling scans accumulate; nested scans would double-count
     through their parent (none exist in the pinned programs)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
     out: dict[str, int] = {}
     for eqn in iter_eqns(jaxpr):
         if eqn.primitive.name != "scan":
             continue
         body = eqn.params.get("jaxpr")
-        inner = body.jaxpr if isinstance(body, jax.core.ClosedJaxpr) else body
+        inner = body.jaxpr if isinstance(body, ClosedJaxpr) else body
         for name, n in collective_counts(inner).items():
             out[name] = out.get(name, 0) + n
     return out
@@ -717,7 +722,7 @@ def _entry_sdtw_pallas_dispatch() -> list[CheckResult]:
     entry per dispatch shape, the second same-shape call a cache hit
     (no recompiles), with the probed shapes covering BOTH sides of
     ``prefers_pallas`` so the gate exercises kernel and scan paths alike
-    (the same gate discipline as the conv impls; BENCH_SOFTDTW.md)."""
+    (the same gate discipline as the conv impls)."""
     import jax
     import numpy as np
 

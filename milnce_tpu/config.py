@@ -170,7 +170,9 @@ class LossConfig:
                                         # TPU wavefront kernel wherever a
                                         # measured-winning layout applies
                                         # (batch-on-lanes or one-block), scan
-                                        # otherwise (BENCH_SOFTDTW.md;
+                                        # otherwise (measured on a v5e
+                                        # before PR 1, to be measured
+                                        # again by the benchmark;
                                         # reference always ran CUDA,
                                         # loss.py:26-97)
     sdtw_gamma: Optional[float] = None  # None = each loss's reference
@@ -243,10 +245,9 @@ class ParallelConfig:
     process_id: Optional[int] = None
     platform: str = ""                  # force a jax backend ('cpu' for
                                         # hermetic runs on accelerator
-                                        # hosts; '' = jax default).  Env
-                                        # vars alone don't suffice —
-                                        # accelerator plugins override
-                                        # JAX_PLATFORMS, so this applies
+                                        # hosts, 'tpu' so jax refuses to
+                                        # start without a chip; '' = jax
+                                        # default).  Applied through
                                         # jax.config before backend init.
     num_devices: int = 0                # build the mesh over the FIRST N
                                         # local devices only (0 = all) —

@@ -53,11 +53,42 @@ logsumexp accumulators and recomputed chunk-by-chunk in the backward
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def psum_local_grad(x: jax.Array, axis_name) -> jax.Array:
+    """``lax.psum`` whose reverse-mode gradient is the LOCAL term.
+
+    The loss is differentiated INSIDE the ``shard_map`` body and the
+    train step then psums the parameter grads, so the reduction's
+    cotangent must pass through unscaled.  A plain ``lax.psum`` does
+    that only when the body is traced with ``check_vma=True``; under
+    ``check_vma=False`` (what both train steps pass) it transposes to
+    another psum and every gradient comes out axis-size times too
+    large.  The explicit VJP is the same under both settings
+    (tests/test_milnce.py pins each on the 8-device mesh)."""
+    return lax.psum(x, axis_name)
+
+
+def _psum_local_grad_fwd(x, axis_name):
+    return lax.psum(x, axis_name), None
+
+
+def _psum_local_grad_bwd(axis_name, _, g):
+    # under check_vma=True the cotangent of the replicated sum is typed
+    # unvarying; the local term it feeds is varying over the axis
+    names = axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
+    missing = tuple(n for n in names if n not in jax.typeof(g).vma)
+    return (lax.pcast(g, missing, to="varying") if missing else g,)
+
+
+psum_local_grad.defvjp(_psum_local_grad_fwd, _psum_local_grad_bwd)
 
 
 def milnce_loss(video_embd: jax.Array, text_embd: jax.Array,
@@ -102,15 +133,9 @@ def milnce_loss(video_embd: jax.Array, text_embd: jax.Array,
 
     local_sum = jnp.sum(denominator - numerator)
     if axis_name is not None:
-        # Value: the mesh-global sum.  Gradient: identity to the LOCAL
-        # term only — jax versions disagree on the psum transpose when
-        # grad is taken inside the shard_map body (old jax overcounts
-        # the replicated cotangent by the axis size), so the reduction
-        # goes through the version-aware compat helper.  Both versions
-        # then agree with the unsharded reference once the train step
+        # Value: the mesh-global sum.  Gradient: the LOCAL term only,
+        # which agrees with the unsharded reference once the train step
         # psums the param grads
         # (tests/test_milnce.py::test_sharded_gradients_match_unsharded).
-        from milnce_tpu.parallel.compat import psum_with_identity_grad
-
-        local_sum = psum_with_identity_grad(local_sum, axis_name)
+        local_sum = psum_local_grad(local_sum, axis_name)
     return local_sum / b_global

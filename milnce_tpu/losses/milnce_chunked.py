@@ -23,7 +23,7 @@ memory-efficient-contrastive / FlashAttention move applied to MIL-NCE:
 - semantics are IDENTICAL to ``milnce_loss``: positive-bag logsumexp
   numerator, row+column denominator with double-counted positives, the
   same 2 ``all_gather`` collectives (whose AD transposes stay the same 2
-  reduce_scatters), and the same ``psum_with_identity_grad`` reduction.
+  reduce_scatters), and the same ``psum_local_grad`` reduction.
 
 Backend gate (the soft-DTW playbook, ops/softdtw.py ``SoftDTW``):
 ``backend='scan'`` is this module's pure-jax stream; ``'pallas'`` is the
@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from milnce_tpu.losses.milnce import milnce_loss
+from milnce_tpu.losses.milnce import milnce_loss, psum_local_grad
 from milnce_tpu.ops.softdtw import BIG
 
 MILNCE_IMPLS = ("dense", "chunked", "auto")
@@ -89,14 +89,12 @@ def prefers_chunked(b_local: int, b_global: int, k: int) -> bool:
 def _axis_prod(axis_name) -> int:
     """Static mesh extent of ``axis_name`` (None = 1, tuple = product) —
     legal inside the shard_map body, where mesh axis sizes are static."""
-    from milnce_tpu.parallel.compat import axis_size
-
     if axis_name is None:
         return 1
     names = axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
     n = 1
     for name in names:
-        n *= int(axis_size(name))
+        n *= int(lax.axis_size(name))
     return n
 
 
@@ -292,9 +290,7 @@ def milnce_loss_chunked(video_embd: jax.Array, text_embd: jax.Array,
     denominator = jnp.logaddexp(row_lse, col_lse)
     local_sum = jnp.sum(denominator - numerator)
     if axis_name is not None:
-        from milnce_tpu.parallel.compat import psum_with_identity_grad
-
-        local_sum = psum_with_identity_grad(local_sum, axis_name)
+        local_sum = psum_local_grad(local_sum, axis_name)
     return local_sum / b_global
 
 

@@ -1,13 +1,17 @@
 """Build + load the native C++ runtime library (ctypes, no pybind11).
 
 Compiles ``native/milnce_native.cpp`` on first use into
-``build/libmilnce_native.so`` (cached by source mtime).  Everything that
-uses it degrades gracefully when no C++ toolchain is present.
+``build/libmilnce_native-<hash of the source>.so``: the name IS the
+staleness check, so a library built from another source — ``build/`` is
+git-ignored and travels with a copied tree — is never loaded.
+Everything that uses it degrades gracefully when no C++ toolchain is
+present.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import shutil
 import subprocess
@@ -17,22 +21,33 @@ from typing import Optional
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "milnce_native.cpp")
-_OUT = os.path.join(_REPO_ROOT, "build", "libmilnce_native.so")
+
+
+def _out_path() -> str:
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:12]
+    return os.path.join(_REPO_ROOT, "build",
+                        f"libmilnce_native-{digest}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _compile() -> bool:
+def _compile(out: str) -> bool:
     cxx = shutil.which("g++") or shutil.which("c++")
-    if cxx is None or not os.path.exists(_SRC):
+    if cxx is None:
         return False
-    os.makedirs(os.path.dirname(_OUT), exist_ok=True)
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    # build beside the target and rename: several processes (test
+    # workers) may build at once, and none may dlopen a half-written file
+    tmp = f"{out}.{os.getpid()}.tmp"
     cmd = [cxx, "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-           "-o", _OUT, _SRC]
+           "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, out)
         return True
     except subprocess.CalledProcessError as e:
         import sys
@@ -43,19 +58,21 @@ def _compile() -> bool:
 
 
 def load_native_library() -> Optional[ctypes.CDLL]:
-    """Compile-if-stale and dlopen the native library; None if unavailable."""
+    """Compile-if-absent and dlopen the native library built from THIS
+    source; None if unavailable."""
     global _lib, _load_failed
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        stale = (not os.path.exists(_OUT)
-                 or (os.path.exists(_SRC)
-                     and os.path.getmtime(_SRC) > os.path.getmtime(_OUT)))
-        if stale and not _compile():
+        if not os.path.exists(_SRC):
+            _load_failed = True
+            return None
+        out = _out_path()
+        if not os.path.exists(out) and not _compile(out):
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_OUT)
+            lib = ctypes.CDLL(out)
         except OSError:
             _load_failed = True
             return None

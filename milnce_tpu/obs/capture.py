@@ -188,17 +188,27 @@ class ProfilerCapture:
             timer.cancel()
         try:
             self._stop_fn()
+            # before the state leaves 'stopping': close() waits on that
+            self._event("capture.stop", cause=cause)
         finally:
             with self._lock:
                 self._state = "idle"
                 self._last_done = self._time()
-        self._event("capture.stop", cause=cause)
         return True
 
-    def close(self) -> None:
+    def close(self, timeout_s: float = 30.0) -> None:
         """Owner teardown: stop a still-active capture so a run that
-        ends mid-capture flushes its trace instead of corrupting it."""
+        ends mid-capture flushes its trace instead of corrupting it —
+        and wait out a stop already in flight on the duration timer's
+        thread (a trace flush takes seconds on a loaded host), so its
+        trace and its ``capture.stop`` event land before the owner
+        closes the recorder."""
         self.stop(cause="close")
+        for _ in range(int(timeout_s / 0.01)):
+            with self._lock:
+                if self._state != "stopping":
+                    return
+            time.sleep(0.01)
 
     # ---- reading ---------------------------------------------------------
 

@@ -24,8 +24,8 @@ online max/rescale + accumulate (VPU) — in VMEM:
   masked to ``-BIG`` logits / zero weights, the same finite-sentinel
   discipline as ops/softdtw.py.
 
-On non-TPU backends the kernel runs in Pallas interpret mode, so the
-same code path is unit-testable on CPU (tests/test_milnce_chunked.py
+On the CPU the kernel runs in Pallas interpret mode (ops/pallas_mode.py),
+so the same code path is unit-testable there (tests/test_milnce_chunked.py
 pins value+grad parity against the scan stream and the dense loss).
 ``prefers_pallas`` is the ``backend='auto'`` shape-dispatch rule — a
 pure function of static shapes, pinned no-recompile by the
@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from milnce_tpu.ops import pallas_mode
 from milnce_tpu.ops.softdtw import BIG
 
 _LANES = 128
@@ -53,10 +54,6 @@ _LANES = 128
 # Same budget scale the soft-DTW kernels verified against a real v5e
 # scoped-vmem OOM (ops/softdtw_pallas.py _VMEM_TABLE_BUDGET).
 _VMEM_F32_BUDGET = 1_200_000
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad8(n: int) -> int:
@@ -83,29 +80,36 @@ def prefers_pallas(b: int, b_global: int, k: int, d: int,
         # tile grid — legal in interpret mode only; route it to the scan
         # (single-chunk streams are exempt: the block equals the array)
         return False
+    return (d % _LANES == 0
+            and _resident_f32(b, k, d, chunk) <= _VMEM_F32_BUDGET)
+
+
+def _resident_f32(b: int, k: int, d: int, chunk: int) -> int:
+    """f32 elements the BACKWARD kernel keeps in VMEM per grid step —
+    the larger of the two resident sets (it holds recomputed logits AND
+    weight blocks, the gv/gt grad accumulators, and the per-chunk
+    gva/gta output blocks the forward doesn't have); a rule that only
+    modeled the forward would compile the forward and VMEM-OOM mid-step
+    in the backward on a real chip."""
     bp, bkp = _pad8(b), _pad8(b * k)
     ck = chunk * k
-    # budget the BACKWARD kernel — the larger of the two resident sets
-    # (it holds recomputed logits AND weight blocks, the gv/gt grad
-    # accumulators, and the per-chunk gva/gta output blocks the forward
-    # doesn't have); a rule that only modeled the forward would compile
-    # the forward and VMEM-OOM mid-step in the backward on a real chip
-    resident = (2 * (bp + bkp) * d          # v/t blocks + gv/gt accums
-                + 2 * (bp + bkp) * _LANES   # lse + cotangent blocks
-                + 4 * (chunk + ck) * d      # chunk in + grad out blocks,
-                                            # double-buffered
-                + 2 * (bp * ck + bkp * chunk))  # logits + weight temps
-    return d % _LANES == 0 and resident <= _VMEM_F32_BUDGET
+    return (2 * (bp + bkp) * d          # v/t blocks + gv/gt accums
+            + 2 * (bp + bkp) * _LANES   # lse + cotangent blocks
+            + 4 * (chunk + ck) * d      # chunk in + grad out blocks,
+                                        # double-buffered
+            + 2 * (bp * ck + bkp * chunk))  # logits + weight temps
 
 
-def _check_chunk_alignment(chunk: int, bg: int) -> None:
-    """Compiled-TPU precondition, checked at trace time so an explicit
-    ``backend='pallas'`` with a misaligned ``loss.milnce_chunk`` fails
-    naming the knob instead of as an opaque Mosaic lowering error deep
-    in the step compile.  Interpret mode (every non-TPU backend) has no
-    tile grid and legitimately accepts any chunk — the parity tests'
-    odd chunks stay runnable on CPU."""
-    if _interpret():
+def _check_compiled_preconditions(b: int, k: int, d: int, chunk: int,
+                                  bg: int) -> None:
+    """Compiled-kernel preconditions, checked at trace time so an
+    explicit ``loss.milnce_backend=pallas`` outside them fails naming
+    the knob and the shape instead of as an opaque Mosaic lowering error
+    or the compiler's ``RESOURCE_EXHAUSTED ... vmem`` from deep inside
+    the step compile (``backend='auto'`` never selects such a shape).
+    Interpret mode has no tile grid and no VMEM and legitimately accepts
+    anything — the parity tests' odd shapes stay runnable on the CPU."""
+    if pallas_mode.interpret():
         return
     if chunk % 8 and chunk != bg:
         raise ValueError(
@@ -114,6 +118,14 @@ def _check_chunk_alignment(chunk: int, bg: int) -> None:
             "sublanes; trailing dims Mosaic pads itself): use a "
             f"multiple of 8, a chunk >= the gathered batch ({bg}), or "
             "backend='scan'")
+    resident = _resident_f32(b, k, d, chunk)
+    if resident > _VMEM_F32_BUDGET:
+        raise ValueError(
+            f"loss.milnce_backend=pallas at b_local={b}, b_global={bg}, "
+            f"K={k}, D={d}, loss.milnce_chunk={chunk} keeps {resident} "
+            f"f32 elements in VMEM per grid step, over the kernel's "
+            f"budget of {_VMEM_F32_BUDGET}: lower loss.milnce_chunk, or "
+            "use loss.milnce_backend=auto (which takes the scan here)")
 
 
 def _row_scalar(ref):
@@ -177,7 +189,7 @@ def _run_forward(v, t, v_all, t_all, chunk, bg, k):
     b, d = v.shape
     bk = t.shape[0]
     bp, bkp = _pad8(b), _pad8(bk)
-    _check_chunk_alignment(chunk, bg)
+    _check_compiled_preconditions(b, k, d, chunk, bg)
     nc = -(-bg // chunk)
     f32 = jnp.float32
     vp = _pad_rows(v.astype(f32), bp)
@@ -199,7 +211,7 @@ def _run_forward(v, t, v_all, t_all, chunk, bg, k):
                    jax.ShapeDtypeStruct((bp, _LANES), f32),
                    jax.ShapeDtypeStruct((bkp, _LANES), f32),
                    jax.ShapeDtypeStruct((bkp, _LANES), f32)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(vp, tp, vap, tap)
     row_lse = rm[:b, 0] + jnp.log(rs[:b, 0])
     col_lse = cm[:bk, 0] + jnp.log(cs[:bk, 0])
@@ -259,7 +271,7 @@ def _run_backward(v, t, v_all, t_all, row_lse, col_lse, g_row, g_col,
     b, d = v.shape
     bk = t.shape[0]
     bp, bkp = _pad8(b), _pad8(bk)
-    _check_chunk_alignment(chunk, bg)
+    _check_compiled_preconditions(b, k, d, chunk, bg)
     nc = -(-bg // chunk)
     f32 = jnp.float32
     vp = _pad_rows(v.astype(f32), bp)
@@ -284,7 +296,7 @@ def _run_backward(v, t, v_all, t_all, row_lse, col_lse, g_row, g_col,
                    jax.ShapeDtypeStruct((bkp, d), f32),
                    jax.ShapeDtypeStruct((nc * chunk, d), v_all.dtype),
                    jax.ShapeDtypeStruct((nc * chunk * k, d), t_all.dtype)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(vp, tp, vap, tap,
       _bcast_rows(row_lse, bp), _bcast_rows(g_row, bp),
       _bcast_rows(col_lse, bkp), _bcast_rows(g_col, bkp))

@@ -174,8 +174,8 @@ class SoftDTW:
 
     ``backend='scan'`` uses this module's lax.scan DP; ``backend='pallas'``
     uses the TPU wavefront kernel (same math, kernel-resident diagonals);
-    ``backend='auto'`` picks per cost-matrix shape (v5e measurements,
-    BENCH_SOFTDTW.md): the kernel wherever the batch-on-lanes layout
+    ``backend='auto'`` picks per cost-matrix shape (measured on a v5e
+    before PR 1, to be measured again by the benchmark): the kernel wherever the batch-on-lanes layout
     applies (3.5-26x over the scan at large-batch/short-pair shapes) or
     the whole padded batch fits one sublane-batch VMEM block (~3x); the
     scan otherwise, where re-running the diagonal loop per batch tile

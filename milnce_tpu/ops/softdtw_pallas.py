@@ -34,11 +34,36 @@ wiring):
   finite analog of the reference's ``inf -> -inf`` fixup
   (soft_dtw_cuda.py:101-102).
 
-On non-TPU backends the kernel runs in Pallas interpret mode, so the
-same code path is unit-testable on CPU.  All three variants (in-VMEM,
-chunked, backward) lower through Mosaic and run compiled on real TPU
-(verified on v5e; see BENCH_SOFTDTW.md for timings and the lowering
-rules the layout was bought with).
+On the CPU the kernel runs in Pallas interpret mode (ops/pallas_mode.py),
+so the same code path is unit-testable there.  All three variants
+(in-VMEM, chunked, backward) lower through Mosaic; that they compile for
+a v5e at the main path's shapes is pinned by tests/test_tpu_compile.py.
+
+Mosaic lowering rules the layout was bought with (found on a v5e):
+
+- Block shapes must keep the *last two* dims (8, 128)-tileable or equal
+  to the array dims; scalar-ish outputs like a (bt, 1) value block are
+  unlowerably mis-tiled — read scalars out of the result table on the
+  host instead.
+- Dynamic indices belong on the *leading, untiled* ref dimension
+  (diagonal-leading layout); a dynamic sublane index makes every loop
+  step a whole-block read-modify-write.
+- Mosaic's vector lowering crashes (``Check failed: limits[i] <=
+  dim(i)``) when leading-dim x sublane block area gets large; bisected:
+  forward survives ~8192, backward dies above ~5360.  ``_batch_tile``
+  caps the product at 5120.
+- Scoped VMEM is ~16 MB and OOMs are *compile-time* errors (``Ran out
+  of memory in memory space vmem``); the 1.2M-element
+  ``_VMEM_TABLE_BUDGET`` keeps the worst block (3 tables,
+  double-buffered) near ~11 MB.
+- A Mosaic grid executes its blocks SEQUENTIALLY on the core, so a batch
+  split into G VMEM-sized tiles runs G*(N+M) wavefront steps end to end,
+  where one ``lax.scan`` runs (N+M) wide ones: a multi-block
+  sublane-batch grid has G times the scan's sequential depth.  The
+  kernel wins where that inversion doesn't happen — one block holding
+  the whole batch, or the lanes layout (batch on the 128-wide lane dim).
+  The chunked long-sequence kernels advance ONE shared wavefront across
+  the grid's chunk axis, so chunking adds HBM streaming, not depth.
 """
 
 from __future__ import annotations
@@ -52,11 +77,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from milnce_tpu.ops import pallas_mode
 from milnce_tpu.ops.softdtw import BIG, check_bandwidth, skew_cost
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------- forward
@@ -207,8 +229,9 @@ def _tile_for_batch(bsz: int, n: int, m: int) -> int:
 
 def fits_one_block(bsz: int, n: int, m: int) -> bool:
     """True when the whole padded batch runs as a SINGLE kernel block —
-    the regime where the wavefront kernel beats the scan (~3x on v5e;
-    BENCH_SOFTDTW.md).  Multi-block grids re-run the diagonal loop per
+    the regime where the wavefront kernel beats the scan (~3x, measured
+    on a v5e before PR 1, to be measured again by the benchmark).
+    Multi-block grids re-run the diagonal loop per
     tile and lose to one scan over the full batch."""
     bt = _batch_tile(n, m)
     return bt >= 8 and -(-bsz // 8) * 8 <= bt
@@ -229,7 +252,7 @@ def _run_forward(d_skew: jax.Array, n: int, m: int, gamma: float,
         in_specs=[pl.BlockSpec((n + m - 1, bt, n), lambda b: (0, b, 0))],
         out_specs=pl.BlockSpec((n + m + 1, bt, n + 1), lambda b: (0, b, 0)),
         out_shape=jax.ShapeDtypeStruct((n + m + 1, bp, n + 1), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(d3)
     r_skew = r3.transpose(1, 0, 2)[:bsz]
     return r_skew[:, n + m, n], r_skew
@@ -241,9 +264,9 @@ def _run_forward(d_skew: jax.Array, n: int, m: int, gamma: float,
 # alignment index lives on SUBLANES and batch fills the 128-wide LANE
 # dimension.  Per wavefront step this touches ceil((N+1)/8) vector tiles
 # instead of ceil(bt/8) — for batch >> N+1 that is up to n1/128 of the
-# sublane-batch layout's total tile traffic.  Measured compiled on v5e
-# (BENCH_SOFTDTW.md): 25.8x over the scan at (128, 17, 15) fwd+bwd and
-# 3.5x at (1024, 32, 32) — regimes where the sublane-batch layout LOSES
+# sublane-batch layout's total tile traffic.  Measured on a v5e before
+# PR 1, to be measured again by the benchmark: 25.8x over the scan at
+# (128, 17, 15) fwd+bwd and 3.5x at (1024, 32, 32) — regimes where the sublane-batch layout LOSES
 # to the scan — so it is the default wherever its shape conditions hold
 # (escape hatch: MILNCE_SDTW_LANES=0).
 
@@ -265,8 +288,9 @@ def _use_lanes(bsz: int, n: int, m: int) -> bool:
 
 
 def prefers_pallas(bsz: int, n: int, m: int) -> bool:
-    """Shape-dispatch rule for ``SoftDTW(backend='auto')``, from the v5e
-    measurements in BENCH_SOFTDTW.md: the kernel wins wherever the
+    """Shape-dispatch rule for ``SoftDTW(backend='auto')`` (measured on
+    a v5e before PR 1, to be measured again by the benchmark): the
+    kernel wins wherever the
     batch-on-lanes layout applies (3.5-26x, any batch size) or the whole
     padded batch runs as a single sublane-batch block (~3x).  Elsewhere —
     multi-block sublane grids re-running the diagonal loop per tile —
@@ -331,7 +355,7 @@ def _run_forward_lanes(d_skew: jax.Array, n: int, m: int, gamma: float,
         in_specs=[pl.BlockSpec((n + m - 1, n, bl), lambda b: (0, 0, b))],
         out_specs=pl.BlockSpec((n + m + 1, n + 1, bl), lambda b: (0, 0, b)),
         out_shape=jax.ShapeDtypeStruct((n + m + 1, n + 1, bp), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(d3)
     r_skew = r3.transpose(2, 0, 1)[:bsz]
     return r_skew[:, n + m, n], r_skew
@@ -395,7 +419,7 @@ def _run_backward_lanes(r_ext_skew: jax.Array, d_ext_skew: jax.Array,
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n + m + 3, n + 2, bp), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(r3, d3)
     return out.transpose(2, 0, 1)[:bsz]
 
@@ -427,7 +451,7 @@ def _run_forward_chunked(d_skew: jax.Array, n: int, m: int, gamma: float,
         out_shape=jax.ShapeDtypeStruct((n_chunks * chunk, bp, n + 1),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((2, bt, n + 1), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(d3)
     r_body = r3.transpose(1, 0, 2)[:bsz, :n_diag]
     # re-attach the constant diagonals 0 and 1
@@ -584,7 +608,7 @@ def _run_backward_chunked(r_ext_skew: jax.Array, d_ext_skew: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n_chunks * chunk, bp, n2),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((6, bt, n2), jnp.float32)],
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(r3, d3)
     return out.transpose(1, 0, 2)[:bsz, :n_rows]
 
@@ -663,7 +687,7 @@ def _run_backward(r_ext_skew: jax.Array, d_ext_skew: jax.Array, n: int,
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n + m + 3, bp, n + 2), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_mode.interpret(),
     )(r3, d3)
     return out.transpose(1, 0, 2)[:bsz]
 

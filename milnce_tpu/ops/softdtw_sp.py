@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from milnce_tpu.parallel.compat import axis_size, shard_map
 from milnce_tpu.ops.softdtw import (BIG, check_bandwidth, skew_cost,
                                     softmin3)
 
@@ -53,7 +52,7 @@ def _softdtw_sp_local(D_local: jax.Array, n: int, m: int, gamma,
 
     Returns the (B,) soft-DTW values, identical on every shard."""
     bsz, k, _ = D_local.shape
-    p_count = axis_size(axis_name)
+    p_count = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     row_offset = idx * k                       # global D-row of local row 0
     g_rows = row_offset + jnp.arange(k)        # global D-row ids (= i-1)
@@ -113,7 +112,7 @@ def _build_sp_fn(mesh: Mesh, axis_name: str, n: int, m: int,
         return _softdtw_sp_local(D_local, n=n, m=m, gamma=gamma,
                                  axis_name=axis_name, bandwidth=bandwidth)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis_name, None), P()),
         out_specs=P(), check_vma=False))

@@ -27,40 +27,26 @@ def _multihost_tpu_env() -> bool:
     TPU runtime's worker list means this process must join a
     jax.distributed cluster before touching devices.
 
-    The list comes from the env when the TPU env file was sourced, else
-    from the instance metadata — the same two sources JAX's own cluster
-    detection consults (clusters/cloud_tpu_cluster.py), so a process
-    launched from a bare shell on a pod VM is still detected."""
-    hosts = os.environ.get("TPU_WORKER_HOSTNAMES")
-    if hosts is None:
-        try:
-            # Private jax API (mirrors its GcpTpuCluster): guarded — if it
-            # moves, autodetect degrades to env-only, never crashes.  The
-            # running_in_cloud_tpu_vm gate (libtpu presence) keeps the
-            # metadata HTTP lookup — retried with long timeouts inside
-            # jax — off every non-TPU startup path.
-            from jax._src.cloud_tpu_init import running_in_cloud_tpu_vm
-            from jax._src.clusters.cloud_tpu_cluster import get_tpu_env_value
-
-            if running_in_cloud_tpu_vm:
-                hosts = get_tpu_env_value("WORKER_HOSTNAMES") or ""
-            else:
-                hosts = ""
-        except Exception:  # graftlint: disable=GL007(private-jax-API probe: if it moves, autodetect deliberately degrades to env-only — documented in the try block above)
-            hosts = ""
-    return "," in hosts
+    Decided from the environment ALONE (``TPU_WORKER_HOSTNAMES``, which
+    a pod VM's TPU env file exports).  Asking the instance metadata
+    instead is an HTTP lookup that jax retries six times with 60 s
+    limits: on a host with no metadata server a single-host run would
+    stall there before it ever touched a device, and it must reach its
+    first device in seconds.  A pod process started from a shell that
+    has not sourced the env file passes ``coordinator_address``."""
+    return "," in os.environ.get("TPU_WORKER_HOSTNAMES", "")
 
 
 def initialize_distributed(cfg: ParallelConfig) -> None:
     """Multi-host process bootstrap.
 
-    - ``platform`` set: pin the jax backend first (``jax.config`` wins
-      where a bare env var loses to accelerator plugins) — hermetic CPU
-      runs on accelerator hosts;
+    - ``platform`` set: pin the jax backend first — 'cpu' for hermetic
+      runs on accelerator hosts, 'tpu' so that JAX itself refuses to
+      start where there is no chip;
     - explicit ``coordinator_address``: classic bring-up (any platform);
-    - no address but a multi-host TPU slice detected: bare
+    - no address but a multi-host TPU slice in the environment: bare
       ``jax.distributed.initialize()`` — coordinator, process count and
-      id all come from the TPU metadata, zero flags (contrast the
+      id all come from the TPU runtime, zero flags (contrast the
       reference's hand-maintained 10-IP list, train.py:48);
     - single host: no-op, ``jax.devices()`` already sees every chip.
     """
@@ -93,6 +79,15 @@ def build_mesh(cfg: ParallelConfig,
         grid = devs.reshape(-1, cfg.model_parallel_size)
         return Mesh(grid, (cfg.data_axis, cfg.model_axis))
     return Mesh(devs, (cfg.data_axis,))
+
+
+def describe_devices(mesh: Mesh) -> str:
+    """Platform, device kind and count of the devices a mesh is built
+    over — the first log line of the trainer and of the server, so that
+    a run that landed on the CPU says so where a reader looks first."""
+    dev = mesh.devices.flat[0]
+    return (f"platform={dev.platform} device_kind={dev.device_kind!r} "
+            f"devices={mesh.devices.size}")
 
 
 def batch_sharding(mesh: Mesh, axis: str = "data") -> NamedSharding:

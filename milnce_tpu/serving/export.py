@@ -362,6 +362,9 @@ def main(argv=None) -> None:
             section, _, fname = key.partition(".")
             setattr(getattr(cfg, section), fname, val)
 
+    from milnce_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     step, tree = _restore_inference_subset(ns.checkpoint_dir, ns.epoch)
     video_shape = (cfg.data.num_frames, cfg.data.video_size,
                    cfg.data.video_size, 3)

@@ -39,7 +39,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from milnce_tpu.analysis.lockrt import make_lock
-from milnce_tpu.parallel.compat import shard_map
 from milnce_tpu.parallel.mesh import batch_sharding, replicated
 from milnce_tpu.serving.batcher import pad_rows
 from milnce_tpu.serving.engine import DEVICE_DISPATCH_LOCK
@@ -67,7 +66,7 @@ def make_topk_fn(mesh: Mesh, data_axis: str, k: int):
         s_top, j = lax.top_k(s_all, k)                   # exact global
         return s_top, jnp.take_along_axis(i_all, j, axis=1)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_topk, mesh=mesh,
         in_specs=(P(data_axis), P(data_axis), P()),
         out_specs=(P(), P()), check_vma=False))

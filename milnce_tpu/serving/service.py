@@ -846,34 +846,35 @@ def serve_http(service: RetrievalService, host: str = "127.0.0.1",
     return server
 
 
-def main(argv=None) -> None:
-    """``milnce-serve``: HTTP retrieval service over a frozen export.
-
-    Same CLI grammar as the trainer (``--preset`` + ``--serve.*`` /
-    ``--parallel.*`` overrides — config.py).  The corpus comes from
-    ``--serve.corpus_npz`` (a (N, D) float32 embedding matrix, e.g. an
-    offline eval extraction); without it the service starts embed-only
-    (query requests 400 until an index exists)."""
+def build_server(cfg):
+    """Everything ``milnce-serve`` builds before it listens: backend,
+    mesh, engine (ladder precompiled), index, service and the bound —
+    not yet serving — HTTP server.  Returns ``(server, service, index,
+    engine)``; the caller owns ``serve_forever`` and, afterwards,
+    :func:`close_server`."""
     import os
 
     import jax
 
-    from milnce_tpu.config import parse_cli
     from milnce_tpu.data.tokenizer import Tokenizer
     from milnce_tpu.obs import runctx as obs_runctx
     from milnce_tpu.obs.capture import ProfilerCapture
-    from milnce_tpu.parallel.mesh import build_mesh, initialize_distributed
+    from milnce_tpu.parallel.mesh import (build_mesh, describe_devices,
+                                          initialize_distributed)
     from milnce_tpu.serving.engine import InferenceEngine
     from milnce_tpu.serving.export import METADATA_FILE
     from milnce_tpu.serving.index import DeviceRetrievalIndex
+    from milnce_tpu.utils.compile_cache import configure_compile_cache
 
-    cfg = parse_cli(argv, description="milnce-tpu serving front")
     s = cfg.serve
     if not s.export_dir:
         raise SystemExit("--serve.export_dir is required (a milnce-export "
                          "artifact directory)")
     initialize_distributed(cfg.parallel)
+    configure_compile_cache()
     mesh = build_mesh(cfg.parallel)
+    # first line: where this server really runs
+    print(f"milnce-serve: {describe_devices(mesh)}", flush=True)
     edge = bool(s.edge_export_dir) and s.edge_replicas > 0
     if s.edge_replicas > 0 and not s.edge_export_dir:
         raise SystemExit("--serve.edge_replicas needs "
@@ -976,7 +977,51 @@ def main(argv=None) -> None:
         capture=capture, anomaly_ratio=s.anomaly_ratio,
         max_inflight=s.max_inflight, tiers=s.tiers,
         continuous=s.continuous_batching)
-    server = serve_http(service, s.host, s.port)
+    return serve_http(service, s.host, s.port), service, index, engine
+
+
+def close_server(cfg, server, service, index, engine) -> None:
+    """Unwind what :func:`build_server` built (live-index snapshot,
+    batcher/pool close) after ``serve_forever`` returned."""
+    s = cfg.serve
+    server.server_close()
+    service.close()
+    if s.live_index and index is not None:
+        if s.index_snapshot_dir:
+            # checkpoint the grown corpus so the next boot resumes
+            # the generation instead of re-ingesting from scratch
+            if not index.flush(timeout=30.0):
+                # acknowledged-but-unpublished rows exist and could
+                # not be swapped in time (wedged/failing builder) —
+                # the snapshot below is the LIVE generation only;
+                # dropping ingest silently would betray the 200s
+                # those adds already returned
+                st = index.stats()
+                print(f"milnce-serve: WARNING — shutdown flush timed "
+                      f"out with {st['pending_rows']} ingested rows "
+                      f"unpublished ({st['swap_failures']} swap "
+                      f"failures); snapshot covers generation "
+                      f"{st['generation']} only", flush=True)
+            index.snapshot(s.index_snapshot_dir)
+        index.close()
+    if s.replicas > 1:
+        engine.close()
+
+
+def main(argv=None) -> None:
+    """``milnce-serve``: HTTP retrieval service over a frozen export.
+
+    Same CLI grammar as the trainer (``--preset`` + ``--serve.*`` /
+    ``--parallel.*`` overrides — config.py).  The corpus comes from
+    ``--serve.corpus_npz`` (a (N, D) float32 embedding matrix, e.g. an
+    offline eval extraction); without it the service starts embed-only
+    (query requests 400 until an index exists)."""
+    from milnce_tpu.config import parse_cli
+
+    cfg = parse_cli(argv, description="milnce-tpu serving front")
+    s = cfg.serve
+    server, service, index, engine = build_server(cfg)
+    edge = bool(s.edge_export_dir) and s.edge_replicas > 0
 
     # graceful shutdown: SIGTERM/SIGINT must unwind through the finally
     # below (live-index snapshot, batcher/pool close) instead of killing
@@ -997,34 +1042,13 @@ def main(argv=None) -> None:
           f"replicas={s.replicas}"
           + (f"+{s.edge_replicas} edge" if edge else "") + ", "
           f"index={'none' if index is None else index.size}, "
-          f"tokenizer={'yes' if tokenizer else 'token_ids-only'}; "
+          f"tokenizer={'yes' if service.tokenizer else 'token_ids-only'}; "
           f"Prometheus scrape: /metrics)",
           flush=True)
     try:
         server.serve_forever()
     finally:
-        server.server_close()
-        service.close()
-        if s.live_index and index is not None:
-            if s.index_snapshot_dir:
-                # checkpoint the grown corpus so the next boot resumes
-                # the generation instead of re-ingesting from scratch
-                if not index.flush(timeout=30.0):
-                    # acknowledged-but-unpublished rows exist and could
-                    # not be swapped in time (wedged/failing builder) —
-                    # the snapshot below is the LIVE generation only;
-                    # dropping ingest silently would betray the 200s
-                    # those adds already returned
-                    st = index.stats()
-                    print(f"milnce-serve: WARNING — shutdown flush timed "
-                          f"out with {st['pending_rows']} ingested rows "
-                          f"unpublished ({st['swap_failures']} swap "
-                          f"failures); snapshot covers generation "
-                          f"{st['generation']} only", flush=True)
-                index.snapshot(s.index_snapshot_dir)
-            index.close()
-        if s.replicas > 1:
-            engine.close()
+        close_server(cfg, server, service, index, engine)
 
 
 if __name__ == "__main__":

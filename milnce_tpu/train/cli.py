@@ -16,10 +16,12 @@ from __future__ import annotations
 from milnce_tpu.config import parse_cli
 from milnce_tpu.elastic import DRAINED_EXIT_CODE
 from milnce_tpu.train.loop import run_training
+from milnce_tpu.utils.compile_cache import configure_compile_cache
 
 
 def main(argv=None):
     cfg = parse_cli(argv, description="milnce-tpu trainer")
+    configure_compile_cache()
     result = run_training(cfg)
     if result.drained:
         print(f"drained: {result.steps} steps, final loss "

@@ -301,20 +301,24 @@ def plan_curriculum(stages, num_samples: int, epochs: int) -> CurriculumPlan:
 def hbm_budget_bytes() -> Optional[int]:
     """Per-chip HBM budget the stage pre-flight gates against:
     ``MILNCE_HBM_GIB`` (explicit, wins — also how CPU runs arm the gate)
-    else the backend's reported ``bytes_limit``; ``None`` disarms the
-    pre-flight (hermetic CPU default)."""
+    else the device's reported ``bytes_limit``.  On the ``tpu`` platform
+    a device that does not report one is an error — a pre-flight
+    disarmed in silence is no pre-flight; anywhere else ``None`` disarms
+    it (the CPU reports no memory stats)."""
     env = os.environ.get("MILNCE_HBM_GIB")
     if env:
         return int(float(env) * 2 ** 30)
     import jax
 
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:  # graftlint: disable=GL007(best-effort backend probe — a backend without memory_stats (CPU, some tunnels) just disarms the pre-flight, the documented None contract; nothing to record)
-        pass
-    return None
+    dev = jax.local_devices()[0]
+    if dev.platform != "tpu":
+        return None
+    stats = dev.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{dev.device_kind!r} reports no memory_stats()['bytes_limit'] "
+            "— set MILNCE_HBM_GIB to arm the curriculum HBM pre-flight")
+    return int(stats["bytes_limit"])
 
 
 def preflight_stages(step_fn, state, stages, *, num_candidates: int,

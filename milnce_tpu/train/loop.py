@@ -37,6 +37,7 @@ from milnce_tpu.obs import spans as obs_spans
 from milnce_tpu.obs.anomaly import EwmaSpikeDetector
 from milnce_tpu.obs.capture import ProfilerCapture
 from milnce_tpu.parallel.mesh import (broadcast_str, build_mesh,
+                                      describe_devices,
                                       initialize_distributed,
                                       replicate_to_mesh)
 from milnce_tpu.resilience import faults
@@ -48,7 +49,7 @@ from milnce_tpu.train.state import TrainState, build_optimizer, create_train_sta
 from milnce_tpu.train.step import make_train_step
 from milnce_tpu.utils.logging import RunLogger
 from milnce_tpu.utils.profiling import StepTimer, maybe_trace
-from milnce_tpu.utils.roofline import (device_peak_flops as roofline_peak,
+from milnce_tpu.utils.roofline import (chip_peak_flops as roofline_peak,
                                        mfu as roofline_mfu,
                                        train_step_flops as
                                        roofline_step_flops)
@@ -258,7 +259,8 @@ def run_training(cfg: Config, max_steps: Optional[int] = None) -> TrainResult:
 
     logger = RunLogger(cfg.train.log_root, cfg.train.checkpoint_dir,
                        enabled=jax.process_index() == 0 and cfg.train.verbose)
-    logger.log(f"mesh: {mesh.shape} | devices: {len(jax.devices())} "
+    # first line: where this run really executes
+    logger.log(f"{describe_devices(mesh)} | mesh: {dict(mesh.shape)} "
                f"| global batch: {cfg.train.batch_size}")
 
     # Observability (obs/, OBSERVABILITY.md): an append-only span/event
@@ -329,8 +331,8 @@ def run_training(cfg: Config, max_steps: Optional[int] = None) -> TrainResult:
     #                                     host's — an elastic 4-way resume
     #                                     on an 8-device host must not
     #                                     halve its MFU by fiction
-    dev0 = jax.devices()[0]
-    peak = roofline_peak(str(getattr(dev0, "device_kind", dev0.platform)))
+    # None off the TPU (gauge off); a TPU kind the table lacks raises
+    peak = roofline_peak(mesh.devices.flat[0])
 
     def _stage_step_flops(st) -> Optional[float]:
         # per-stage: the curriculum changes batch/frames/resolution, and
@@ -431,7 +433,11 @@ def run_training(cfg: Config, max_steps: Optional[int] = None) -> TrainResult:
                              st0.resolution, 3), np.float32)
     sample_text = np.zeros((2 * cfg.data.num_candidates, cfg.data.max_words),
                            np.int32)
-    variables = model.init(rng, sample_video, sample_text)
+    # init and optimizer init each as ONE jitted program: eagerly, Flax
+    # runs the whole forward op by op, and on a cold chip every op is a
+    # compile of its own — 470 s before the first step of the full-width
+    # model on a v5e against 138 s for the step's own compile
+    variables = jax.jit(model.init)(rng, sample_video, sample_text)
     if cfg.train.pretrain_ckpt:
         # converted reference weights (main_distributed.py:81-83)
         from milnce_tpu.utils.torch_convert import load_torch_checkpoint_as_flax
@@ -445,7 +451,7 @@ def run_training(cfg: Config, max_steps: Optional[int] = None) -> TrainResult:
     # checkpoints are identical to a flat run's.
     schedule = build_schedule_total(cfg.optim, plan.total_steps)
     optimizer = build_optimizer(cfg.optim, schedule)
-    state = create_train_state(variables, optimizer)
+    state = jax.jit(lambda v: create_train_state(v, optimizer))(variables)
 
     # State placement: the ONE path every arrival sharding goes through
     # (fresh init, Orbax restore, rollback restore) — on the 2-D mesh it

@@ -121,9 +121,8 @@ def _render_memplan(plans: dict, results, ladder=None) -> str:
     lines.append("")
     lines.append("## Sharding attribution")
     lines.append("")
-    lines.append("Donated arg leaves per entry (the GL014 audit surface; "
-                 "donation is gated OFF on CPU by parallel/compat.py but "
-                 "must stay requested for TPU):")
+    lines.append("Donated arg leaves per entry (the GL014 audit "
+                 "surface):")
     lines.append("")
     for name, p in plans.items():
         n_don = len(p.donated)
@@ -226,9 +225,9 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from milnce_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from milnce_tpu.analysis import memplan
 

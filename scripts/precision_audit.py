@@ -362,9 +362,9 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from milnce_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from milnce_tpu.analysis import numerics
 

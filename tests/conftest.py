@@ -11,13 +11,13 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 import jax  # noqa: E402
 
-# The env-var route (JAX_PLATFORMS=cpu) can be overridden by accelerator
-# plugins that force their own platform list; the config update wins.
+from milnce_tpu.utils.compile_cache import configure_compile_cache  # noqa: E402
+
+# The tests run on the CPU whatever the host holds.
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "highest")
 
 # Persistent compilation cache: the S3D train step takes ~2 min to compile
 # on the virtual 8-device CPU mesh; identical HLO across test runs hits disk.
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+# JAX_COMPILATION_CACHE_DIR wins where it is set; else this fixed path.
+configure_compile_cache("/tmp/jax_test_cache")

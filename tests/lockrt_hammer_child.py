@@ -39,9 +39,9 @@ assert lockrt.sanitizing_enabled(), \
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from milnce_tpu.utils.compile_cache import configure_compile_cache  # noqa: E402
+
+configure_compile_cache("/tmp/jax_test_cache")
 
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -212,12 +212,10 @@ def main() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # older jax: default implementation
+    from milnce_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache("/tmp/jax_test_cache")
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     from milnce_tpu.config import ParallelConfig
     from milnce_tpu.parallel.mesh import build_mesh, initialize_distributed

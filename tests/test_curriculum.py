@@ -430,7 +430,8 @@ def test_resume_with_curriculum_removed_fails_loudly(tmp_path):
 
 def _fake_bench_row(timeout_s=None, **kw):
     f = kw["frames"]
-    return {"dtype": kw["dtype"], "batch": kw["batch"],
+    return {"platform": "tpu", "device_kind": "TPU v5 lite", "n_chips": 1,
+            "dtype": kw["dtype"], "batch": kw["batch"],
             "remat": kw["remat"], "s2d": kw["s2d"],
             "conv_impl": kw["conv_impl"], "loss": kw.get("loss", "milnce"),
             "loss_impl": None, "grad_accum": kw.get("grad_accum", 1),
@@ -455,10 +456,10 @@ def test_bench_curriculum_axis_composes_schedule_rate(monkeypatch):
         "MILNCE_BENCH_CURRICULUM",
         "num_frames=2,resolution=32,batch_size=8,until_step=100;"
         "num_frames=4,resolution=64,batch_size=8")
-    rec = bench.run_bench(False, {"platform": "cpu", "kind": "cpu", "n": 1})
+    rec = bench.run_bench()
 
-    # headline = the sweep's 4f row (240/4), untouched by stage rows
-    assert rec["value"] == pytest.approx(60.0)
+    # headline = the sweep's 16f row (240/16), untouched by stage rows
+    assert rec["value"] == pytest.approx(15.0)
     cur = rec["curriculum"]
     assert [s["label"] for s in cur["stages"]] == ["2f@32 batch 8",
                                                    "4f@64 batch 8"]
@@ -486,6 +487,6 @@ def test_bench_curriculum_axis_requires_step_bounds(monkeypatch):
         "MILNCE_BENCH_CURRICULUM",
         "num_frames=2,resolution=32,batch_size=8,until_epoch=1;"
         "num_frames=4,resolution=64,batch_size=8")
-    rec = bench.run_bench(False, {"platform": "cpu", "kind": "cpu", "n": 1})
+    rec = bench.run_bench()
     assert "curriculum" not in rec
-    assert rec["value"] == pytest.approx(60.0)
+    assert rec["value"] == pytest.approx(15.0)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from milnce_tpu.parallel.compat import shard_map
+from jax import shard_map
 from milnce_tpu.losses.dtw_losses import (cdtw_loss, sdtw_3_loss,
                                           sdtw_cidm_loss, sdtw_negative_loss)
 

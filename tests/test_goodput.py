@@ -573,13 +573,23 @@ def test_mfu_helper_matches_bench_formula():
         bench_style, rel=1e-12)
 
 
-def test_peak_flops_env_override(monkeypatch):
-    from milnce_tpu.utils.roofline import device_peak_flops
+def test_peak_flops_table_or_error_never_a_default(monkeypatch):
+    """The MFU denominator comes from the table alone: off the TPU the
+    gauge is off (None), on it an unknown device_kind is an error, and
+    no environment variable overrides either."""
+    from types import SimpleNamespace as Dev
+
+    from milnce_tpu.utils.roofline import chip_peak_flops, device_peak_flops
 
     assert device_peak_flops("cpu") is None
     assert device_peak_flops("TPU v5e") == 197e12
     monkeypatch.setenv("MILNCE_PEAK_FLOPS", "2.5e12")
-    assert device_peak_flops("cpu") == 2.5e12
+    assert device_peak_flops("cpu") is None
+    assert chip_peak_flops(Dev(platform="cpu", device_kind="cpu")) is None
+    assert chip_peak_flops(Dev(platform="tpu",
+                               device_kind="TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="TPU v9000"):
+        chip_peak_flops(Dev(platform="tpu", device_kind="TPU v9000"))
 
 
 # ---------------------------------------------------------------------------
@@ -724,10 +734,12 @@ def test_live_mfu_gauge_agrees_with_bench_formula(tmp_path, monkeypatch):
     table, same formula, same displayed throughput."""
     from milnce_tpu.obs import metrics as obs_metrics
     from milnce_tpu.train.loop import run_training
-    from milnce_tpu.utils.roofline import (device_peak_flops, mfu,
-                                           train_step_flops)
+    from milnce_tpu.train import loop as train_loop
+    from milnce_tpu.utils.roofline import mfu, train_step_flops
 
-    monkeypatch.setenv("MILNCE_PEAK_FLOPS", "1e12")
+    # the CPU has no peak (gauge off): the test, not an option of the
+    # program, gives this run a denominator
+    monkeypatch.setattr(train_loop, "roofline_peak", lambda dev: 1e12)
     cfg = _tiny_cfg(tmp_path, samples=32)
     cfg.train.run_id = "goodput-mfu"
     # capture configured but the run is clean: doubles as the
@@ -747,7 +759,7 @@ def test_live_mfu_gauge_agrees_with_bench_formula(tmp_path, monkeypatch):
     import jax
 
     expected = mfu(flops, clips_per_sec / cfg.train.batch_size,
-                   device_peak_flops("cpu"), len(jax.devices()))
+                   1e12, len(jax.devices()))
     assert live_mfu == pytest.approx(expected, rel=0.02), (
         f"live {live_mfu} vs bench-formula {expected}")
     # the display events carry mfu, and the ledger snapshot exposes it

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from milnce_tpu.analysis import memplan
-from milnce_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _mesh1d():
@@ -261,29 +261,28 @@ def test_gl014_fires_once_per_planted_donation_bug():
         donate_argnums=(), grad_bearing=True) == []
 
 
-def test_gl014_tpu_gate_verified_through_cpu_donation_gate():
-    """The audit must honor the CPU gate (donation legitimately dropped
-    here) while proving the TPU path still requests it — the pure
-    backend-keyed half of parallel/compat.donation_argnums."""
-    from milnce_tpu.parallel.compat import (donation_argnums,
-                                            donation_argnums_for_backend)
+def test_production_step_donates_its_state_on_the_cpu_too():
+    """No backend gate: the donated step consumes its input state here
+    exactly as on the chip (jax 0.9.0 resumes cleanly with donation on
+    the CPU — the resume suites run the donated program)."""
+    spec = memplan._entries()["train_step_milnce"]
+    fn, args = spec.build(donate=True)
+    state = jax.tree_util.tree_map(
+        lambda s: jax.numpy.zeros(s.shape, s.dtype), args[0])
+    rest = [jax.numpy.zeros(a.shape, a.dtype) for a in args[1:]]
+    new_state, _ = fn(state, *rest)[:2]
+    assert jax.tree_util.tree_leaves(state.params)[0].is_deleted()
+    assert not jax.tree_util.tree_leaves(new_state.params)[0].is_deleted()
 
-    assert donation_argnums_for_backend("tpu", 0) == (0,)
-    assert donation_argnums_for_backend("gpu", 0) == (0,)
-    assert donation_argnums_for_backend("cpu", 0) == ()
-    # this suite runs on CPU: the live gate and the pure function agree
-    assert donation_argnums(0) == donation_argnums_for_backend(
-        jax.default_backend(), 0)
 
-
-def test_gl014_tpu_wiring_read_off_the_traced_program():
-    """The TPU half of GL014 must interrogate what the factory REALLY
+def test_gl014_wiring_read_off_the_traced_program():
+    """The wiring half of GL014 must interrogate what the factory REALLY
     passes to jax.jit, not round-trip a registry constant (review r13:
     a factory that drops its donate_argnums= plumbing must fail).  The
     donated production build traces one donated invar per state leaf;
     the donate=False build — exactly what a plumbing-less factory would
     produce — traces zero."""
-    traced, expected = memplan._tpu_donation_wired("train_step_milnce")
+    traced, expected = memplan._donation_wired("train_step_milnce")
     assert expected > 0 and traced == expected, (traced, expected)
     # the regression shape: no donate wiring -> zero donated invars
     spec = memplan._entries()["train_step_milnce"]
@@ -331,7 +330,7 @@ def test_all_registered_entries_plan_green():
         assert (entry, "GL013-peak-budget") in checks
         assert (entry, "GL015-top-contributors") in checks
         assert (entry, "GL014-donation") in checks
-        assert (entry, "GL014-tpu-donation-requested") in checks
+        assert (entry, "GL014-donation-requested") in checks
 
 
 def test_guarded_step_peak_exceeds_plain_by_one_state_copy():

@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import set_mesh, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from milnce_tpu.losses.milnce import milnce_loss
-from milnce_tpu.parallel.compat import set_mesh, shard_map
 
 
 def numpy_milnce(v, t):
@@ -70,8 +70,16 @@ def test_sharded_equals_unsharded():
     np.testing.assert_allclose(float(out), numpy_milnce(v, t), rtol=1e-5)
 
 
-def test_sharded_gradients_match_unsharded():
+@pytest.mark.parametrize("check_vma", [True, False],
+                         ids=["check_vma", "no_check_vma"])
+def test_sharded_gradients_match_unsharded(check_vma):
+    """Gradient taken INSIDE the shard_map body, under each setting of
+    ``check_vma`` the repo passes (the train steps pass False): a plain
+    ``lax.psum`` in the loss transposes to another psum under False and
+    every element comes out 8.0x the unsharded one — the reduction's
+    explicit VJP (losses/milnce.py psum_local_grad) must not."""
     devices = jax.devices()
+    assert len(devices) == 8, "conftest must provide 8 virtual devices"
     mesh = Mesh(np.array(devices), ("data",))
     b, k, d = 8, 2, 16
     rng = np.random.RandomState(2)
@@ -90,8 +98,9 @@ def test_sharded_gradients_match_unsharded():
                 argnums=(0, 1))(vv, tt)
             return gv, gt
         return shard_map(local, mesh=mesh,
-                             in_specs=(P("data"), P("data")),
-                             out_specs=(P("data"), P("data")))(v, t)
+                         in_specs=(P("data"), P("data")),
+                         out_specs=(P("data"), P("data")),
+                         check_vma=check_vma)(v, t)
 
     with set_mesh(mesh):
         gv, gt = sharded_grads(jax.device_put(v, NamedSharding(mesh, P("data"))),

@@ -15,6 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from milnce_tpu.config import LossConfig, OptimConfig, ParallelConfig
@@ -24,7 +25,6 @@ from milnce_tpu.losses.milnce_chunked import (build_milnce_loss,
                                               milnce_loss_chunked,
                                               prefers_chunked)
 from milnce_tpu.models import S3D
-from milnce_tpu.parallel.compat import set_mesh, shard_map
 from milnce_tpu.parallel.mesh import build_mesh, replicate_to_mesh
 from milnce_tpu.parallel.sharding_map import (place_tree, sharded_count,
                                               state_partition_specs)
@@ -167,16 +167,19 @@ def _batch(seed=0):
     return video, text, start
 
 
-def _train(loss_cfg, two_d=False, grad_accum=1, n_steps=2):
+def _train(loss_cfg, two_d=False, grad_accum=1, n_steps=2,
+           optim_cfg=OptimConfig(warmup_steps=2), n_devices=0):
     """Fresh init -> n_steps of the real step program; returns per-step
     losses and the final state (mirror of test_train_2d._train, with the
-    loss impl as the axis under test)."""
+    loss impl as the axis under test).  ``n_devices`` builds the data
+    mesh over the first N devices (0 = all 8)."""
     if two_d:
         mesh = build_mesh(ParallelConfig(model_axis="model",
                                          model_parallel_size=2))
         bn_axes = ("data", "model")
     else:
-        mesh = build_mesh(ParallelConfig())
+        mesh = build_mesh(ParallelConfig(),
+                          devices=jax.devices()[:n_devices or None])
         bn_axes = "data"
     model = S3D(num_classes=16, vocab_size=_VOCAB, word_embedding_dim=8,
                 text_hidden_dim=16, inception_blocks=1,
@@ -185,8 +188,7 @@ def _train(loss_cfg, two_d=False, grad_accum=1, n_steps=2):
         jax.random.PRNGKey(0),
         jnp.zeros((2, _FRAMES, _SIZE, _SIZE, 3), jnp.float32),
         jnp.zeros((2, _WORDS), jnp.int32))
-    opt = build_optimizer(OptimConfig(warmup_steps=2),
-                          build_schedule(OptimConfig(warmup_steps=2), 10))
+    opt = build_optimizer(optim_cfg, build_schedule(optim_cfg, 10))
     state = create_train_state(variables, opt)
     if two_d:
         specs = state_partition_specs(state, mesh, "model",
@@ -229,6 +231,35 @@ def test_train_step_parity_dense_vs_chunked_1d():
     chunked, st_c = _train(_CHUNKED)
     np.testing.assert_allclose(chunked, dense, rtol=2e-4, atol=2e-5)
     _assert_states_match(st_d, st_c)
+
+
+@pytest.mark.parametrize("loss_name", ["milnce", "sdtw_3"])
+def test_train_step_parity_sgd_8way_vs_one_device(loss_name):
+    """Two SGD steps on the 8-way mesh equal two on a one-device mesh
+    (same global batch, sync BN so both see the same statistics), params
+    leaf-for-leaf.  SGD on purpose: Adam is invariant to the gradient's
+    scale, so the Adam parity cases above cannot see a loss-reduction
+    transpose that multiplies every gradient by the mesh size (the psum
+    fault ``psum_local_grad`` repairs); SGD moves the params by exactly
+    that factor.  The DTW case pins the pmean-reduced family the same
+    way (sdtw_3: its gamma=0.1 soft-min is smooth, where cdtw's 1e-5 is
+    a hard min whose alignment path flips on f32 reduction-order noise).
+    Tolerance: lr 0.05 scales f32 reduction-order noise to ~4e-5; an
+    8x gradient would move the params by ~1e-2."""
+    sgd = OptimConfig(name="sgd", lr=0.05, warmup_steps=1)
+    loss_cfg = LossConfig(name=loss_name)
+    l8, st8 = _train(loss_cfg, optim_cfg=sgd)
+    l1, st1 = _train(loss_cfg, optim_cfg=sgd, n_devices=1)
+    np.testing.assert_allclose(l8, l1, rtol=2e-4, atol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(st8.params),
+                    jax.tree_util.tree_leaves(st1.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-4)
+    moved = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+        jax.tree_util.tree_leaves(st1.params),
+        jax.tree_util.tree_leaves(_train(loss_cfg, optim_cfg=sgd,
+                                         n_devices=1, n_steps=0)[1].params)))
+    assert moved > 1e-3, f"SGD barely moved the params ({moved}): no teeth"
 
 
 def test_train_step_parity_dense_vs_chunked_2d():

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from milnce_tpu.models import S3D
-from milnce_tpu.parallel.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 from milnce_tpu.models.s3dg import space_to_depth, _tf_same_max_pool
 
 

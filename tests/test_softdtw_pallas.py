@@ -117,7 +117,8 @@ def test_mil_regime_batch_squared_pairs():
 @pytest.mark.slow
 def test_lanes_layout_matches_scan(monkeypatch):
     """Large-batch short-pair shapes route through the batch-on-lanes
-    kernels by default (measured 3.5-26x on v5e, BENCH_SOFTDTW.md);
+    kernels by default (3.5-26x measured on a v5e before PR 1, to be
+    measured again by the benchmark);
     values and grads must match the scan (multi-block at B=300,
     rectangular, and the 32x32 MIL shape)."""
     monkeypatch.delenv("MILNCE_SDTW_LANES", raising=False)

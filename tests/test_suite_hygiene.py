@@ -66,6 +66,12 @@ _FAST_CHILD_EXEMPT = {
     # end-to-end; tiny preset + the shared persistent compile cache
     # keep it seconds-scale, and the live-index gate pins it tier-1.
     "test_serve_tiers.py::test_two_tier_chaos_bench_acceptance",
+    # ISSUE 22: the REFUSAL paths — bench's measuring child and the flag
+    # probe where JAX finds no TPU.  Subprocesses because the pin IS the
+    # real process's exit code and output; each imports jax, sees the
+    # CPU and exits in seconds without compiling anything.
+    "test_bench.py::test_run_config_child_refuses_off_the_tpu",
+    "test_probes.py::test_flag_probe_without_a_tpu_writes_no_row",
 }
 
 

@@ -1,0 +1,211 @@
+"""The main path's kernels and the full-width train step COMPILE for a
+TPU v5e — asked of the installed TPU compiler with a described
+``v5e:2x2`` and no chip attached.
+
+Interpret mode (every other test of these kernels) cannot see what this
+sees: a slice off the (8, 128) tiling, more VMEM than a kernel may use,
+a program that does not fit 16 GB.  Nothing runs here, so nothing is
+said about results or times — that is ``chip_smoke.py``'s work.
+
+Form (on-chip-measurement guide, section 2): the topology is described
+inside a module-scoped fixture that skips where it cannot be described,
+never while a module is imported and never ``autouse``; every compile
+is made in the test's own process (the worker that describes the
+topology holds libtpu until it exits); the persistent cache is off
+around it (an entry compiled for a described chip cannot be read back).
+``ops/pallas_mode.interpret()`` reads the default backend — the CPU
+here — so the tests steer it themselves.
+"""
+
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from milnce_tpu.ops import pallas_mode
+
+V5E_HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    # The TPU compiler works on every core it finds, for minutes at the
+    # full-width step; the other workers' tests include timing-sensitive
+    # ones (decode watchdogs of 0.3 s, step-time spike detectors) that
+    # fail when starved.  The compiler's threads are started from here
+    # on and inherit this thread's CPU mask: keep them on three cores.
+    had = os.sched_getaffinity(0)
+    os.sched_setaffinity(0, set(sorted(had)[:3]))
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        os.sched_setaffinity(0, had)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _compiled_kernels_and_no_cache(monkeypatch):
+    """This file's tests only (the fixture lives here, not in conftest):
+    kernels lower through Mosaic instead of the interpreter, and the
+    persistent compilation cache is off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled, compiled.as_text()
+
+
+def _shape(one_chip, dims, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+
+# --------------------------------------------------------------------------
+# soft-DTW: every layout the dispatch rule can pick, forward and backward
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("bsz,n,m", [
+    (128, 17, 15),          # batch-on-lanes (the reference's preset)
+    (1024, 32, 32),         # batch-on-lanes, 8 lane blocks (SDTW_3 regime)
+    (32, 256, 256),         # sublane-batch, multi-block grid
+    (4, 1024, 1024),        # tables past the VMEM budget: chunked stream
+], ids=["lanes-128x17x15", "lanes-1024x32x32", "multiblock-32x256x256",
+        "chunked-4x1024x1024"])
+def test_softdtw_pallas_compiles_forward_and_backward(one_chip, bsz, n, m):
+    from milnce_tpu.ops import softdtw_pallas as sp
+
+    if (bsz, n, m) == (4, 1024, 1024):
+        assert not sp._table_fits_vmem(n, m), "shape no longer chunked"
+
+    def value_and_grad(D):
+        return jax.value_and_grad(
+            lambda d: jnp.sum(sp.softdtw_pallas(d, 0.1)))(D)
+
+    _, text = _compile(value_and_grad, _shape(one_chip, (bsz, n, m)))
+    assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# chunked MIL-NCE stream kernel (never compiled for a chip before PR 22)
+# --------------------------------------------------------------------------
+
+def _milnce_stream_grads(chunk):
+    from milnce_tpu.ops.milnce_pallas import milnce_stream_pallas
+
+    def f(v, t, va, ta):
+        row, col = milnce_stream_pallas(v, t, va, ta, chunk)
+        return jnp.sum(row) + jnp.sum(col)
+
+    return jax.value_and_grad(f, argnums=(0, 1, 2, 3))
+
+
+@pytest.mark.parametrize("bg,chunk", [(128, 8), (512, 8)],
+                         ids=["Bg128-16chunks", "Bg512-64chunks"])
+def test_milnce_pallas_compiles_forward_and_backward(one_chip, bg, chunk):
+    from milnce_tpu.ops.milnce_pallas import prefers_pallas
+
+    b, k, d = 128, 5, 512
+    assert prefers_pallas(b, bg, k, d, chunk), "auto would not pick this"
+    _, text = _compile(
+        _milnce_stream_grads(chunk),
+        _shape(one_chip, (b, d)), _shape(one_chip, (b * k, d)),
+        _shape(one_chip, (bg, d)), _shape(one_chip, (bg * k, d)))
+    assert "tpu_custom_call" in text
+
+
+def test_milnce_pallas_outside_vmem_budget_names_the_knob(one_chip):
+    """b=128, Bg=512, K=5, D=512 at the default chunk (512): the TPU
+    compiler refuses the backward (``RESOURCE_EXHAUSTED ... vmem``) from
+    deep inside the step compile.  ``backend='auto'`` never picks it; an
+    explicit ``loss.milnce_backend=pallas`` now fails at trace time
+    naming the knob and the shape."""
+    from milnce_tpu.losses.milnce_chunked import milnce_default_chunk
+
+    b, bg, k, d = 128, 512, 5, 512
+    chunk = milnce_default_chunk(b, k, bg)
+    assert chunk == 512
+    with pytest.raises(ValueError) as exc_info:
+        _compile(
+            _milnce_stream_grads(chunk),
+            _shape(one_chip, (b, d)), _shape(one_chip, (b * k, d)),
+            _shape(one_chip, (bg, d)), _shape(one_chip, (bg * k, d)))
+    msg = str(exc_info.value)
+    assert "loss.milnce_backend=pallas" in msg
+    assert "b_global=512" in msg and "loss.milnce_chunk=512" in msg
+
+
+# --------------------------------------------------------------------------
+# the full-width single-chip train step, at chip_smoke.py's batch
+# --------------------------------------------------------------------------
+
+def test_full_width_train_step_fits_one_v5e(topo):
+    """The jitted ``make_train_step`` of the ``full`` preset's model
+    (9 blocks, 512/66250/300/2048) in bfloat16 at 32f@224, K=5, 20 words
+    and the smoke's batch, lowered from ``jax.eval_shape`` shapes onto a
+    one-device mesh of the described topology: it compiles, and what it
+    needs on the device stays under a v5e's 16 GB."""
+    import chip_smoke
+    from milnce_tpu.config import full_preset
+    from milnce_tpu.models.build import build_model
+    from milnce_tpu.train.schedule import build_schedule
+    from milnce_tpu.train.state import build_optimizer, create_train_state
+    from milnce_tpu.train.step import make_train_step
+
+    cfg = full_preset()
+    cfg.model.dtype = "bfloat16"
+    sizes = chip_smoke.FULL_SIZES
+    batch, frames, size = sizes.batch, sizes.frames, sizes.size
+    k, words = sizes.candidates, sizes.words
+    assert (frames, size, k, words) == (32, 224, 5, 20)
+    model = build_model(cfg.model)
+    optimizer = build_optimizer(cfg.optim, build_schedule(cfg.optim, 1000))
+
+    def init_state(key):
+        variables = model.init(
+            key, jnp.zeros((2, frames, size, size, 3), jnp.float32),
+            jnp.zeros((2 * k, words), jnp.int32))
+        return create_train_state(variables, optimizer)
+
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    repl, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=repl),
+        jax.eval_shape(init_state, jax.random.PRNGKey(0)))
+    step = make_train_step(model, optimizer, mesh, finite_guard=True)
+    compiled = step.lower(
+        state,
+        jax.ShapeDtypeStruct((batch, frames, size, size, 3), jnp.uint8,
+                             sharding=data),
+        jax.ShapeDtypeStruct((batch * k, words), jnp.int32, sharding=data),
+        jax.ShapeDtypeStruct((batch,), jnp.float32,
+                             sharding=data)).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < need < V5E_HBM_BYTES, (
+        f"full-width step at batch {batch} needs {need / 1e9:.2f} GB")

@@ -65,12 +65,10 @@ def test_all_registered_entry_invariants_hold():
 
 
 def test_f64_detector_catches_planted_upcast():
-    from jax.experimental import enable_x64
-
     def f(x):
         return x.astype("float64") + 1.0
 
-    with enable_x64():
+    with jax.enable_x64():
         jaxpr = jax.make_jaxpr(f)(np.ones((3,), np.float32)).jaxpr
     assert f64_sites(jaxpr), "planted f64 upcast not detected"
 
@@ -84,7 +82,6 @@ def test_f64_detector_clean_on_f32():
 def test_collective_counter_sees_through_nested_jaxprs():
     from jax.sharding import PartitionSpec as P
 
-    from milnce_tpu.parallel.compat import shard_map
     from milnce_tpu.parallel.mesh import build_mesh
     from milnce_tpu.config import ParallelConfig
 
@@ -92,8 +89,8 @@ def test_collective_counter_sees_through_nested_jaxprs():
 
     @jax.jit
     def summed(x):
-        return shard_map(lambda xs: jax.lax.psum(xs.sum(), "data"),
-                         mesh=mesh, in_specs=P("data"), out_specs=P())(x)
+        return jax.shard_map(lambda xs: jax.lax.psum(xs.sum(), "data"),
+                             mesh=mesh, in_specs=P("data"), out_specs=P())(x)
 
     jaxpr = jax.make_jaxpr(summed)(np.ones((8,), np.float32)).jaxpr
     assert collective_counts(jaxpr) == {"psum": 1}

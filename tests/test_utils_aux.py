@@ -174,6 +174,24 @@ class TestMultihostDetect:
         mesh_mod.initialize_distributed(ParallelConfig())
         assert called == []
 
+    def test_unset_environment_is_single_host_and_asks_nobody(self,
+                                                              monkeypatch):
+        """Decided from the environment alone: with the variable unset a
+        run is single-host, and the instance metadata (an HTTP lookup
+        jax retries with 60 s limits) is never asked."""
+        import jax._src.clusters.cloud_tpu_cluster as cluster
+        import milnce_tpu.parallel.mesh as mesh_mod
+        from milnce_tpu.config import ParallelConfig
+
+        monkeypatch.delenv("TPU_WORKER_HOSTNAMES", raising=False)
+        asked, called = [], []
+        monkeypatch.setattr(cluster, "get_tpu_env_value",
+                            lambda *a, **k: asked.append(a) or "a,b")
+        monkeypatch.setattr(mesh_mod.jax.distributed, "initialize",
+                            lambda *a, **k: called.append((a, k)))
+        mesh_mod.initialize_distributed(ParallelConfig())
+        assert asked == [] and called == []
+
     def test_multihost_tpu_auto_initializes(self, monkeypatch):
         import milnce_tpu.parallel.mesh as mesh_mod
         from milnce_tpu.config import ParallelConfig
@@ -200,8 +218,8 @@ class TestMultihostDetect:
         assert called[0]["num_processes"] == 2
 
     def test_platform_pin_applies_jax_config(self, monkeypatch):
-        """--parallel.platform pins the backend via jax.config (env vars
-        alone lose to accelerator plugins); '' leaves it untouched."""
+        """--parallel.platform pins the backend via jax.config; ''
+        leaves it untouched."""
         import milnce_tpu.parallel.mesh as mesh_mod
         from milnce_tpu.config import ParallelConfig, parse_cli
 
