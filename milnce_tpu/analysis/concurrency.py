@@ -82,6 +82,10 @@ _GUARDED_BY = re.compile(r"#\s*guarded-by:\s*(?P<lock>[A-Za-z_]\w*)")
 # and `os.path.join(a, b)` never trip it.
 _BLOCK_METHOD_VERBS = {"result", "join", "wait"}
 _DEVICE_VERBS = {"device_put", "device_get", "block_until_ready"}
+# ``with device_dispatch(site, ...)`` (serving/engine.py) holds a lock
+# for its body like a ``with <lock>`` does.
+_DISPATCH_CONTEXT = "device_dispatch"
+_DISPATCH_DEFAULT_LOCK = "engine:DEVICE_DISPATCH_LOCK"
 
 
 def _module_key(path: str) -> str:
@@ -482,6 +486,15 @@ class _ModulePass:
                 return f"{self.mod}:{cls_name}.{expr.attr}"
             if isinstance(expr, ast.Name) and expr.id in self.module_locks:
                 return self.module_locks[expr.id]
+            if (isinstance(expr, ast.Call) and _terminal_and_root(
+                    expr.func)[0] == _DISPATCH_CONTEXT):
+                # serving's one way to take a dispatch lock: it holds
+                # ``lock=`` (where that resolves), else the process-wide
+                # lock it defaults to
+                for kw in expr.keywords:
+                    if kw.arg == "lock":
+                        return resolve(kw.value)
+                return _DISPATCH_DEFAULT_LOCK
             return None
         return resolve
 

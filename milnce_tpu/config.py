@@ -286,10 +286,11 @@ class TrainConfig:
                                         # run logger is enabled).  Recording
                                         # is host-side only — obs/,
                                         # OBSERVABILITY.md
-    obs_profiler_bridge: bool = False   # wrap spans in jax.profiler.
-                                        # TraceAnnotation so they land in
-                                        # real TPU traces (pairs with
-                                        # trace_dir)
+    obs_profiler_bridge: bool = False   # accepted and ignored: spans are
+                                        # always jax.profiler annotations
+                                        # (obs/spans.py); the field goes
+                                        # with the benchmark line that
+                                        # passes it (ROADMAP)
     run_id: str = ""                    # run identity stamped on every
                                         # RUN_EVENTS.jsonl line + obs
                                         # snapshot ('' = auto: process 0

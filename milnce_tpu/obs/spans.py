@@ -5,7 +5,9 @@ run after the fact: ``step`` (hot-loop dispatch), ``decode.timeout`` /
 ``decode.retry`` (watchdog escalations), ``batcher.flush`` (serving
 micro-batches), ``ladder.warmup`` (engine pre-trace sweep),
 ``ckpt.save`` / ``ckpt.restore`` / ``rollback`` (checkpoint lifecycle),
-``display`` (the train loop's cadenced fetch).
+``display`` (the train loop's cadenced fetch), ``query`` / ``dispatch``
+(a served call and each hold of the device lock), ``runtime.gc`` (a
+collector pause).
 
 Durability has two tiers:
 
@@ -19,10 +21,13 @@ Durations come from ``time.monotonic`` (wall-clock ``ts`` is attached
 for human correlation only).  A span around a jitted call measures
 HOST-SIDE dispatch, not device work — that is deliberate: the recorder
 must never block on the device (the same host-side-only invariant as
-the metrics registry).  For device truth, the opt-in
-``profiler_bridge=True`` wraps each span in
-``jax.profiler.TraceAnnotation`` so spans land in real TPU traces
-(jax is imported lazily, only when the bridge is on).
+the metrics registry).  For device truth every span is also a
+``jax.profiler.TraceAnnotation`` of its name (:func:`annotation`)
+whenever jax is already imported in the process: free while no profiler
+session runs, and inside one — a benchmark's trace, an operator's
+``POST /obs/capture``, a flush-spike capture — the program's spans lie
+on ``/host:CPU`` beside the device's ops, on one clock.  Nothing here
+imports jax: a host-only process (a loader thread) stays host-only.
 
 Thread-safe: ring appends and file writes are lock-guarded (spans fire
 from reader threads, the batcher worker and request threads).
@@ -31,7 +36,10 @@ from reader threads, the batcher worker and request threads).
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
+import sys
+import threading
 import time
 from collections import deque
 from typing import Optional
@@ -50,11 +58,58 @@ def _wall() -> float:
     return time.time()
 
 
+def now() -> float:
+    """The recorder's clock, for what a caller times itself and puts on
+    a record (``lock_wait_ms``, ``topk_ms``): a span's ``dur_ms`` and
+    its attributes then share one clock.  Host time by design."""
+    return _now()
+
+
+def ms_since(t0: float) -> float:
+    """Milliseconds from ``t0`` (a :func:`now` reading), rounded as
+    ``dur_ms`` is."""
+    return round((_now() - t0) * 1e3, 4)
+
+
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` where jax is already
+    imported in this process, else a null context.  Read from
+    ``sys.modules`` so that no caller ever imports jax through here; a
+    ``jax.profiler`` still half-way through its own import counts as
+    absent."""
+    make = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return make(name) if make is not None else contextlib.nullcontext()
+
+
+class _Span:
+    """One timed region of :meth:`SpanRecorder.span`.  A class and not a
+    generator: spans sit on the serving path's per-call host time, which
+    is what the device waits for between two scans."""
+
+    __slots__ = ("_recorder", "_rec", "_t0", "_bridge")
+
+    def __init__(self, recorder: "SpanRecorder", name: str, attrs: dict):
+        self._recorder = recorder
+        self._rec = {"kind": "span", "name": name, "ts": _wall(), **attrs}
+
+    def __enter__(self) -> dict:
+        self._t0 = _now()
+        self._bridge = annotation(self._rec["name"])
+        self._bridge.__enter__()
+        return self._rec
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._bridge.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            self._rec["error"] = exc_type.__name__
+        self._rec["dur_ms"] = ms_since(self._t0)
+        self._recorder._record(self._rec)
+        return False
+
+
 class SpanRecorder:
-    def __init__(self, path: Optional[str] = None, ring: int = 2048,
-                 profiler_bridge: bool = False):
+    def __init__(self, path: Optional[str] = None, ring: int = 2048):
         self.path = path or None
-        self.profiler_bridge = bool(profiler_bridge)
         self._ring: deque = deque(maxlen=max(1, int(ring)))
         self._lock = make_lock("obs.spans.recorder")
         self._mono_last = 0.0
@@ -99,29 +154,12 @@ class SpanRecorder:
         rec.update(attrs)
         self._record(rec)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        """Timed region; records on exit with ``dur_ms`` (host-side
-        elapsed).  Exceptions propagate — the span still records, with
-        ``error`` naming the exception type."""
-        if self.profiler_bridge:
-            import jax
-
-            bridge = jax.profiler.TraceAnnotation(name)
-        else:
-            bridge = contextlib.nullcontext()
-        t0 = _now()
-        rec = {"kind": "span", "name": name, "ts": _wall()}
-        rec.update(attrs)
-        try:
-            with bridge:
-                yield rec
-        except BaseException as exc:
-            rec["error"] = type(exc).__name__
-            raise
-        finally:
-            rec["dur_ms"] = round((_now() - t0) * 1e3, 4)
-            self._record(rec)
+    def span(self, name: str, **attrs) -> "_Span":
+        """Timed region; ``with`` yields the record's dict (a caller may
+        add attributes to it) and records on exit with ``dur_ms``
+        (host-side elapsed).  Exceptions propagate — the span still
+        records, with ``error`` naming the exception type."""
+        return _Span(self, name, attrs)
 
     # ---- reading / lifecycle --------------------------------------------
 
@@ -156,6 +194,78 @@ class SpanRecorder:
             self.close()
         except Exception:  # graftlint: disable=GL007(interpreter-teardown finalizer: close is best-effort, raising only makes unraisable-exception noise)
             pass
+
+
+# ---------------------------------------------------------------------------
+# collector pauses
+# ---------------------------------------------------------------------------
+
+# A collection that held the interpreter this long is worth a record: a
+# young-generation pass is tens of microseconds, a stall is not.
+GC_PAUSE_MIN_MS = 5.0
+_GC_DRAIN_S = 0.05
+
+
+class GcPauseEvents:
+    """``runtime.gc`` events (``generation``, ``dur_ms``, ``collected``,
+    ``end_mono``) for every collection of :data:`GC_PAUSE_MIN_MS` or
+    more, from a ``gc.callbacks`` hook.
+
+    A collection starts wherever an allocation triggers it — also inside
+    the recorder's or the run context's critical section, on the thread
+    that holds the lock — so the hook takes no lock: it reads the clock
+    and appends to a deque.  A daemon thread writes the events out at
+    most :data:`_GC_DRAIN_S` later; ``end_mono`` is the pause's own end
+    on the recorder's clock (the record's ``mono`` is when it was
+    written)."""
+
+    def __init__(self, recorder: Optional["SpanRecorder"] = None):
+        self._recorder = recorder       # None = the process default
+        self._t0: Optional[float] = None
+        self._pauses: deque = deque()
+        self._stop = threading.Event()
+        self._writer = threading.Thread(target=self._run, daemon=True,
+                                        name="obs-gc-pauses")
+
+    def _hook(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = _now()
+            return
+        t0, self._t0 = self._t0, None
+        if t0 is None:
+            return
+        end = _now()
+        if (end - t0) * 1e3 >= GC_PAUSE_MIN_MS:
+            self._pauses.append((end, end - t0, info.get("generation"),
+                                 info.get("collected")))
+
+    def _drain(self) -> None:
+        rec = self._recorder if self._recorder is not None \
+            else get_recorder()
+        while self._pauses:
+            end, dur, generation, collected = self._pauses.popleft()
+            rec.event("runtime.gc", generation=generation,
+                      dur_ms=round(dur * 1e3, 4), collected=collected,
+                      end_mono=round(end, 6))
+
+    def _run(self) -> None:
+        while not self._stop.wait(_GC_DRAIN_S):
+            self._drain()
+
+    def install(self) -> "GcPauseEvents":
+        """Hook in and start the writer (once per object)."""
+        gc.callbacks.append(self._hook)
+        self._writer.start()
+        return self
+
+    def remove(self) -> None:
+        """Unhook, stop the writer and write out what is left."""
+        with contextlib.suppress(ValueError):
+            gc.callbacks.remove(self._hook)
+        self._stop.set()
+        if self._writer.is_alive():
+            self._writer.join(timeout=5.0)
+        self._drain()
 
 
 # ---------------------------------------------------------------------------

@@ -101,6 +101,7 @@ class _Request:
     payload: np.ndarray
     future: Future
     deadline: Optional[float]        # absolute time.monotonic() seconds
+    submitted: float                 # time.monotonic() at submit()
 
 
 class DynamicBatcher:
@@ -241,10 +242,11 @@ class DynamicBatcher:
         if self._closed.is_set():
             raise RuntimeError("batcher is closed")
         t_ms = self.default_timeout_ms if timeout_ms is None else timeout_ms
-        deadline = (time.monotonic() + t_ms / 1000.0) if t_ms > 0 else None
+        now = time.monotonic()
+        deadline = (now + t_ms / 1000.0) if t_ms > 0 else None
         fut: Future = Future()
         self._m_requests.inc()
-        self._q.put(_Request(np.asarray(payload), fut, deadline))
+        self._q.put(_Request(np.asarray(payload), fut, deadline, now))
         if self._closed.is_set():
             # close() raced the put above: the worker may already have
             # drained and exited, so this request would hang forever —
@@ -349,6 +351,12 @@ class DynamicBatcher:
             self._release_lane()
             return
         n = len(live)
+        # how long the rows sat queued before this flush began: the
+        # oldest row's wait and the mean, on the flush's own record
+        t0 = time.monotonic()
+        waits_ms = [(t0 - r.submitted) * 1e3 for r in live]
+        waited = {"queue_wait_ms": round(max(waits_ms), 4),
+                  "queue_wait_mean_ms": round(sum(waits_ms) / n, 4)}
         try:
             # the whole batch computation is inside the try: a bad
             # payload (mixed row shapes -> np.stack raises) must fail
@@ -361,15 +369,15 @@ class DynamicBatcher:
                 # the batch on its own worker and the completion callback
                 # scatters results, so the NEXT batch can flush (to
                 # another replica) while this one is still in flight
-                t0 = time.monotonic()
                 fut = self._run_batch_async(rows)
                 fut.add_done_callback(
-                    lambda f: self._complete(f, live, bucket, n, t0))
+                    lambda f: self._complete(f, live, bucket, n, t0,
+                                             waited))
                 return
             rec = self._recorder if self._recorder is not None \
                 else obs_spans.get_recorder()
             with rec.span("batcher.flush", batcher=self.name,
-                          bucket=bucket, rows=n) as flush_span:
+                          bucket=bucket, rows=n, **waited) as flush_span:
                 out = np.asarray(self._run_batch(rows))
         except Exception as exc:
             # batch failure -> every caller sees the error (never a hang)
@@ -384,7 +392,7 @@ class DynamicBatcher:
         self._account_flush(bucket, n, flush_span["dur_ms"])
 
     def _complete(self, f: Future, live: list[_Request], bucket: int,
-                  n: int, t0: float) -> None:
+                  n: int, t0: float, waited: dict) -> None:
         """Async-flush completion (runs on the pool's worker thread):
         scatter per-row results / the batch error, then the same
         accounting as a synchronous flush.  The timed record is an
@@ -403,7 +411,7 @@ class DynamicBatcher:
         rec = self._recorder if self._recorder is not None \
             else obs_spans.get_recorder()
         rec.event("batcher.flush", batcher=self.name, bucket=bucket,
-                  rows=n, dur_ms=dur_ms)
+                  rows=n, dur_ms=dur_ms, **waited)
         self._account_flush(bucket, n, dur_ms)
 
     def _account_flush(self, bucket: int, n: int, dur_ms: float) -> None:

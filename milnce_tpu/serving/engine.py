@@ -36,6 +36,7 @@ at construction, optionally cast to bf16 for MXU-rate inference.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Sequence
 
 import numpy as np
@@ -72,6 +73,79 @@ class ReplicaDead(RuntimeError):
 # import) swaps in the order-checking SanitizedLock; the "dispatch" in
 # its name is what exempts device work under it from graftlint GL012.
 DEVICE_DISPATCH_LOCK = make_lock("serving.device_dispatch")
+
+
+class _Hold:
+    """What a site gets from :func:`device_dispatch`: :meth:`phase`
+    times one leg of the hold into the ``dispatch`` record."""
+
+    __slots__ = ("site", "record")
+
+    def __init__(self, site: str, record: dict):
+        self.site, self.record = site, record
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """``put`` / ``call`` / ``get``: ``<name>_ms`` on the record, and
+        a ``<site>.<name>`` annotation for a running profiler."""
+        t0 = obs_spans.now()
+        try:
+            with obs_spans.annotation(f"{self.site}.{name}"):
+                yield
+        finally:
+            self.record[f"{name}_ms"] = obs_spans.ms_since(t0)
+
+    def round_trip(self, fn, rows, sharding, *resident):
+        """``fn(*resident, rows)`` as every query site runs it, each leg
+        a phase: the explicit ``device_put`` of the host ``rows``, the
+        jitted call's return (the enqueue), the blocking ``device_get``
+        of its result.  Written out, not three :meth:`phase` blocks: this
+        is host time inside the hold, with the device idle."""
+        site, now = self.site, obs_spans.now
+        t0 = now()
+        with obs_spans.annotation(site + ".put"):
+            x = jax.device_put(rows, sharding)
+        t1 = now()
+        with obs_spans.annotation(site + ".call"):
+            out = fn(*resident, x)
+        t2 = now()
+        with obs_spans.annotation(site + ".get"):
+            out = jax.device_get(out)
+        t3 = now()
+        self.record.update(put_ms=round((t1 - t0) * 1e3, 4),
+                           call_ms=round((t2 - t1) * 1e3, 4),
+                           get_ms=round((t3 - t2) * 1e3, 4))
+        return out
+
+
+@contextlib.contextmanager
+def device_dispatch(site: str, *, lock=None, recorder=None, **attrs):
+    """THE way serving code takes a dispatch lock: ``lock`` (default
+    :data:`DEVICE_DISPATCH_LOCK`) with ``jax.transfer_guard("disallow")``
+    inside it, and ONE ``dispatch`` span per hold on ``recorder``
+    (default: the process recorder) carrying ``site``, the caller's
+    ``attrs`` (``rows``, ``bucket``), ``lock_wait_ms`` (call to lock
+    acquired), ``hold_ms`` (acquired to released) and the legs the site
+    marks through the yielded :class:`_Hold`.  The record is written
+    after the release, so the lock still nests over nothing; clock reads
+    only — no device sync is added (OBSERVABILITY.md).  In a profiler
+    session the waiter shows as ``<site>.lock_wait`` and the holder as
+    its phases, so an idle gap of the device names what the holder was
+    doing, not how many were waiting."""
+    lock = DEVICE_DISPATCH_LOCK if lock is None else lock
+    rec = recorder if recorder is not None else obs_spans.get_recorder()
+    with rec.span("dispatch", site=site, **attrs) as record:
+        t0 = obs_spans.now()
+        with obs_spans.annotation(f"{site}.lock_wait"):
+            lock.acquire()
+        record["lock_wait_ms"] = obs_spans.ms_since(t0)
+        t1 = obs_spans.now()
+        try:
+            with jax.transfer_guard("disallow"):
+                yield _Hold(site, record)
+        finally:
+            lock.release()
+            record["hold_ms"] = obs_spans.ms_since(t1)
 
 
 def bucket_ladder(n_dev: int, min_bucket: int, max_batch: int) -> tuple:
@@ -258,9 +332,9 @@ class InferenceEngine:
                               "this replica is now permanently dead")
         # Steady state: implicit transfers are bugs (they stall the async
         # dispatch pipeline); both legs of the request are explicit.
-        with self._dispatch_lock, jax.transfer_guard("disallow"):
-            x = jax.device_put(rows, self._batch_sh)
-            out = jax.device_get(fn(self._variables, x))
+        with device_dispatch(f"engine.{entry}", lock=self._dispatch_lock,
+                             rows=n, bucket=bucket) as hold:
+            out = hold.round_trip(fn, rows, self._batch_sh, self._variables)
         out = np.asarray(out)
         with self._stats_lock:
             self._calls[(entry, bucket)] = \

@@ -39,9 +39,10 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from milnce_tpu.analysis.lockrt import make_lock
+from milnce_tpu.obs import spans as obs_spans
 from milnce_tpu.parallel.mesh import batch_sharding, replicated
 from milnce_tpu.serving.batcher import pad_rows
-from milnce_tpu.serving.engine import DEVICE_DISPATCH_LOCK
+from milnce_tpu.serving.engine import device_dispatch
 
 
 def make_topk_fn(mesh: Mesh, data_axis: str, k: int):
@@ -119,11 +120,6 @@ class DeviceRetrievalIndex:
         # Pad the corpus so rows split evenly AND every shard holds at
         # least k rows (lax.top_k needs k <= local extent).
         rows = max(-(-self.size // n_data), self.k)
-        corpus, valid = shard_corpus(emb, n_data, rows)
-
-        sh_rows = batch_sharding(mesh, data_axis)
-        self._corpus = jax.device_put(corpus, sh_rows)       # device-resident
-        self._valid = jax.device_put(valid, sh_rows)
         self._query_sh = replicated(mesh)
         self._fn = make_topk_fn(mesh, data_axis, self.k)
         # call accounting is hit straight off concurrent request threads
@@ -132,8 +128,24 @@ class DeviceRetrievalIndex:
         self._stats_lock = make_lock("serving.index.stats")
         self._calls = 0
         self._baseline_cache = None
-        if precompile:
-            self.warmup()
+        # the boot log's split of the build (the live index records a
+        # span of the same name per generation)
+        with obs_spans.get_recorder().span("index.build",
+                                           rows=self.size) as span:
+            t0 = obs_spans.now()
+            corpus, valid = shard_corpus(emb, n_data, rows)
+            span["shard_ms"] = obs_spans.ms_since(t0)
+            t0 = obs_spans.now()
+            sh_rows = batch_sharding(mesh, data_axis)
+            self._corpus = jax.device_put(corpus, sh_rows)   # device-resident
+            self._valid = jax.device_put(valid, sh_rows)
+            # host time of the two puts: they return before the copy is
+            # done, and the first scan of the warm-up waits for it
+            span["upload_ms"] = obs_spans.ms_since(t0)
+            t0 = obs_spans.now()
+            if precompile:
+                self.warmup()
+            span["warmup_ms"] = obs_spans.ms_since(t0)
 
     # ---- query path ------------------------------------------------------
 
@@ -153,13 +165,13 @@ class DeviceRetrievalIndex:
             raise ValueError(f"expected (n, {self.dim}) queries, got "
                              f"{q.shape}")
         n = q.shape[0]
-        q = pad_rows(q, self.bucket_for(n))
+        bucket = self.bucket_for(n)
+        q = pad_rows(q, bucket)
         # serialized dispatch: see DEVICE_DISPATCH_LOCK in engine.py —
         # index queries come straight off request threads
-        with DEVICE_DISPATCH_LOCK, jax.transfer_guard("disallow"):
-            qd = jax.device_put(q, self._query_sh)
-            scores, idx = jax.device_get(self._fn(self._corpus, self._valid,
-                                                  qd))
+        with device_dispatch("index.topk", rows=n, bucket=bucket) as hold:
+            scores, idx = hold.round_trip(self._fn, q, self._query_sh,
+                                          self._corpus, self._valid)
         with self._stats_lock:
             self._calls += 1
         return np.asarray(scores)[:n], np.asarray(idx)[:n]

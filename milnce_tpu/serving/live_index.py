@@ -58,7 +58,7 @@ from milnce_tpu.obs import spans as obs_spans
 from milnce_tpu.parallel.mesh import batch_sharding, replicated
 from milnce_tpu.resilience import faults
 from milnce_tpu.serving.batcher import pad_rows
-from milnce_tpu.serving.engine import DEVICE_DISPATCH_LOCK
+from milnce_tpu.serving.engine import device_dispatch
 from milnce_tpu.serving.export import (export_corpus_snapshot,
                                        load_corpus_snapshot)
 from milnce_tpu.serving.index import make_topk_fn, shard_corpus
@@ -232,15 +232,19 @@ class LiveRetrievalIndex:
         rows = shard_rung(host.shape[0], self._n_data, self.k,
                           self._min_shard_rows)
         corpus, valid = shard_corpus(host, self._n_data, rows)
-        with DEVICE_DISPATCH_LOCK, jax.transfer_guard("disallow"):
-            corpus_d = jax.device_put(corpus, self._corpus_sh)
-            valid_d = jax.device_put(valid, self._corpus_sh)
+        with device_dispatch("index.upload", recorder=self._recorder,
+                             rows=host.shape[0], bucket=rows) as hold:
+            with hold.phase("put"):
+                corpus_d = jax.device_put(corpus, self._corpus_sh)
+                valid_d = jax.device_put(valid, self._corpus_sh)
         return _Generation(gen, host, rows, corpus_d, valid_d)
 
-    def _dispatch(self, g: _Generation, q_padded: np.ndarray):
-        with DEVICE_DISPATCH_LOCK, jax.transfer_guard("disallow"):
-            qd = jax.device_put(q_padded, self._query_sh)
-            scores, idx = jax.device_get(self._fn(g.corpus, g.valid, qd))
+    def _dispatch(self, g: _Generation, q_padded: np.ndarray, n: int):
+        """Top-k of the ``n`` live rows of ``q_padded`` over ``g``."""
+        with device_dispatch("index.topk", recorder=self._recorder,
+                             rows=n, bucket=q_padded.shape[0]) as hold:
+            scores, idx = hold.round_trip(self._fn, q_padded, self._query_sh,
+                                          g.corpus, g.valid)
         return np.asarray(scores), np.asarray(idx)
 
     def _warm_rung(self, g: _Generation) -> None:
@@ -263,7 +267,7 @@ class LiveRetrievalIndex:
             self._warming_recompiles = pre
         try:
             for b in self.query_buckets:
-                self._dispatch(g, np.zeros((b, self.dim), np.float32))
+                self._dispatch(g, np.zeros((b, self.dim), np.float32), b)
             self._m_builder_compiles.inc()
             size = getattr(self._fn, "_cache_size", None)
             baseline = int(size()) if size is not None else None
@@ -299,7 +303,7 @@ class LiveRetrievalIndex:
             raise ValueError(f"corpus holds {g.size} rows < k={self.k} — "
                              "ingest more before querying")
         n = q.shape[0]
-        scores, idx = self._dispatch(g, pad_rows(q, self.bucket_for(n)))
+        scores, idx = self._dispatch(g, pad_rows(q, self.bucket_for(n)), n)
         with self._state_lock:
             self._calls += 1
         return scores[:n], idx[:n], g.gen

@@ -386,6 +386,10 @@ class RetrievalService:
         # in the same process — must divert this service's spans and the
         # ``/obs/events`` ring together, never split them
         self._recorder = recorder
+        # build_server's collector-pause hook (obs/spans.py
+        # GcPauseEvents), removed by close_server; None when the service
+        # was built by hand
+        self.gc_pauses = None
         # admission controller (the bounded global queue + feasibility
         # shed): max_inflight=0 keeps the overload bound off but the
         # controller still meters in-flight rows for /healthz
@@ -467,7 +471,8 @@ class RetrievalService:
     def embed_text_ids(self, token_ids: np.ndarray,
                        timeout_ms: Optional[float] = None,
                        tier: Optional[str] = None,
-                       replica_class: Optional[str] = None) -> np.ndarray:
+                       replica_class: Optional[str] = None,
+                       note: Optional[dict] = None) -> np.ndarray:
         """(n, W) int32 -> (n, D): cache hits answered on host, misses
         batched through the engine; results land back in the cache.
 
@@ -484,7 +489,12 @@ class RetrievalService:
         batcher's queue is class-blind, and cached rows carry no class
         stamp — an edge-tier int8 embedding silently answering a later
         full-precision request would mix tiers.  None (the default)
-        batches across every class as usual."""
+        batches across every class as usual.
+
+        ``note``: a record (the ``query`` span) that gets ``cache_hits``
+        and ``embed_wait_ms`` — first submit to last row resolved, 0
+        for a call of hits only."""
+        note = {} if note is None else note
         rows = np.ascontiguousarray(token_ids, dtype=np.int32)
         if rows.ndim != 2:
             raise ValueError(f"expected (n, W) token ids, got {rows.shape}")
@@ -498,11 +508,18 @@ class RetrievalService:
         eff_timeout_ms = (self._default_timeout_ms if timeout_ms is None
                           else float(timeout_ms))
         with self._admission.admit(rows.shape[0], eff_timeout_ms, tier):
+            t0 = obs_spans.now()
             if replica_class is not None:
-                return self._embed_class_pinned(rows, replica_class)
+                try:
+                    return self._embed_class_pinned(rows, replica_class)
+                finally:
+                    note.update(cache_hits=0,
+                                embed_wait_ms=obs_spans.ms_since(t0))
             keys = [token_key(r) for r in rows]
             out: list[Optional[np.ndarray]] = [self.cache.get(k)
                                                for k in keys]
+            note.update(cache_hits=sum(hit is not None for hit in out),
+                        embed_wait_ms=0.0)
             pending = [(i, self._batcher.submit(rows[i], timeout_ms))
                        for i, hit in enumerate(out) if hit is None]
             wait = self._result_wait_s(timeout_ms)
@@ -519,6 +536,8 @@ class RetrievalService:
                         reason) from exc
                 self.cache.put(keys[i], row)
                 out[i] = row
+            if pending:
+                note["embed_wait_ms"] = obs_spans.ms_since(t0)
             return np.stack(out) if out else np.zeros(
                 (0, self.engine.embed_dim or 0), np.float32)
 
@@ -565,26 +584,35 @@ class RetrievalService:
         index generation).  The generation is the freshness stamp a
         live index answers with (``/v1/query`` surfaces it as
         ``index_generation`` so clients can detect a stale read); a
-        frozen index answers None."""
+        frozen index answers None.
+
+        One ``query`` span per call (``rows``, ``cache_hits``,
+        ``embed_wait_ms``, ``topk_ms``; ``error`` on a refusal or a
+        failure): the record's ``mono`` is the answer's instant on the
+        program's own clock."""
         if self.index is None:
             raise ValueError("service built without a retrieval index")
         k = self.index.k if k is None else int(k)
         if not 1 <= k <= self.index.k:
             raise ValueError(f"k={k} outside [1, index k={self.index.k}]")
         self._m_queries.inc(len(token_ids))
-        try:
-            emb = self.embed_text_ids(token_ids, timeout_ms, tier,
-                                      replica_class)
-            if hasattr(self.index, "topk_with_gen"):
-                scores, idx, gen = self.index.topk_with_gen(emb)
-            else:
-                scores, idx = self.index.topk(emb)
-                gen = None
-        except (ShedError, DegradedError, PoolSaturated, PoolUnavailable):
-            raise        # refusals, not failures: counted on their own
-        except Exception:
-            self._m_errors.inc(len(token_ids))
-            raise
+        with self.recorder.span("query", rows=len(token_ids)) as span:
+            try:
+                emb = self.embed_text_ids(token_ids, timeout_ms, tier,
+                                          replica_class, note=span)
+                t0 = obs_spans.now()
+                if hasattr(self.index, "topk_with_gen"):
+                    scores, idx, gen = self.index.topk_with_gen(emb)
+                else:
+                    scores, idx = self.index.topk(emb)
+                    gen = None
+                span["topk_ms"] = obs_spans.ms_since(t0)
+            except (ShedError, DegradedError, PoolSaturated,
+                    PoolUnavailable):
+                raise    # refusals, not failures: counted on their own
+            except Exception:
+                self._m_errors.inc(len(token_ids))
+                raise
         return scores[:, :k], idx[:, :k], gen
 
     def query_ids(self, token_ids: np.ndarray, k: Optional[int] = None,
@@ -880,26 +908,31 @@ def build_server(cfg):
         raise SystemExit("--serve.edge_replicas needs "
                          "--serve.edge_export_dir (the quantized/student "
                          "artifact the edge class serves)")
-    if s.replicas > 1 or edge:
-        from milnce_tpu.serving.pool import ReplicaPool
+    # the boot log: engine.load (the ladder.warmup span inside it),
+    # corpus.load and index.build split the time to "listening"
+    boot = obs_spans.get_recorder()
+    with boot.span("engine.load", replicas=s.replicas):
+        if s.replicas > 1 or edge:
+            from milnce_tpu.serving.pool import ReplicaPool
 
-        engine = ReplicaPool.from_export(
-            s.export_dir, s.replicas, dtype=s.dtype,
-            max_batch=s.max_batch, min_bucket=s.min_bucket,
-            data_axis=cfg.parallel.data_axis,
-            queue_depth=s.replica_queue_depth,
-            error_threshold=s.error_threshold, slo_ms=s.slo_ms,
-            slo_breaches=s.slo_breaches,
-            probe_interval_s=s.probe_interval_s,
-            hedge_quantile=s.hedge_quantile, hedge_min_ms=s.hedge_min_ms,
-            max_requeues=s.max_requeues,
-            edge_export_dir=s.edge_export_dir,
-            edge_replicas=s.edge_replicas,
-            registry=obs_metrics.registry())
-    else:
-        engine = InferenceEngine.from_export(
-            s.export_dir, mesh, dtype=s.dtype, max_batch=s.max_batch,
-            min_bucket=s.min_bucket, data_axis=cfg.parallel.data_axis)
+            engine = ReplicaPool.from_export(
+                s.export_dir, s.replicas, dtype=s.dtype,
+                max_batch=s.max_batch, min_bucket=s.min_bucket,
+                data_axis=cfg.parallel.data_axis,
+                queue_depth=s.replica_queue_depth,
+                error_threshold=s.error_threshold, slo_ms=s.slo_ms,
+                slo_breaches=s.slo_breaches,
+                probe_interval_s=s.probe_interval_s,
+                hedge_quantile=s.hedge_quantile,
+                hedge_min_ms=s.hedge_min_ms,
+                max_requeues=s.max_requeues,
+                edge_export_dir=s.edge_export_dir,
+                edge_replicas=s.edge_replicas,
+                registry=obs_metrics.registry())
+        else:
+            engine = InferenceEngine.from_export(
+                s.export_dir, mesh, dtype=s.dtype, max_batch=s.max_batch,
+                min_bucket=s.min_bucket, data_axis=cfg.parallel.data_axis)
     # sentence requests need a vocab: --serve.token_dict_path wins, else
     # the path the export recorded; with neither, token_ids-only (400s
     # on "sentences" explain themselves)
@@ -922,7 +955,7 @@ def build_server(cfg):
                                            max_words=engine.text_words)
     corpus = None
     if s.corpus_npz:
-        with np.load(s.corpus_npz) as z:
+        with boot.span("corpus.load") as span, np.load(s.corpus_npz) as z:
             if "emb" in z.files:            # the documented contract
                 corpus = z["emb"]
             elif len(z.files) == 1:
@@ -933,6 +966,7 @@ def build_server(cfg):
                     f"{z.files} — store the corpus under the 'emb' key "
                     "(np.savez(..., emb=embeddings)) so the index can't "
                     "silently build over the wrong array")
+            span["rows"] = int(corpus.shape[0])
     index = None
     if s.live_index:
         from milnce_tpu.serving.export import INDEX_METADATA_FILE
@@ -977,6 +1011,7 @@ def build_server(cfg):
         capture=capture, anomaly_ratio=s.anomaly_ratio,
         max_inflight=s.max_inflight, tiers=s.tiers,
         continuous=s.continuous_batching)
+    service.gc_pauses = obs_spans.GcPauseEvents().install()
     return serve_http(service, s.host, s.port), service, index, engine
 
 
@@ -986,6 +1021,9 @@ def close_server(cfg, server, service, index, engine) -> None:
     s = cfg.serve
     server.server_close()
     service.close()
+    if service.gc_pauses is not None:
+        service.gc_pauses.remove()
+        service.gc_pauses = None
     if s.live_index and index is not None:
         if s.index_snapshot_dir:
             # checkpoint the grown corpus so the next boot resumes

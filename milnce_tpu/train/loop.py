@@ -288,8 +288,7 @@ def run_training(cfg: Config, max_steps: Optional[int] = None) -> TrainResult:
         name = ("RUN_EVENTS.jsonl" if process_index == 0
                 else f"RUN_EVENTS.p{process_index}.jsonl")
         rec_path = os.path.join(obs_dir, name)
-    rec = obs_spans.SpanRecorder(
-        path=rec_path, profiler_bridge=cfg.train.obs_profiler_bridge)
+    rec = obs_spans.SpanRecorder(path=rec_path)
     rec.event("run.start", seed=cfg.train.seed,
               batch_size=cfg.train.batch_size,
               processes=jax.process_count())

@@ -325,6 +325,36 @@ def run(fn, x, sh):
     assert _ids(other) == ["GL012", "GL012"]  # device_put + device_get
 
 
+def test_device_dispatch_context_holds_the_dispatch_lock():
+    """``with device_dispatch(site)`` is a hold of the process-wide
+    dispatch lock (or of ``lock=``): device verbs under it stay exempt,
+    any other blocking call under it is GL012, and it joins the
+    lock-order graph under the lock's own id."""
+    src = """
+import time
+import jax
+from milnce_tpu.serving.engine import device_dispatch
+
+def run(fn, x, sh):
+    with device_dispatch("index.topk", rows=1) as hold:
+        out = jax.device_get(fn(jax.device_put(x, sh)))
+    return out
+
+def bad(fn, x):
+    with device_dispatch("index.topk"):
+        time.sleep(1.0)
+"""
+    findings = [f for f in lint_source(src) if not f.suppressed]
+    assert [f.rule.id for f in findings] == ["GL012"]
+    assert "engine:DEVICE_DISPATCH_LOCK" in findings[0].message
+    own = src.replace('device_dispatch("index.topk"):',
+                      'device_dispatch("index.topk", lock=STATS_LOCK):'
+                      ).replace("import time", "import time\nimport "
+                                "threading\nSTATS_LOCK = threading.Lock()")
+    msgs = [f.message for f in lint_source(own) if not f.suppressed]
+    assert len(msgs) == 1 and "STATS_LOCK" in msgs[0]
+
+
 # ---------------------------------------------------------------------------
 # GL000 stale suppressions + the --no-concurrency contract
 # ---------------------------------------------------------------------------

@@ -26,6 +26,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -314,14 +315,40 @@ class TestSpans:
             assert install(prev) is mine
         assert get_recorder() is prev
 
-    def test_profiler_bridge_spans_still_record(self):
-        """opt-in TraceAnnotation bridge: spans must record normally
-        (and not crash) when wrapped in the jax profiler annotation."""
-        rec = SpanRecorder(profiler_bridge=True)
+    def test_spans_are_profiler_annotations_with_no_switch(self):
+        """The TraceAnnotation bridge is always on where jax is imported
+        (it is, in this process): spans record as ever, and
+        ``annotation`` hands out the profiler's own class."""
+        import jax
+
+        from milnce_tpu.obs.spans import annotation
+
+        assert isinstance(annotation("step"), jax.profiler.TraceAnnotation)
+        rec = SpanRecorder()
         with rec.span("step", step=1):
             pass
         last = rec.tail()[-1]
         assert last["name"] == "step" and last["dur_ms"] >= 0
+
+    def test_span_imports_no_jax_in_a_process_without_it(self):
+        """A host-only process (a loader) that records spans stays
+        host-only: the bridge reads ``sys.modules`` and imports
+        nothing."""
+        code = ("import sys\n"
+                "from milnce_tpu.obs.spans import SpanRecorder, annotation\n"
+                "rec = SpanRecorder()\n"
+                "with rec.span('decode', sample=1):\n"
+                "    rec.event('decode.retry')\n"
+                "assert rec.tail()[-1]['name'] == 'decode'\n"
+                "assert type(annotation('x')).__name__ == 'nullcontext'\n"
+                "assert not [m for m in sys.modules if m == 'jax' "
+                "or m.startswith('jax.')], 'span() imported jax'\n"
+                "print('HOST_ONLY_OK')\n")
+        proc = subprocess.run([sys.executable, "-c", code],
+                              capture_output=True, text=True, timeout=120,
+                              cwd=_REPO)
+        assert proc.returncode == 0, proc.stderr
+        assert "HOST_ONLY_OK" in proc.stdout
 
     def test_close_is_idempotent(self, tmp_path):
         rec = SpanRecorder(path=str(tmp_path / "x.jsonl"))
@@ -330,6 +357,63 @@ class TestSpans:
         rec.close()
         rec.event("ring_only_after_close")    # must not raise
         assert rec.tail()[-1]["name"] == "ring_only_after_close"
+
+
+class TestGcPauseEvents:
+    def test_only_a_long_collection_is_an_event(self, monkeypatch):
+        """The hook itself, on a clock the test moves: 12 ms is an event
+        with its generation, count and the pause's own end; 1 ms is
+        not; nothing is written from inside the hook."""
+        from milnce_tpu.obs import spans
+
+        clock = [100.0]
+        monkeypatch.setattr(spans, "_now", lambda: clock[0])
+        rec = SpanRecorder()
+        pauses = spans.GcPauseEvents(rec)
+        for dur_s, gen in ((0.012, 2), (0.001, 0)):
+            pauses._hook("start", {"generation": gen})
+            clock[0] += dur_s
+            pauses._hook("stop", {"generation": gen, "collected": 7})
+        assert rec.tail() == []         # the hook takes no lock
+        pauses.remove()                 # never installed: drains only
+        (ev,) = rec.tail()
+        assert ev["name"] == "runtime.gc" and ev["kind"] == "event"
+        assert (ev["generation"], ev["collected"]) == (2, 7)
+        assert ev["dur_ms"] == pytest.approx(12.0)
+        assert ev["end_mono"] == pytest.approx(100.012)
+        assert spans.GC_PAUSE_MIN_MS == 5.0
+
+    def test_install_hooks_gc_and_remove_unhooks(self):
+        import gc
+
+        from milnce_tpu.obs import spans
+
+        rec = SpanRecorder()
+        before = list(gc.callbacks)
+        pauses = spans.GcPauseEvents(rec).install()
+        try:
+            assert len(gc.callbacks) == len(before) + 1
+            junk = []
+            for _ in range(400_000):    # cycles only a collection frees
+                a = []
+                a.append(a)
+                junk.append(a)
+            del junk, a
+            gc.collect()
+
+            def mine():     # building the junk sets off passes of its own
+                return [e for e in rec.tail() if e["name"] == "runtime.gc"
+                        and e["collected"] >= 400_000]
+
+            deadline = time.monotonic() + 5.0
+            while not mine() and time.monotonic() < deadline:
+                time.sleep(0.01)        # the writer thread's 50 ms
+            (ev,) = mine()
+        finally:
+            pauses.remove()
+        assert gc.callbacks == before
+        assert ev["generation"] == 2
+        assert ev["dur_ms"] >= spans.GC_PAUSE_MIN_MS
 
 
 # ---------------------------------------------------------------------------

@@ -578,3 +578,375 @@ class TestHTTP:
             service.capture = old_cap
             server.shutdown()
             server.server_close()
+
+# ---------------------------------------------------------------------------
+# the request path's own clock (ISSUE 26): dispatch / query / queue wait
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def ring():
+    """A fresh process-default recorder for one test."""
+    from milnce_tpu.obs import spans
+
+    rec = spans.SpanRecorder(ring=4096)
+    prev = spans.install(rec)
+    yield rec
+    spans.install(prev)
+
+
+def _named(rec, name, **attrs):
+    return [r for r in rec.tail() if r.get("name") == name
+            and all(r.get(k) == v for k, v in attrs.items())]
+
+
+def _fresh_rows(seed, n=1):
+    return np.random.default_rng(seed).integers(
+        1, 64, (n, _WORDS)).astype(np.int32)
+
+
+class TestDispatchSpans:
+    def _drive(self, stack, site):
+        """One hold of the lock at ``site`` -> (rows, bucket) it carried."""
+        eng = stack["engine"]
+        if site == "engine.text":
+            eng.embed_text(np.ones((3, _WORDS), np.int32))
+            return 3, 8
+        if site == "engine.video":
+            eng.embed_video(stack["clips"][:9])
+            return 9, 16
+        if site == "index.topk":
+            stack["index"].topk(stack["corpus_emb"][:2])
+            return 2, 8
+        raise AssertionError(site)
+
+    @pytest.mark.parametrize("site", ["engine.text", "engine.video",
+                                      "index.topk"])
+    def test_one_dispatch_record_per_hold(self, stack, ring, site):
+        rows, bucket = self._drive(stack, site)
+        (rec,) = _named(ring, "dispatch")
+        assert rec["kind"] == "span" and rec["site"] == site
+        assert (rec["rows"], rec["bucket"]) == (rows, bucket)
+        assert rec["lock_wait_ms"] >= 0 and rec["hold_ms"] > 0
+        # wait and hold lie inside the span, the three legs inside the hold
+        assert rec["lock_wait_ms"] + rec["hold_ms"] <= rec["dur_ms"] + 0.01
+        legs = rec["put_ms"] + rec["call_ms"] + rec["get_ms"]
+        assert 0 < legs <= rec["hold_ms"] + 0.01
+
+    def test_live_index_sites_upload_and_topk(self, stack, ring):
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.live_index import LiveRetrievalIndex
+
+        mine = SpanRecorder()       # the injected recorder gets them
+        live = LiveRetrievalIndex(stack["mesh"], stack["corpus_emb"], k=5,
+                                  query_buckets=(8,), recorder=mine)
+        try:
+            (up,) = _named(mine, "dispatch", site="index.upload")
+            assert up["rows"] == _CORPUS and up["bucket"] >= 5
+            assert up["put_ms"] <= up["hold_ms"] + 0.01
+            warm = len(_named(mine, "dispatch", site="index.topk"))
+            assert warm == 1            # the one query bucket, warmed
+            live.topk(stack["corpus_emb"][:3])
+            recs = _named(mine, "dispatch", site="index.topk")
+            assert len(recs) == warm + 1
+            assert (recs[-1]["rows"], recs[-1]["bucket"]) == (3, 8)
+            assert recs[-1]["lock_wait_ms"] + recs[-1]["hold_ms"] \
+                <= recs[-1]["dur_ms"] + 0.01
+            assert not _named(ring, "dispatch")
+        finally:
+            live.close()
+
+    def test_second_contender_waits_out_the_first_hold(self):
+        """Two threads ask for one lock while a third holds it: whoever
+        gets it second waited at least as long as the first held it."""
+        import time
+
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.engine import device_dispatch
+
+        rec, lock = SpanRecorder(), threading.Lock()
+        asking = threading.Barrier(3)
+
+        def contender(i):
+            asking.wait(timeout=10)
+            with device_dispatch("test.site", lock=lock, recorder=rec,
+                                 rows=i):
+                time.sleep(0.03)
+
+        threads = [threading.Thread(target=contender, args=(i,))
+                   for i in range(2)]
+        with lock:
+            for t in threads:
+                t.start()
+            asking.wait(timeout=10)
+            time.sleep(0.05)            # both are inside acquire() now
+        for t in threads:
+            t.join(timeout=10)
+            assert not t.is_alive()
+        first, second = sorted(_named(rec, "dispatch"),
+                               key=lambda r: r["lock_wait_ms"])
+        assert first["hold_ms"] >= 30.0
+        assert second["lock_wait_ms"] >= first["lock_wait_ms"] \
+            + first["hold_ms"] - 1.0
+        assert second["lock_wait_ms"] >= first["hold_ms"]
+
+    def test_a_failing_hold_still_records_and_frees_the_lock(self):
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.engine import device_dispatch
+
+        rec, lock = SpanRecorder(), threading.Lock()
+        with pytest.raises(KeyError):
+            with device_dispatch("test.site", lock=lock, recorder=rec):
+                raise KeyError("boom")
+        (r,) = _named(rec, "dispatch")
+        assert r["error"] == "KeyError" and "hold_ms" in r
+        assert not lock.locked()
+
+    def test_the_lock_is_taken_only_through_device_dispatch(self):
+        """A grep: under milnce_tpu/serving/ nothing enters a dispatch
+        lock or the transfer guard on its own."""
+        import os
+        import re
+
+        import milnce_tpu.serving as serving
+
+        root = os.path.dirname(serving.__file__)
+        lock = r"(?:[\w.]*\.)?(?:DEVICE_DISPATCH_LOCK|_dispatch_lock)\b"
+        taken = re.compile(rf"with\s+{lock}|{lock}\.acquire\("
+                           r"|transfer_guard\(")
+        hits = []
+        for name in sorted(os.listdir(root)):
+            if not name.endswith(".py"):
+                continue
+            with open(os.path.join(root, name)) as fh:
+                src = fh.read()
+            if name == "engine.py":     # the one place that may
+                head, rest = src.split("def device_dispatch(", 1)
+                body, tail = rest.split("\ndef ", 1)
+                assert "lock.acquire()" in body
+                assert 'transfer_guard("disallow")' in body
+                src = head + tail
+            code = "\n".join(line.split("#", 1)[0]
+                             for line in src.splitlines())
+            code = re.sub(r'"""(?s:.*?)"""', "", code)
+            hits += [(name, m.group(0)) for m in taken.finditer(code)]
+        assert not hits, hits
+
+
+class TestQuerySpan:
+    def test_miss_then_hit_then_mixed(self, stack, ring):
+        svc = stack["service"]
+        new = _fresh_rows(2601, 2)
+        svc.query_ids(new)
+        svc.query_ids(new)                          # both cached now
+        svc.query_ids(np.concatenate([new[:1], _fresh_rows(2602, 1)]))
+        miss, hit, mixed = _named(ring, "query")
+        assert [r["rows"] for r in (miss, hit, mixed)] == [2, 2, 2]
+        assert [r["cache_hits"] for r in (miss, hit, mixed)] == [0, 2, 1]
+        assert miss["embed_wait_ms"] > 0 and mixed["embed_wait_ms"] > 0
+        assert hit["embed_wait_ms"] == 0
+        for r in (miss, hit, mixed):
+            assert 0 < r["topk_ms"] <= r["dur_ms"] and "error" not in r
+            assert r["embed_wait_ms"] + r["topk_ms"] <= r["dur_ms"] + 0.01
+        # a call of hits only holds the lock once (its scan); a miss
+        # twice (flush and scan)
+        sites = [r["site"] for r in _named(ring, "dispatch")]
+        assert sites.count("index.topk") == 3
+        assert sites.count("engine.text") == 2
+
+    def test_a_shed_call_records_its_error(self, stack, ring):
+        from milnce_tpu.serving.service import RetrievalService, ShedError
+
+        svc = RetrievalService(stack["engine"], stack["index"],
+                               max_inflight=1)
+        try:
+            with pytest.raises(ShedError):
+                svc.query_ids(_fresh_rows(2603, 2))
+        finally:
+            svc.close()
+        (r,) = _named(ring, "query")
+        assert r["error"] == "ShedError" and r["rows"] == 2
+        assert "topk_ms" not in r and not _named(ring, "dispatch")
+
+    def test_a_failing_scan_records_its_error(self, stack, ring,
+                                              monkeypatch):
+        svc = stack["service"]
+
+        def broken(queries):
+            raise RuntimeError("scan failed")
+
+        monkeypatch.setattr(svc.index, "topk", broken)
+        with pytest.raises(RuntimeError):
+            svc.query_ids(_fresh_rows(2604, 1))
+        (r,) = _named(ring, "query")
+        assert r["error"] == "RuntimeError" and r["cache_hits"] == 0
+
+
+class TestQueueWait:
+    @pytest.mark.parametrize("mode", ["sync", "async"])
+    def test_flush_record_carries_the_rows_queue_wait(self, mode):
+        """Two rows 40 ms apart into one flush: the oldest row's wait is
+        the whole delay bound, the mean about 20 ms less — on the span of
+        a synchronous flush and on the event of a pipelined one."""
+        import time
+        from concurrent.futures import Future
+
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.batcher import DynamicBatcher
+
+        def run_async(rows):
+            fut: Future = Future()
+            fut.set_result(rows * 2.0)
+            return fut
+
+        rec = SpanRecorder()
+        b = DynamicBatcher(lambda rows: rows * 2.0, lambda n: 4,
+                           max_batch=4, max_delay_ms=120, recorder=rec,
+                           run_batch_async=(run_async if mode == "async"
+                                            else None))
+        try:
+            first = b.submit(np.ones((3,), np.float32))
+            time.sleep(0.04)
+            second = b.submit(np.ones((3,), np.float32))
+            first.result(timeout=10)
+            second.result(timeout=10)
+            deadline = time.monotonic() + 5.0
+            while not _named(rec, "batcher.flush") \
+                    and time.monotonic() < deadline:
+                time.sleep(0.005)
+        finally:
+            b.close()
+        (flush,) = _named(rec, "batcher.flush")
+        assert flush["kind"] == ("event" if mode == "async" else "span")
+        assert flush["rows"] == 2
+        assert 110.0 <= flush["queue_wait_ms"] < 1000.0
+        assert flush["queue_wait_mean_ms"] <= flush["queue_wait_ms"] - 15.0
+        assert flush["queue_wait_mean_ms"] >= flush["queue_wait_ms"] / 2
+
+
+# ---------------------------------------------------------------------------
+# build_server / close_server: the boot log, the collector hook, and the
+# program's annotations in a profiler session with no flag set
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """``milnce-serve``'s own construction at the tiny preset, over a
+    recorder of its own -> what the tests below look at."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+
+    from milnce_tpu.config import parse_cli
+    from milnce_tpu.models.build import build_model
+    from milnce_tpu.obs import spans
+    from milnce_tpu.serving import service as serving
+    from milnce_tpu.serving.export import export_inference_checkpoint
+
+    work = tmp_path_factory.mktemp("served")
+    cfg = parse_cli([
+        "--preset", "tiny", "--model.inception_blocks", "1",
+        "--parallel.platform", "cpu", "--serve.max_batch", "8",
+        "--serve.topk", "3", "--serve.port", "0",
+        "--serve.export_dir", str(work / "export"),
+        "--serve.corpus_npz", str(work / "corpus.npz")])
+    d = cfg.data
+    shape = (d.num_frames, d.video_size, d.video_size, 3)
+    model = build_model(cfg.model)
+    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1,) + shape),
+                           jnp.zeros((1, d.max_words), jnp.int32))
+    export_inference_checkpoint(
+        cfg.serve.export_dir, jax.device_get(variables["params"]),
+        jax.device_get(variables["batch_stats"]), cfg.model,
+        max_words=d.max_words, video_shape=shape)
+    corpus = np.random.default_rng(5).standard_normal(
+        (40, cfg.model.embedding_dim)).astype(np.float32)
+    np.savez(cfg.serve.corpus_npz, emb=corpus)
+
+    rec = spans.SpanRecorder(ring=1 << 14)
+    prev = spans.install(rec)
+    hooks_before = list(gc.callbacks)
+    built = serving.build_server(cfg)
+    out = dict(cfg=cfg, rec=rec, built=built, work=work,
+               hooks_before=hooks_before, closed=False)
+    yield out
+    if not out["closed"]:
+        serving.close_server(cfg, *built)
+    spans.install(prev)
+
+
+class TestBuiltServer:
+    def test_boot_log_splits_the_set_up(self, served):
+        rec = served["rec"]
+        (load,) = _named(rec, "engine.load")
+        (warm,) = _named(rec, "ladder.warmup")
+        (corpus,) = _named(rec, "corpus.load")
+        (build,) = _named(rec, "index.build")
+        assert warm["dur_ms"] <= load["dur_ms"] and corpus["rows"] == 40
+        assert build["rows"] == 40
+        parts = build["shard_ms"] + build["upload_ms"] + build["warmup_ms"]
+        assert 0 < parts <= build["dur_ms"] + 0.01
+        # the index's warm-up scans are dispatch records of their own
+        assert _named(rec, "dispatch", site="index.topk")
+
+    def test_a_profiler_session_shows_the_programs_annotations(self, served):
+        """No flag, no option: start a trace around one served query and
+        the program's own names are on /host:CPU."""
+        import glob
+        import os
+
+        import jax
+
+        _server, service, _index, _engine = served["built"]
+        trace_dir = str(served["work"] / "trace")
+        jax.profiler.start_trace(trace_dir)
+        try:
+            rows = np.random.default_rng(77).integers(
+                1, 100, (1, served["cfg"].data.max_words)).astype(np.int32)
+            service.query_ids(rows)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        names = {ev.name for plane in data.planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for ev in line.events}
+        want = {"query", "dispatch", "batcher.flush"}
+        for site in ("engine.text", "index.topk"):
+            want |= {f"{site}.{leg}"
+                     for leg in ("lock_wait", "put", "call", "get")}
+        assert want <= names, sorted(want - names)
+
+    def test_collector_pause_recorded_and_hook_gone_after_close(self,
+                                                                 served):
+        import gc
+        import time
+
+        from milnce_tpu.serving import service as serving
+
+        _server, service, _index, _engine = served["built"]
+        assert service.gc_pauses is not None
+        assert len(gc.callbacks) == len(served["hooks_before"]) + 1
+        junk = []
+        for _ in range(400_000):
+            a = []
+            a.append(a)
+            junk.append(a)
+        del junk, a
+        gc.collect()
+
+        def mine():     # building the junk sets off passes of its own
+            return [e for e in _named(served["rec"], "runtime.gc")
+                    if e["collected"] >= 400_000]
+
+        deadline = time.monotonic() + 5.0
+        while not mine() and time.monotonic() < deadline:
+            time.sleep(0.01)            # the writer thread's 50 ms
+        (ev,) = mine()
+        assert ev["generation"] == 2 and ev["dur_ms"] >= 5.0
+        assert ev["end_mono"] <= ev["mono"]
+        serving.close_server(served["cfg"], *served["built"])
+        served["closed"] = True
+        assert gc.callbacks == served["hooks_before"]
+        assert service.gc_pauses is None
