@@ -38,6 +38,14 @@ nearest pending deadline so expiry is prompt, not discovered at the
 next size/delay flush.  A deadline does NOT abort device work already
 in flight: once a batch is submitted its rows get their results.
 
+**Blocks** (``submit_block``): a request may be a block of rows that
+must share one batch (a multi-row retrieval call: one scan, one index
+generation).  A block is never split: one that would take the forming
+batch past the top bucket is held over and leads the next batch.  What
+a batch returns need not be one array either: ``take`` says how a
+request's rows are cut out of it (the scan coalescer of service.py gets
+scores, indices and the generation that ranked them).
+
 numpy-only on purpose: payloads and results are host arrays; every
 device interaction lives behind the injected ``run_batch`` callable.
 Thread safety: ``submit`` may be called from any number of threads;
@@ -98,10 +106,12 @@ def pad_rows(rows: np.ndarray, bucket: int) -> np.ndarray:
 
 @dataclass
 class _Request:
-    payload: np.ndarray
+    payload: np.ndarray              # (rows, ...) — a lone row is (1, ...)
     future: Future
     deadline: Optional[float]        # absolute time.monotonic() seconds
     submitted: float                 # time.monotonic() at submit()
+    rows: int = 1
+    block: bool = False              # resolves to its rows, not to row i
 
 
 class DynamicBatcher:
@@ -135,6 +145,15 @@ class DynamicBatcher:
       company that isn't coming).
     - ``lanes``: concurrently-in-flight batch bound in continuous mode
       (the pool's replica count in pipelined mode, else 1).
+    - ``take(out, at)``: a request's share of what the batch returned;
+      ``at`` is the row index of a ``submit`` and the slice of a
+      ``submit_block``.  None = ``out`` is one array and the share is
+      ``out[at]``.
+    - ``pad``: False hands ``run_batch`` the live rows alone (the
+      executor pads to its own ladder and sees how many rows are real);
+      ``bucket_for`` then only names the bucket on the flush record.
+    - ``span_name``: the flush record's name, so that two batchers of
+      one service can be told apart by it (OBSERVABILITY.md).
     """
 
     def __init__(self, run_batch: Callable[[np.ndarray], np.ndarray],
@@ -147,9 +166,14 @@ class DynamicBatcher:
                  on_flush: Optional[Callable[[float, int], None]] = None,
                  run_batch_async: Optional[Callable[[np.ndarray],
                                                     Future]] = None,
-                 continuous: bool = False, lanes: int = 1):
+                 continuous: bool = False, lanes: int = 1,
+                 take: Optional[Callable] = None, pad: bool = True,
+                 span_name: str = "batcher.flush"):
         assert max_batch >= 1
         self._run_batch = run_batch
+        self._take = take
+        self._pad = bool(pad)
+        self._span_name = span_name
         self._run_batch_async = run_batch_async
         self.continuous = bool(continuous)
         # in-flight batch bound for continuous mode: acquired by the
@@ -173,6 +197,10 @@ class DynamicBatcher:
         self.default_timeout_ms = float(default_timeout_ms)
         self.name = name
         self._q: queue.Queue[_Request] = queue.Queue()
+        # a block that did not fit the batch being formed: it leads the
+        # next one.  The worker's alone (set, taken and failed at close
+        # on its thread)
+        self._carry: Optional[_Request] = None
         self._closed = threading.Event()
         self.registry = registry if registry is not None \
             else obs_metrics.MetricsRegistry()
@@ -239,14 +267,26 @@ class DynamicBatcher:
 
         ``timeout_ms``: deadline for THIS request (None = the batcher
         default; <= 0 = no deadline)."""
+        return self._enqueue(np.asarray(payload)[None], timeout_ms, False)
+
+    def submit_block(self, rows: np.ndarray,
+                     timeout_ms: Optional[float] = None) -> Future:
+        """Enqueue ``(n, ...)`` rows that ride ONE batch; the Future
+        resolves to their share of it, in order.  More rows than the top
+        bucket fail with ``bucket_for``'s error."""
+        return self._enqueue(np.asarray(rows), timeout_ms, True)
+
+    def _enqueue(self, payload: np.ndarray, timeout_ms: Optional[float],
+                 block: bool) -> Future:
         if self._closed.is_set():
             raise RuntimeError("batcher is closed")
         t_ms = self.default_timeout_ms if timeout_ms is None else timeout_ms
         now = time.monotonic()
         deadline = (now + t_ms / 1000.0) if t_ms > 0 else None
         fut: Future = Future()
-        self._m_requests.inc()
-        self._q.put(_Request(np.asarray(payload), fut, deadline, now))
+        n = payload.shape[0]
+        self._m_requests.inc(n)
+        self._q.put(_Request(payload, fut, deadline, now, n, block))
         if self._closed.is_set():
             # close() raced the put above: the worker may already have
             # drained and exited, so this request would hang forever —
@@ -258,17 +298,42 @@ class DynamicBatcher:
     # ---- worker side ----------------------------------------------------
 
     def _run(self) -> None:
-        if self.continuous:
-            self._run_continuous()
-            return
+        (self._run_continuous if self.continuous else self._run_windowed)()
+        if self._carry is not None:
+            self._fail_closed(self._carry)
+            self._carry = None
+        self._drain_closed()
+
+    def _next(self, timeout: Optional[float] = None) -> _Request:
+        """The held-over block first, else the queue's next request
+        (``queue.Empty`` after ``timeout``; None = without waiting)."""
+        if self._carry is not None:
+            r, self._carry = self._carry, None
+            return r
+        if timeout is None:
+            return self._q.get_nowait()
+        return self._q.get(timeout=timeout)
+
+    def _offer(self, batch: list, n: int, r: _Request) -> bool:
+        """``r`` joins ``batch`` (``n`` rows so far) unless that takes the
+        batch past the top bucket: then it is held over, whole.  An empty
+        batch takes anything — a block larger than the top bucket fails
+        alone, at ``bucket_for``."""
+        if batch and n + r.rows > self.max_batch:
+            self._carry = r
+            return False
+        batch.append(r)
+        return True
+
+    def _run_windowed(self) -> None:
         while not self._closed.is_set():
             try:
-                first = self._q.get(timeout=_IDLE_POLL_S)
+                first = self._next(_IDLE_POLL_S)
             except queue.Empty:
                 continue
-            batch = [first]
+            batch, n = [first], first.rows
             flush_at = time.monotonic() + self.max_delay_s
-            while len(batch) < self.max_batch:
+            while n < self.max_batch:
                 wake = flush_at
                 for r in batch:
                     if r.deadline is not None:
@@ -277,30 +342,30 @@ class DynamicBatcher:
                 if remaining <= 0:
                     break
                 try:
-                    batch.append(self._q.get(timeout=remaining))
+                    r = self._q.get(timeout=remaining)
                 except queue.Empty:
                     break        # woke at flush_at or a pending deadline
+                if not self._offer(batch, n, r):
+                    break
+                n += r.rows
             self._flush(batch)
-        self._drain_closed()
 
     def _run_continuous(self) -> None:
         """Continuous batching: flush as soon as a lane is free, fill
         bucket slots from new arrivals while every lane is busy."""
         while not self._closed.is_set():
             try:
-                first = self._q.get(timeout=_IDLE_POLL_S)
+                first = self._next(_IDLE_POLL_S)
             except queue.Empty:
                 continue
             batch = [first]
             self._drain_into(batch)
-            self._set_forming(len(batch))
             got_lane = self._lane_sem.acquire(timeout=_LANE_POLL_S)
             while not got_lane and not self._closed.is_set():
                 # parked on busy lanes: expire aged requests promptly
                 # and keep topping the forming batch up to the bucket
                 batch = self._expire(batch)
                 self._drain_into(batch)
-                self._set_forming(len(batch))
                 got_lane = self._lane_sem.acquire(timeout=_LANE_POLL_S)
             self._set_forming(0)
             if not got_lane:        # closing: fail the collected batch
@@ -308,7 +373,6 @@ class DynamicBatcher:
                     self._fail_closed(r)
                 break
             self._flush(batch)      # the flush resolution frees the lane
-        self._drain_closed()
 
     def _set_forming(self, n: int) -> None:
         with self._forming_lock:
@@ -316,12 +380,19 @@ class DynamicBatcher:
 
     def _drain_into(self, batch: list) -> None:
         """Move whatever is queued RIGHT NOW into ``batch`` (up to the
-        top bucket) without waiting — the continuous-mode accumulator."""
-        while len(batch) < self.max_batch:
+        top bucket) without waiting — the continuous-mode accumulator —
+        and publish the forming rows for :meth:`depth`."""
+        n = sum(r.rows for r in batch)
+        while n < self.max_batch:
             try:
-                batch.append(self._q.get_nowait())
+                r = self._next()
             except queue.Empty:
-                return
+                break
+            if not self._offer(batch, n, r):
+                break
+            n += r.rows
+        self._set_forming(
+            n + (self._carry.rows if self._carry is not None else 0))
 
     def _release_lane(self) -> None:
         if self._lane_sem is not None:
@@ -350,20 +421,22 @@ class DynamicBatcher:
         if not live:
             self._release_lane()
             return
-        n = len(live)
-        # how long the rows sat queued before this flush began: the
-        # oldest row's wait and the mean, on the flush's own record
+        n = sum(r.rows for r in live)
+        # how long the requests sat queued before this flush began: the
+        # oldest one's wait and the mean, on the flush's own record
         t0 = time.monotonic()
         waits_ms = [(t0 - r.submitted) * 1e3 for r in live]
         waited = {"queue_wait_ms": round(max(waits_ms), 4),
-                  "queue_wait_mean_ms": round(sum(waits_ms) / n, 4)}
+                  "queue_wait_mean_ms": round(sum(waits_ms) / len(live), 4)}
         try:
             # the whole batch computation is inside the try: a bad
-            # payload (mixed row shapes -> np.stack raises) must fail
+            # payload (mixed row shapes -> np.concatenate raises) must fail
             # THIS batch's futures, never kill the worker thread — a
             # dead worker would strand every later submit forever
             bucket = self._bucket_for(n)
-            rows = pad_rows(np.stack([r.payload for r in live]), bucket)
+            rows = np.concatenate([r.payload for r in live])
+            if self._pad:
+                rows = pad_rows(rows, bucket)
             if self._run_batch_async is not None:
                 # pipelined mode: submit and move on — the pool resolves
                 # the batch on its own worker and the completion callback
@@ -376,9 +449,9 @@ class DynamicBatcher:
                 return
             rec = self._recorder if self._recorder is not None \
                 else obs_spans.get_recorder()
-            with rec.span("batcher.flush", batcher=self.name,
+            with rec.span(self._span_name, batcher=self.name,
                           bucket=bucket, rows=n, **waited) as flush_span:
-                out = np.asarray(self._run_batch(rows))
+                out = self._run_batch(rows)
         except Exception as exc:
             # batch failure -> every caller sees the error (never a hang)
             self._release_lane()
@@ -387,8 +460,7 @@ class DynamicBatcher:
             self._m_batch_errors.inc()
             return
         self._release_lane()
-        for i, r in enumerate(live):
-            r.future.set_result(out[i])
+        self._scatter(live, out)
         self._account_flush(bucket, n, flush_span["dur_ms"])
 
     def _complete(self, f: Future, live: list[_Request], bucket: int,
@@ -399,20 +471,31 @@ class DynamicBatcher:
         ``event`` with ``dur_ms`` (a span cannot straddle threads)."""
         self._release_lane()            # frees the lane for the NEXT
         try:                            # batch before scattering results
-            out = np.asarray(f.result())
+            out = f.result()
         except Exception as exc:
             for r in live:
                 r.future.set_exception(exc)
             self._m_batch_errors.inc()
             return
-        for i, r in enumerate(live):
-            r.future.set_result(out[i])
+        self._scatter(live, out)
         dur_ms = round((time.monotonic() - t0) * 1e3, 4)
         rec = self._recorder if self._recorder is not None \
             else obs_spans.get_recorder()
-        rec.event("batcher.flush", batcher=self.name, bucket=bucket,
+        rec.event(self._span_name, batcher=self.name, bucket=bucket,
                   rows=n, dur_ms=dur_ms, **waited)
         self._account_flush(bucket, n, dur_ms)
+
+    def _scatter(self, live: list[_Request], out) -> None:
+        """Each request its share of what the batch returned, in the
+        order the rows went in."""
+        take = self._take
+        if take is None:
+            out = np.asarray(out)
+        lo = 0
+        for r in live:
+            at = slice(lo, lo + r.rows) if r.block else lo
+            r.future.set_result(out[at] if take is None else take(out, at))
+            lo += r.rows
 
     def _account_flush(self, bucket: int, n: int, dur_ms: float) -> None:
         self._m_flushes.inc()

@@ -5,7 +5,9 @@ Request flow for a text query (the full tentpole path)::
     sentence --tokenizer--> token row --cache?--> hit: cached embedding
                                       \\--miss--> DynamicBatcher (pad to
                                       bucket) --> InferenceEngine.embed_text
-    embedding --> DeviceRetrievalIndex.topk --> (scores, corpus indices)
+    embedding --> scan coalescer (every row that is waiting, up to the
+                  index's top bucket, in ONE pass) -->
+                  DeviceRetrievalIndex.topk --> (scores, corpus indices)
 
 Everything device-side is pre-traced and transfer-guarded (engine.py /
 index.py); everything host-side is stdlib + numpy.  The HTTP front is
@@ -435,6 +437,23 @@ class RetrievalService:
                 self._admission.observe_flush(dur_ms, rows)
 
             self._pool.set_on_latency(_on_dispatch)
+        # The scan coalescer: ONE worker owns this service's calls of
+        # ``index.topk``.  It scans the instant no scan is in flight with
+        # whatever rows are waiting (hits and misses alike, a call's rows
+        # together), and rows that arrive meanwhile ride the next pass —
+        # the text batcher's continuous mode with one lane, so a lone
+        # caller pays a thread hand-off and no window.  Always on:
+        # ``max_delay_ms`` and ``continuous`` govern the text batcher only.
+        self._scans = None
+        if index is not None:
+            ladder = getattr(index, "query_buckets", None) or engine.buckets
+            self._scans = DynamicBatcher(
+                self._scan, getattr(index, "bucket_for", engine.bucket_for),
+                max_batch=ladder[-1], default_timeout_ms=default_timeout_ms,
+                name="topk", registry=self.registry, buckets=engine.buckets,
+                recorder=recorder, continuous=True, lanes=1, pad=False,
+                take=lambda out, at: (out[0][at], out[1][at], out[2]),
+                span_name="topk.flush")
         self._default_timeout_ms = float(default_timeout_ms)
         self._m_degraded = self.registry.counter(
             "milnce_serve_degraded_total",
@@ -586,10 +605,15 @@ class RetrievalService:
         ``index_generation`` so clients can detect a stale read); a
         frozen index answers None.
 
+        The call's rows ride one scan of the coalescer, with whatever
+        other callers' rows are waiting, so the generation is the one
+        that ranked every row of the call; ``timeout_ms`` bounds the
+        wait for that scan as it bounds the wait for the text flush.
+
         One ``query`` span per call (``rows``, ``cache_hits``,
-        ``embed_wait_ms``, ``topk_ms``; ``error`` on a refusal or a
-        failure): the record's ``mono`` is the answer's instant on the
-        program's own clock."""
+        ``embed_wait_ms``, ``topk_ms``: handed to the coalescer until
+        answered; ``error`` on a refusal or a failure): the record's
+        ``mono`` is the answer's instant on the program's own clock."""
         if self.index is None:
             raise ValueError("service built without a retrieval index")
         k = self.index.k if k is None else int(k)
@@ -601,11 +625,9 @@ class RetrievalService:
                 emb = self.embed_text_ids(token_ids, timeout_ms, tier,
                                           replica_class, note=span)
                 t0 = obs_spans.now()
-                if hasattr(self.index, "topk_with_gen"):
-                    scores, idx, gen = self.index.topk_with_gen(emb)
-                else:
-                    scores, idx = self.index.topk(emb)
-                    gen = None
+                scores, idx, gen = self._scans.submit_block(
+                    emb, timeout_ms).result(
+                        timeout=self._result_wait_s(timeout_ms))
                 span["topk_ms"] = obs_spans.ms_since(t0)
             except (ShedError, DegradedError, PoolSaturated,
                     PoolUnavailable):
@@ -614,6 +636,16 @@ class RetrievalService:
                 self._m_errors.inc(len(token_ids))
                 raise
         return scores[:, :k], idx[:, :k], gen
+
+    def _scan(self, emb: np.ndarray) -> tuple:
+        """One pass over the index for the rows the coalescer gathered
+        -> (scores, indices, generation; None from a frozen index).  The
+        index's method is looked up at every scan: whoever replaces
+        ``index.topk`` on the instance changes what callers are
+        answered."""
+        if hasattr(self.index, "topk_with_gen"):
+            return self.index.topk_with_gen(emb)
+        return (*self.index.topk(emb), None)
 
     def query_ids(self, token_ids: np.ndarray, k: Optional[int] = None,
                   timeout_ms: Optional[float] = None,
@@ -686,6 +718,8 @@ class RetrievalService:
             "query_errors": int(self._m_errors.value),
             "engine": self.engine.stats(),
             "batcher": self._batcher.stats(),
+            "scans": (self._scans.stats() if self._scans is not None
+                      else None),
             "cache": self.cache.stats(),
             "index": self.index.stats() if self.index is not None else None,
             "admission": self._admission.stats(),
@@ -710,6 +744,8 @@ class RetrievalService:
 
     def close(self) -> None:
         self._batcher.close()
+        if self._scans is not None:
+            self._scans.close()
 
 
 # ---------------------------------------------------------------------------

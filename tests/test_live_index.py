@@ -280,6 +280,66 @@ class TestSwapChaos:
             faults.parse_spec("index.typo@*")
 
 
+class TestCoalescedGeneration:
+    def test_every_call_is_stamped_with_the_generation_that_ranked_it(
+            self, stack):
+        """Callers hammer the service through the scan coalescer while a
+        swap publishes one new row per generation, planted to win one
+        probe query: an answer that holds a row carries a generation in
+        which that row was live, and no caller sees a generation go
+        backwards."""
+        import threading
+
+        service, index, engine = (stack["service"], stack["index"],
+                                  stack["engine"])
+        probe = np.random.default_rng(77).integers(
+            1, 64, (1, _WORDS)).astype(np.int32)
+        q = engine.embed_text(probe)[0]
+        base = index.size
+        born = {}                           # row -> generation it came in
+        stop = threading.Event()
+        seen = [[] for _ in range(6)]
+        errors = []
+
+        def caller(c):
+            try:
+                while not stop.is_set():
+                    _, idx, gen = service.query_ids_with_gen(probe)
+                    seen[c].append((gen, idx[0].tolist()))
+            except Exception as exc:                 # noqa: BLE001
+                errors.append(exc)
+
+        threads = [threading.Thread(target=caller, args=(c,), daemon=True)
+                   for c in range(6)]
+        for t in threads:
+            t.start()
+        try:
+            for step in range(3):
+                # a row far along the probe's direction ranks first
+                service.index_add(embeddings=(q * (50.0 + step))[None],
+                                  wait=True)
+                born[base + step] = index.generation
+        finally:
+            stop.set()
+            for t in threads:
+                t.join(30)
+        assert not errors, errors
+        assert all(s for s in seen)
+        for answers in seen:
+            gens = [g for g, _ in answers]
+            assert gens == sorted(gens)
+            for gen, idx in answers:
+                for row, gen_born in born.items():
+                    if row in idx:
+                        assert gen >= gen_born, (row, gen, gen_born)
+                    elif gen >= gen_born:
+                        # live in the generation that ranked this call:
+                        # it wins, so it must be in the answer
+                        raise AssertionError((row, gen, idx))
+        _, idx, gen = service.query_ids_with_gen(probe)
+        assert gen == index.generation and idx[0, 0] == base + 2
+
+
 class TestSnapshotRestore:
     def test_snapshot_restore_query_bit_exact_round_trip(self, stack,
                                                          tmp_path):

@@ -1,0 +1,441 @@
+"""The scan coalescer of ``RetrievalService`` (ISSUE 27): every caller
+that is waiting shares ONE pass over the index.  Stub engine and stub
+index (numpy only, no device): the index counts its scans and can hold
+one in flight while the test lines callers up behind it."""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from milnce_tpu.obs import metrics as obs_metrics
+from milnce_tpu.obs import spans as obs_spans
+from milnce_tpu.serving.batcher import DeadlineExpired
+from milnce_tpu.serving.cache import EmbeddingLRUCache
+from milnce_tpu.serving.service import RetrievalService, ShedError
+
+_WORDS, _DIM, _K = 4, 8, 3
+_LADDER = (4, 8)
+
+
+def _bucket_for(n):
+    for b in _LADDER:
+        if n <= b:
+            return b
+    raise ValueError(f"{n} queries exceeds the top query bucket "
+                     f"{_LADDER[-1]}")
+
+
+class _Engine:
+    """``embed_text`` is a fixed linear map of the token ids."""
+
+    buckets = _LADDER
+    max_batch = _LADDER[-1]
+    text_words = _WORDS
+    embed_dim = _DIM
+    bucket_for = staticmethod(_bucket_for)
+
+    def __init__(self):
+        self._w = np.random.default_rng(0).normal(
+            size=(_WORDS, _DIM)).astype(np.float32)
+
+    def embed_text(self, rows):
+        return rows.astype(np.float32) @ self._w
+
+    def recompiles(self):
+        return 0
+
+    def stats(self):
+        return {}
+
+
+class _Index:
+    """Brute-force top-k over a small corpus.  ``scans`` lists the rows
+    of every pass; ``hold()`` makes the next pass wait, inside the scan,
+    until ``release()``."""
+
+    k = _K
+    query_buckets = _LADDER
+    bucket_for = staticmethod(_bucket_for)
+
+    def __init__(self):
+        self.corpus = np.random.default_rng(1).normal(
+            size=(32, _DIM)).astype(np.float32)
+        self.scans = []
+        self.entered = threading.Event()
+        self._gate = None
+
+    def hold(self):
+        self.entered.clear()
+        self._gate = threading.Event()
+
+    def release(self):
+        gate, self._gate = self._gate, None
+        gate.set()
+
+    def rank(self, q):
+        scores = q @ self.corpus.T
+        idx = np.argsort(-scores, axis=1)[:, :_K].astype(np.int32)
+        return np.take_along_axis(scores, idx, axis=1), idx
+
+    def topk(self, q):
+        self.scans.append(q.shape[0])
+        gate = self._gate
+        if gate is not None:
+            self.entered.set()
+            assert gate.wait(10), "the test never released the scan"
+        return self.rank(q)
+
+    def stats(self):
+        return {"size": self.corpus.shape[0]}
+
+
+class _LiveIndex(_Index):
+    """The live index's surface: the generation is read when the scan
+    starts, as ``LiveRetrievalIndex.topk_with_gen`` captures it."""
+
+    def __init__(self):
+        super().__init__()
+        self.generation = 1
+
+    def topk_with_gen(self, q):
+        gen = self.generation
+        return (*self.topk(q), gen)
+
+
+def _rows(seed, n=1):
+    return np.random.default_rng(seed).integers(
+        1, 50, (n, _WORDS)).astype(np.int32)
+
+
+@pytest.fixture
+def made():
+    """-> make(index=None, **service kwargs) -> (service, index, ring);
+    everything made is closed afterwards."""
+    services = []
+
+    def make(index=None, **kw):
+        index = _Index() if index is None else index
+        ring = obs_spans.SpanRecorder(ring=4096)
+        kw.setdefault("max_delay_ms", 1.0)
+        kw.setdefault("cache", EmbeddingLRUCache(64))
+        svc = RetrievalService(_Engine(), index, recorder=ring,
+                               registry=obs_metrics.MetricsRegistry(), **kw)
+        services.append((svc, index))
+        return svc, index, ring
+
+    yield make
+    for svc, index in services:
+        if index._gate is not None:
+            index.release()
+        svc.close()
+
+
+class _Caller(threading.Thread):
+    """One ``query_ids_with_gen`` call on a thread of its own."""
+
+    def __init__(self, svc, rows, **kw):
+        super().__init__(daemon=True)
+        self.svc, self.rows, self.kw = svc, rows, kw
+        self.answer = self.error = None
+        self.start()
+
+    def run(self):
+        try:
+            self.answer = self.svc.query_ids_with_gen(self.rows, **self.kw)
+        except Exception as exc:
+            self.error = exc
+
+    def done(self, timeout=10.0):
+        self.join(timeout)
+        assert not self.is_alive(), "the call never came back"
+        return self
+
+
+def _wait_for(cond, what, timeout=5.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"never saw: {what}"
+        time.sleep(0.002)
+
+
+def _handed_over(svc):
+    """Rows handed to the coalescer so far."""
+    return svc.health()["scans"]["requests"]
+
+
+def _alone(svc, index, rows):
+    """What ``index.topk`` answers for these rows by themselves."""
+    return index.rank(svc.engine.embed_text(rows))
+
+
+# ---- (a) callers that wait share a pass ------------------------------------
+
+def test_waiting_callers_share_at_most_two_scans(made):
+    svc, index, ring = made()
+    n = 7
+    index.hold()
+    callers = [_Caller(svc, _rows(100 + i)) for i in range(n)]
+    _wait_for(lambda: _handed_over(svc) == n and index.entered.is_set(),
+              "all rows handed to the coalescer behind the first scan")
+    index.release()
+    for c in callers:
+        assert c.done().error is None, c.error
+    assert len(index.scans) <= 2 and sum(index.scans) == n
+    for i, c in enumerate(callers):
+        scores, idx, gen = c.answer
+        want_scores, want_idx = _alone(svc, index, _rows(100 + i))
+        np.testing.assert_array_equal(idx, want_idx)
+        np.testing.assert_allclose(scores, want_scores, rtol=1e-6)
+        assert idx.dtype == np.int32 and gen is None
+    flushes = [r for r in ring.tail() if r["name"] == "topk.flush"]
+    assert [r["rows"] for r in flushes] == index.scans
+    assert all(r["batcher"] == "topk" and r["bucket"] == _bucket_for(r["rows"])
+               and {"queue_wait_ms", "queue_wait_mean_ms", "dur_ms"} <= set(r)
+               for r in flushes)
+    # the text batcher's flush records keep their name to themselves
+    assert {r["batcher"] for r in ring.tail()
+            if r["name"] == "batcher.flush"} == {"text"}
+
+
+def test_a_pass_never_carries_more_than_the_top_bucket(made):
+    svc, index, _ = made()
+    n = 2 * _LADDER[-1] + 3
+    index.hold()
+    callers = [_Caller(svc, _rows(200 + i)) for i in range(n)]
+    _wait_for(lambda: _handed_over(svc) == n and index.entered.is_set(),
+              "all rows handed over")
+    index.release()
+    for c in callers:
+        assert c.done().error is None, c.error
+    assert max(index.scans) <= _LADDER[-1] and sum(index.scans) == n
+    assert len(index.scans) <= 4          # the first, then 8 + 8 + the rest
+
+
+# ---- (b) a lone caller waits for nobody ------------------------------------
+
+def test_a_lone_call_is_scanned_at_once(made):
+    # a text window of a second: a hit never meets it, and the scan has
+    # no window of its own
+    svc, index, ring = made(max_delay_ms=1000.0, continuous=False)
+    rows = _rows(300)
+    svc.query_ids(rows)                       # a miss: pays the window
+    t0 = time.monotonic()
+    svc.query_ids(rows)                       # a hit: the scan alone
+    took_ms = (time.monotonic() - t0) * 1e3
+    assert took_ms < 250.0, took_ms
+    assert index.scans == [1, 1]
+    hit = [r for r in ring.tail() if r["name"] == "query"][-1]
+    assert hit["cache_hits"] == 1 and hit["topk_ms"] < 250.0
+    flush = [r for r in ring.tail() if r["name"] == "topk.flush"][-1]
+    assert flush["queue_wait_ms"] < 100.0
+
+
+# ---- (c) a deadline holds in the scan queue --------------------------------
+
+def test_a_row_whose_deadline_passes_is_never_scanned(made):
+    svc, index, ring = made()
+    first, late = _rows(400), _rows(401)
+    svc.query_ids(np.concatenate([first, late]))      # both cached now
+    assert index.scans == [2]
+    index.hold()
+    a = _Caller(svc, first)
+    _wait_for(index.entered.is_set, "the first scan in flight")
+    b = _Caller(svc, late, timeout_ms=30.0)
+    _wait_for(lambda: _handed_over(svc) == 4, "the late row in the queue")
+    time.sleep(0.06)         # its deadline passes behind the scan in flight
+    index.release()
+    assert a.done().error is None
+    assert isinstance(b.done().error, DeadlineExpired), b.error
+    time.sleep(0.05)
+    assert index.scans == [2, 1]          # the late row rode no pass
+    flushes = [r for r in ring.tail() if r["name"] == "topk.flush"]
+    assert [r["rows"] for r in flushes] == [2, 1]
+    assert svc.health()["scans"]["deadline_expired"] == 1
+
+
+# ---- (d) a scan that fails -------------------------------------------------
+
+def test_a_failing_scan_fails_its_rows_and_the_worker_lives(made,
+                                                            monkeypatch):
+    svc, index, _ = made()
+    real, calls = index.topk, []
+
+    def failing_once(q):
+        calls.append(q.shape[0])
+        if len(calls) == 2:
+            raise RuntimeError("scan failed")
+        return real(q)
+
+    monkeypatch.setattr(index, "topk", failing_once)
+    index.hold()
+    head = _Caller(svc, _rows(500))
+    _wait_for(index.entered.is_set, "the first scan in flight")
+    riders = [_Caller(svc, _rows(501 + i)) for i in range(3)]
+    _wait_for(lambda: _handed_over(svc) == 4, "three rows behind it")
+    index.release()
+    assert head.done().error is None
+    for c in riders:
+        assert isinstance(c.done().error, RuntimeError)
+        assert "scan failed" in str(c.error)
+    assert calls == [1, 3]
+    scores, idx = svc.query_ids(_rows(500))           # answered again
+    np.testing.assert_array_equal(idx, _alone(svc, index, _rows(500))[1])
+    health = svc.health()
+    assert health["scans"]["batch_errors"] == 1
+    assert health["query_errors"] == 3
+
+
+# ---- (e) the index's method is looked up at every scan ---------------------
+
+def test_replacing_topk_on_the_instance_changes_the_answers(made):
+    svc, index, _ = made()
+    rows = _rows(600)
+    _, before = svc.query_ids(rows)
+    real = index.topk
+
+    def altered(q):
+        scores, idx = real(q)
+        return scores, (idx + 1) % index.corpus.shape[0]
+
+    index.topk = altered                  # as the benchmark's fault does
+    _, after = svc.query_ids(rows)
+    np.testing.assert_array_equal(after,
+                                  (before + 1) % index.corpus.shape[0])
+
+
+# ---- (f) a live index: each call the generation that ranked it -------------
+
+def test_a_swap_between_two_scans_stamps_each_call_with_its_own(made):
+    svc, index, _ = made(index=_LiveIndex(), cache=EmbeddingLRUCache(0))
+    index.hold()
+    a = _Caller(svc, _rows(700, n=2))
+    _wait_for(index.entered.is_set, "the first scan in flight")
+    b = _Caller(svc, _rows(701, n=3))
+    _wait_for(lambda: _handed_over(svc) == 5, "the second call behind it")
+    index.generation = 2                  # the swap lands between the two
+    index.release()
+    assert a.done().error is None and b.done().error is None
+    assert a.answer[2] == 1 and b.answer[2] == 2
+    assert index.scans == [2, 3]          # a call's rows ride one scan
+    assert a.answer[0].shape == (2, _K) and b.answer[1].shape == (3, _K)
+
+
+# ---- (g) close ---------------------------------------------------------------
+
+def test_close_resolves_every_waiting_future(made):
+    svc, index, _ = made()
+    index.hold()
+    head = _Caller(svc, _rows(800))
+    _wait_for(index.entered.is_set, "the first scan in flight")
+    waiting = [_Caller(svc, _rows(801 + i)) for i in range(4)]
+    _wait_for(lambda: _handed_over(svc) == 5, "four rows behind it")
+    closer = threading.Thread(target=svc.close, daemon=True)
+    closer.start()
+    time.sleep(0.05)
+    index.release()                       # the scan in flight finishes
+    closer.join(10)
+    assert not closer.is_alive()
+    assert head.done().error is None      # it was on the device: answered
+    for c in waiting:
+        c.done()
+        assert c.answer is not None or "closed" in str(c.error)
+    assert any(c.error is not None for c in waiting) or sum(index.scans) == 5
+    with pytest.raises(RuntimeError, match="closed"):
+        svc.query_ids(_rows(800))
+
+
+# ---- calls of more than one row ----------------------------------------------
+
+def test_a_calls_rows_are_never_split_over_two_scans(made):
+    svc, index, _ = made()
+    index.hold()
+    head = _Caller(svc, _rows(900))
+    _wait_for(index.entered.is_set, "the first scan in flight")
+    # 5 + 5 rows do not fit the top bucket of 8: two passes of 5, never
+    # 8 and 2
+    calls = [_Caller(svc, _rows(901 + i, n=5)) for i in range(2)]
+    _wait_for(lambda: _handed_over(svc) == 11, "both calls behind it")
+    index.release()
+    for c in [head] + calls:
+        assert c.done().error is None, c.error
+    assert index.scans == [1, 5, 5]
+    for i, c in enumerate(calls):
+        np.testing.assert_array_equal(
+            c.answer[1], _alone(svc, index, _rows(901 + i, n=5))[1])
+
+
+def test_a_call_larger_than_the_top_bucket_keeps_its_error(made):
+    svc, index, _ = made()
+    with pytest.raises(ValueError, match="exceeds the top query bucket"):
+        svc.query_ids(_rows(1000, n=_LADDER[-1] + 1))
+    assert index.scans == []
+    svc.query_ids(_rows(1001))            # and the worker lives on
+    assert index.scans == [1]
+
+
+def test_a_shed_call_touches_neither_queue(made):
+    svc, index, _ = made(max_inflight=1)
+    with pytest.raises(ShedError):
+        svc.query_ids(_rows(1100, n=2))
+    health = svc.health()
+    assert health["scans"]["requests"] == 0
+    assert health["batcher"]["requests"] == 0 and index.scans == []
+
+
+def test_a_service_without_an_index_has_no_coalescer():
+    svc = RetrievalService(_Engine(), None,
+                           registry=obs_metrics.MetricsRegistry())
+    try:
+        assert svc.health()["scans"] is None
+        with pytest.raises(ValueError, match="without a retrieval index"):
+            svc.query_ids(_rows(1200))
+    finally:
+        svc.close()
+
+
+# ---- stress ------------------------------------------------------------------
+
+def test_stress_every_caller_gets_its_own_rows_back(made):
+    """More callers than cores, a short switch interval, calls of 1 to 5
+    rows: every answer is the one its rows would get alone, every row
+    rides exactly one pass, and no pass is larger than the top bucket."""
+    import sys
+
+    svc, index, _ = made(cache=EmbeddingLRUCache(16))
+    callers, calls = 24, 25
+    errors, answered = [], [0] * callers
+
+    def loop(c):
+        rng = np.random.default_rng(5000 + c)
+        try:
+            for i in range(calls):
+                rows = _rows(int(rng.integers(0, 40)), n=int(rng.integers(1, 6)))
+                scores, idx = svc.query_ids(rows)
+                want_scores, want_idx = _alone(svc, index, rows)
+                np.testing.assert_array_equal(idx, want_idx)
+                np.testing.assert_allclose(scores, want_scores, rtol=1e-6)
+                answered[c] += rows.shape[0]
+        except Exception as exc:                     # noqa: BLE001
+            errors.append(exc)
+
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=loop, args=(c,), daemon=True)
+                   for c in range(callers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(before)
+    assert not [t for t in threads if t.is_alive()]
+    assert not errors, errors[:3]
+    assert sum(index.scans) == sum(answered) > 0
+    assert max(index.scans) <= _LADDER[-1]
+    assert len(index.scans) < callers * calls       # passes were shared
+    health = svc.health()["scans"]
+    assert health["requests"] == sum(answered)
+    assert health["flushes"] == len(index.scans)

@@ -385,3 +385,132 @@ def test_on_flush_observer_sees_duration_and_rows():
         fut.result(timeout=5)
     bad.close()
     assert seen == []
+
+
+# ---- blocks, and what a batch returns (ISSUE 27: the scan coalescer) --------
+
+def _block(first, n, w=3):
+    return np.stack([np.full((w,), float(first + i), np.float32)
+                     for i in range(n)])
+
+
+@pytest.mark.parametrize("continuous", [False, True])
+def test_block_resolves_to_its_rows_in_order_beside_lone_rows(continuous):
+    eng = _FakeEngine(delay_s=0.05)
+    b = _mk(eng, max_delay_ms=30.0, continuous=continuous)
+    head = b.submit(np.full((3,), 50.0, np.float32))
+    time.sleep(0.02)                    # continuous: head is in flight
+    blk = b.submit_block(_block(0, 3))
+    lone = b.submit(np.full((3,), 7.0, np.float32))
+    assert np.array_equal(blk.result(timeout=5), _block(0, 3) * 2.0)
+    assert np.array_equal(lone.result(timeout=5), np.full((3,), 14.0))
+    assert np.array_equal(head.result(timeout=5), np.full((3,), 100.0))
+    assert b.stats()["requests"] == 5   # rows, not requests
+    b.close()
+
+
+@pytest.mark.parametrize("continuous", [False, True])
+def test_a_block_is_never_split_over_two_batches(continuous):
+    """5 + 5 rows against a top bucket of 8: two batches of 5 — the
+    second block is held over whole and leads the next batch."""
+    eng = _FakeEngine(delay_s=0.1)
+    b = _mk(eng, max_delay_ms=30.0, continuous=continuous)
+    head = b.submit(np.zeros((3,), np.float32))
+    time.sleep(0.04 if continuous else 0.0)
+    blocks = [b.submit_block(_block(10 * (i + 1), 5)) for i in range(2)]
+    tail = b.submit(np.ones((3,), np.float32))
+    for i, f in enumerate(blocks):
+        assert np.array_equal(f.result(timeout=5),
+                              _block(10 * (i + 1), 5) * 2.0)
+    head.result(timeout=5), tail.result(timeout=5)
+    b.close()
+    live = [int((batch.sum(axis=1) != 0).sum()) for batch in eng.batches]
+    assert sum(live) == 11 and max(live) <= 8
+    # the row sums of every block sit in ONE batch
+    for i in range(2):
+        want = set((_block(10 * (i + 1), 5) * 1.0).sum(axis=1).tolist())
+        assert sum(want <= set(batch.sum(axis=1).tolist())
+                   for batch in eng.batches) == 1
+
+
+def test_a_block_past_the_top_bucket_fails_alone():
+    eng = _FakeEngine()
+    b = _mk(eng, continuous=True)
+    ok = b.submit(np.ones((3,), np.float32))
+    too_big = b.submit_block(_block(0, 9))
+    after = b.submit(np.ones((3,), np.float32))
+    with pytest.raises(ValueError):
+        too_big.result(timeout=5)
+    assert np.array_equal(ok.result(timeout=5), np.full((3,), 2.0))
+    assert np.array_equal(after.result(timeout=5), np.full((3,), 2.0))
+    assert b.stats()["batch_errors"] == 1
+    b.close()
+
+
+def test_take_cuts_each_requests_share_out_of_what_the_batch_returned():
+    """The batch returns two arrays and a stamp; a lone row gets its
+    row of each, a block its rows, both the stamp."""
+    seen = []
+
+    def run(rows):
+        seen.append(rows.shape[0])
+        return rows * 2.0, rows.sum(axis=1).astype(np.int32), len(seen)
+
+    b = DynamicBatcher(run, _bucket_for, max_batch=8, continuous=True,
+                       pad=False, span_name="topk.flush",
+                       take=lambda out, at: (out[0][at], out[1][at], out[2]))
+    doubled, total, stamp = b.submit_block(_block(1, 3)).result(timeout=5)
+    assert np.array_equal(doubled, _block(1, 3) * 2.0)
+    assert total.tolist() == [3, 6, 9] and total.dtype == np.int32
+    doubled, total, stamp2 = b.submit(
+        np.full((3,), 4.0, np.float32)).result(timeout=5)
+    assert doubled.shape == (3,) and total == 12
+    assert (stamp, stamp2) == (1, 2)
+    assert seen == [3, 1]               # pad=False: the live rows alone
+    b.close()
+
+
+def test_span_name_and_unpadded_rows_on_the_flush_record():
+    from milnce_tpu.obs import spans as obs_spans
+
+    rec = obs_spans.SpanRecorder(ring=64)
+    eng = _FakeEngine()
+    b = _mk(eng, continuous=True, pad=False, span_name="topk.flush",
+            recorder=rec, name="topk")
+    b.submit_block(_block(0, 5)).result(timeout=5)
+    b.close()
+    (flush,) = [r for r in rec.tail() if r["name"] == "topk.flush"]
+    assert (flush["rows"], flush["bucket"], flush["batcher"]) == (5, 8,
+                                                                  "topk")
+    assert {"queue_wait_ms", "queue_wait_mean_ms", "dur_ms"} <= set(flush)
+    assert not [r for r in rec.tail() if r["name"] == "batcher.flush"]
+    assert eng.batches[0].shape[0] == 5
+
+
+def test_close_fails_the_block_that_was_held_over():
+    """Pipelined continuous mode, the one lane busy: the worker parks
+    with a forming batch and a block held over behind it; both count in
+    ``depth()`` and both are failed, never dropped, at close."""
+    from concurrent.futures import Future
+
+    inflight: list[Future] = []
+
+    def run_async(rows):
+        inflight.append(Future())
+        return inflight[-1]             # never resolved: the lane stays busy
+
+    b = _mk(_FakeEngine(), continuous=True, lanes=1,
+            run_batch_async=run_async)
+    b.submit(np.zeros((3,), np.float32))
+    deadline = time.monotonic() + 5.0
+    while not inflight and time.monotonic() < deadline:
+        time.sleep(0.005)
+    first = b.submit_block(_block(0, 5))
+    held = b.submit_block(_block(5, 5))     # does not fit behind `first`
+    while b.depth() < 10 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert b.depth() == 10              # forming rows + the held block
+    b.close()
+    for f in (first, held):
+        with pytest.raises(RuntimeError, match="closed"):
+            f.result(timeout=5)

@@ -8,6 +8,7 @@ module-scoped stack keeps the compile bill to one warmup sweep."""
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -779,6 +780,73 @@ class TestQuerySpan:
             svc.query_ids(_fresh_rows(2604, 1))
         (r,) = _named(ring, "query")
         assert r["error"] == "RuntimeError" and r["cache_hits"] == 0
+
+
+class TestScanCoalescer:
+    """The coalescer over the real engine and index (its semantics on
+    stubs: tests/test_scan_coalescer.py)."""
+
+    def test_callers_that_wait_share_a_pass_and_keep_their_own_answers(
+            self, stack, ring):
+        from milnce_tpu.serving.engine import DEVICE_DISPATCH_LOCK
+
+        svc, index = stack["service"], stack["index"]
+        n = 12                              # under the top bucket of 16
+        rows = _fresh_rows(2701, n)
+        emb = svc.embed_text_ids(rows)                  # all cached now
+        want = [index.topk(emb[i:i + 1]) for i in range(n)]
+        before = svc.health()["scans"]["requests"]
+        alone = len(_named(ring, "dispatch", site="index.topk"))
+        assert alone == n
+        answers = [None] * n
+
+        def call(i):
+            answers[i] = svc.query_ids(rows[i:i + 1])
+
+        threads = [threading.Thread(target=call, args=(i,), daemon=True)
+                   for i in range(n)]
+        DEVICE_DISPATCH_LOCK.acquire()      # the device is busy elsewhere
+        try:
+            for t in threads:
+                t.start()
+            deadline = time.monotonic() + 10.0
+            while svc.health()["scans"]["requests"] < before + n:
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
+        finally:
+            DEVICE_DISPATCH_LOCK.release()
+        for t in threads:
+            t.join(30)
+        scans = _named(ring, "dispatch", site="index.topk")[alone:]
+        assert len(scans) <= 2 and sum(r["rows"] for r in scans) == n
+        assert all(r["bucket"] == index.bucket_for(r["rows"])
+                   for r in scans)
+        for i in range(n):
+            np.testing.assert_array_equal(answers[i][1], want[i][1])
+            np.testing.assert_allclose(answers[i][0], want[i][0],
+                                       rtol=1e-5, atol=1e-6)
+        flushes = _named(ring, "topk.flush")
+        assert [r["rows"] for r in flushes] == [r["rows"] for r in scans]
+        assert index.recompiles() == 0
+
+    def test_a_sixteen_row_call_rides_one_scan(self, stack, ring):
+        svc = stack["service"]
+        svc.query_ids(_fresh_rows(2702, 16))
+        (scan,) = _named(ring, "dispatch", site="index.topk")
+        assert (scan["rows"], scan["bucket"]) == (16, 16)
+        with pytest.raises(ValueError, match="top query bucket"):
+            svc.query_ids(_fresh_rows(2703, 17))
+
+    def test_health_counts_the_scans_beside_the_text_flushes(self, stack):
+        svc = stack["service"]
+        before = svc.health()
+        svc.query_ids(_fresh_rows(2704, 3))
+        after = svc.health()
+        assert after["scans"]["requests"] - before["scans"]["requests"] == 3
+        assert after["scans"]["flushes"] - before["scans"]["flushes"] == 1
+        assert after["batcher"]["requests"] \
+            - before["batcher"]["requests"] == 3
+        assert "topk" in svc.metrics_text()
 
 
 class TestQueueWait:
