@@ -1,0 +1,22 @@
+"""expert_tokens_mean.serve: what each held expert's product is fed, a
+flush: ``moe_pairs_held`` of the window's ``dispatch`` records of site
+``engine.text`` over (experts held x expert layers), their mean."""
+
+LAYER = "model"
+UNIT = "tokens"
+SOURCE = "program_span"
+MOVES = "queries_per_s"
+SITE = "engine.text"
+
+
+def read(run):
+    from benchmarks import flops_axk1
+
+    pairs = [e["moe_pairs_held"] for e in run.events
+             if e.get("name") == "dispatch" and e.get("site") == SITE
+             and "moe_pairs_held" in e]
+    cfg = run.cell.config
+    if not pairs or "n_routed_experts" not in cfg:
+        return None
+    _dense, moe = flops_axk1.layer_counts(cfg)
+    return sum(pairs) / len(pairs) / (cfg["n_routed_experts"] * moe)

@@ -95,6 +95,54 @@ class ModelConfig:
                                         # conv_impl.  '' = uniform conv_impl.
     remat: bool = False                 # rematerialize Inception blocks
                                         # (jax.checkpoint) to fit big batches
+    text_tower: str = "bow"             # 'bow': word table -> MLP -> max-pool
+                                        # (models/text.py) | 'lm': the causal
+                                        # language model of the text_lm group
+                                        # (models/text_lm.py; served only)
+
+
+TEXT_TOWERS = ("bow", "lm")
+
+
+@dataclass
+class TextLMConfig:
+    """The language model behind ``model.text_tower = 'lm'``, under the
+    names its published ``config.json`` gives them (``rope_scaling``'s
+    keys flattened to ``rope_scaling_<key>``), plus the chip's share of
+    each expert layer: ``experts_held`` experts from ``first_expert`` on
+    (all ``n_routed_experts`` from 0: the whole layer).  Defaults: a small
+    model of the same shape, for tests."""
+
+    hidden_size: int = 64
+    num_attention_heads: int = 4
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 8
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 8
+    n_routed_experts: int = 16
+    num_experts_per_tok: int = 4
+    n_shared_experts: int = 1
+    moe_intermediate_size: int = 32
+    intermediate_size: int = 96
+    first_k_dense_replace: int = 1
+    routed_scaling_factor: float = 2.5
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
+    topk_method: str = "none"
+    rope_theta: float = 10000.0
+    rope_scaling_type: str = "yarn"
+    rope_scaling_factor: float = 32.0
+    rope_scaling_original_max_position_embeddings: int = 4096
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_scaling_mscale: float = 1.0
+    rope_scaling_mscale_all_dim: float = 1.0
+    rms_norm_eps: float = 1e-6
+    num_hidden_layers: int = 3
+    vocab_size: int = 128
+    experts_held: int = 16
+    first_expert: int = 0
 
 
 CONV_IMPLS = ("native", "fold2d", "im2col")        # models/conv3d.py
@@ -540,6 +588,7 @@ class ServeConfig:
 class Config:
     data: DataConfig = field(default_factory=DataConfig)
     model: ModelConfig = field(default_factory=ModelConfig)
+    text_lm: TextLMConfig = field(default_factory=TextLMConfig)
     loss: LossConfig = field(default_factory=LossConfig)
     optim: OptimConfig = field(default_factory=OptimConfig)
     parallel: ParallelConfig = field(default_factory=ParallelConfig)

@@ -12,7 +12,8 @@ import os
 import jax.numpy as jnp
 import numpy as np
 
-from milnce_tpu.config import ModelConfig, parse_conv_impl_map
+from milnce_tpu.config import (TEXT_TOWERS, ModelConfig, TextLMConfig,
+                               parse_conv_impl_map)
 from milnce_tpu.models.s3dg import S3D
 from milnce_tpu.models.text import word2vec_embedding_init
 
@@ -31,7 +32,21 @@ def load_word2vec_table(path: str) -> np.ndarray:
     return np.load(path)
 
 
-def build_model(cfg: ModelConfig, bn_axis_name: str | None = None) -> S3D:
+def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
+                text_lm: TextLMConfig | None = None) -> S3D:
+    """``text_lm``: the language model's group, needed (and validated)
+    where ``cfg.text_tower`` is 'lm'."""
+    if cfg.text_tower not in TEXT_TOWERS:
+        raise ValueError(f"model.text_tower={cfg.text_tower!r}: one of "
+                         f"{', '.join(TEXT_TOWERS)}")
+    lm = None
+    if cfg.text_tower == "lm":
+        from milnce_tpu.models.text_lm import lm_dims
+
+        if text_lm is None:
+            raise ValueError("model.text_tower='lm' needs the text_lm group "
+                             "(build_model(cfg.model, text_lm=cfg.text_lm))")
+        lm = lm_dims(text_lm)
     embedding_init = None
     vocab_size = cfg.vocab_size
     if cfg.word2vec_path and os.path.exists(cfg.word2vec_path):
@@ -55,5 +70,6 @@ def build_model(cfg: ModelConfig, bn_axis_name: str | None = None) -> S3D:
             parse_conv_impl_map(cfg.conv_impl_map).items())) or None,
         embedding_init=embedding_init,
         remat=cfg.remat,
+        text_lm=lm,
         dtype=jnp.dtype(cfg.dtype),
     )

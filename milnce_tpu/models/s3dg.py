@@ -250,6 +250,9 @@ class S3D(nn.Module):
     embedding_init: Optional[Callable] = None
     remat: bool = False                 # rematerialize Inception blocks to
                                         # trade FLOPs for HBM at big batches
+    text_lm: Optional[Any] = None       # models/text_lm.py LMDims: the
+                                        # sentence tower is that language
+                                        # model (None: the bag-of-words one)
     dtype: Any = jnp.float32
 
     def setup(self):
@@ -321,6 +324,13 @@ class S3D(nn.Module):
         self.fc = nn.Dense(self.num_classes, kernel_init=torch_default_kernel(),
                            bias_init=torch_bias(trunk_dim),
                            dtype=self.dtype, name="fc")
+        if self.text_lm is not None:
+            from milnce_tpu.models.text_lm import TextLM
+
+            self.text_module = TextLM(self.text_lm,
+                                      embd_dim=self.num_classes,
+                                      dtype=self.dtype, name="text_module")
+            return
         self.text_module = SentenceEmbedding(
             embd_dim=self.num_classes,
             vocab_size=self.vocab_size,

@@ -124,6 +124,19 @@ class Gauge:
         with self._lock:
             self._fn = fn
 
+    def unbind(self) -> None:
+        """Drop the callback and keep its last reading: what a component
+        does when it closes, so that a registry that outlives it (the
+        process-wide one) does not keep it, and what it holds, alive."""
+        with self._lock:
+            fn = self._fn
+        if fn is None:
+            return
+        last = _host_number(fn())       # outside the lock, as ``value``
+        with self._lock:
+            if self._fn is fn:          # not re-bound meanwhile
+                self._fn, self._value = None, last
+
     @property
     def value(self) -> float:
         with self._lock:

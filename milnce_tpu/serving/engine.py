@@ -172,16 +172,23 @@ def bucket_ladder(n_dev: int, min_bucket: int, max_batch: int) -> tuple:
     return tuple(out)
 
 
-def cast_floats(tree, dtype):
-    """Cast floating leaves of a pytree (params/batch_stats) to ``dtype``;
-    integer leaves (e.g. embedding ids baked into stats) pass through."""
-    dt = jnp.dtype(dtype)
+def place_leaves(tree, sharding, cast_dtype: Optional[str] = None):
+    """The frozen tree onto the mesh, leaf by leaf: each leaf goes up in
+    its own type and, where it is a float of another type than
+    ``cast_dtype``, is cast there and its first copy dropped — no second
+    copy of the TREE exists on the host or the device at any time (a
+    tower of billions of bfloat16 parameters would not fit one).  Integer
+    leaves (int8 weights, ids baked into stats) pass through."""
+    dt = jnp.dtype(cast_dtype) if cast_dtype else None
 
-    def cast(x):
-        x = jnp.asarray(x)
-        return x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating) else x
+    def place(x):
+        x = jax.device_put(x, sharding)
+        if (dt is not None and jnp.issubdtype(x.dtype, jnp.floating)
+                and x.dtype != dt):
+            x = x.astype(dt)
+        return x
 
-    return jax.tree_util.tree_map(cast, tree)
+    return jax.tree_util.tree_map(place, tree)
 
 
 def load_serving_model(export_dir: str, dtype: str = ""):
@@ -195,7 +202,7 @@ def load_serving_model(export_dir: str, dtype: str = ""):
     weights resident, dequantize inside the jitted entries, f32
     accumulation.  ``dtype`` overrides are refused for quantized
     artifacts (the stored precision IS the artifact's contract)."""
-    from milnce_tpu.config import ModelConfig
+    from milnce_tpu.config import ModelConfig, TextLMConfig
     from milnce_tpu.models.build import build_model
     from milnce_tpu.serving.export import (QUANT_FORMAT_VERSION,
                                            load_inference_checkpoint,
@@ -216,7 +223,9 @@ def load_serving_model(export_dir: str, dtype: str = ""):
     model_cfg = ModelConfig(**meta["model"])
     if dtype:
         model_cfg.dtype = dtype
-    model = build_model(model_cfg)
+    text_lm = (TextLMConfig(**meta["text_lm"]) if "text_lm" in meta
+               else None)
+    model = build_model(model_cfg, text_lm=text_lm)
     if quantized:
         from milnce_tpu.quant.quantize import QuantizedModel
 
@@ -261,10 +270,9 @@ class InferenceEngine:
         self.max_batch = self.buckets[-1]
         self.text_words = int(text_words)
         self.video_shape = tuple(int(d) for d in video_shape)
-        if cast_dtype:
-            variables = cast_floats(variables, cast_dtype)
         # one explicit replication at boot; steady state never moves params
-        self._variables = jax.device_put(variables, replicated(mesh))
+        self._variables = place_leaves(variables, replicated(mesh),
+                                       cast_dtype)
         self._batch_sh = batch_sharding(mesh, data_axis)
         self._text_fn = make_text_embed_fn(model, mesh, data_axis)
         self._video_fn = make_video_embed_fn(model, mesh, data_axis)
@@ -302,7 +310,10 @@ class InferenceEngine:
         if rows.ndim != 2 or rows.shape[1] != self.text_words:
             raise ValueError(f"expected (n, {self.text_words}) token ids, "
                              f"got {rows.shape}")
-        return self._run("text", self._text_fn, rows)
+        tokens = int(np.count_nonzero(rows))
+        slots = self.bucket_for(rows.shape[0]) * self.text_words
+        return self._run("text", self._text_fn, rows, tokens=tokens,
+                         pad_tokens=slots - tokens)
 
     def embed_video(self, video_u8: np.ndarray) -> np.ndarray:
         """(n, T, H, W, 3) uint8 frames -> (n, D) float embeddings."""
@@ -312,7 +323,11 @@ class InferenceEngine:
                              f"video, got {clips.shape}")
         return self._run("video", self._video_fn, clips)
 
-    def _run(self, entry: str, fn, rows: np.ndarray) -> np.ndarray:
+    def _run(self, entry: str, fn, rows: np.ndarray,
+             **attrs) -> np.ndarray:
+        """``attrs``: what the entry adds to its ``dispatch`` record (the
+        text entry: the real tokens and the slots of the bucket they leave
+        empty)."""
         n = rows.shape[0]
         bucket = self.bucket_for(n)
         rows = pad_rows(rows, bucket)
@@ -333,8 +348,14 @@ class InferenceEngine:
         # Steady state: implicit transfers are bugs (they stall the async
         # dispatch pipeline); both legs of the request are explicit.
         with device_dispatch(f"engine.{entry}", lock=self._dispatch_lock,
-                             rows=n, bucket=bucket) as hold:
+                             rows=n, bucket=bucket, **attrs) as hold:
             out = hold.round_trip(fn, rows, self._batch_sh, self._variables)
+            if isinstance(out, tuple):
+                # a program that counts what it did (a tower with routed
+                # layers): the rows, and name -> scalar for the record
+                out, counters = out
+                hold.record.update(
+                    {name: int(v) for name, v in counters.items()})
         out = np.asarray(out)
         with self._stats_lock:
             self._calls[(entry, bucket)] = \

@@ -10,9 +10,14 @@ leaves, '/'-joined tree paths as keys) plus one ``metadata.json``
 any host with numpy, no Orbax, no original mesh, no model code at read
 time.
 
-Arrays are stored float32; casting to bf16 is a LOAD-time decision
-(``InferenceEngine.from_export(dtype='bfloat16')``) so one artifact
-serves both precision modes ("bf16-castable", not bf16-committed).
+A float leaf is stored in its own type (float64 never ships: it is
+stored float32); a training checkpoint's leaves are float32, so its
+export is, and casting to bf16 stays a LOAD-time decision
+(``InferenceEngine.from_export(dtype='bfloat16')``): one artifact serves
+both precision modes.  A tree that is bfloat16 already (a language-model
+tower of billions of parameters) is written as it is, bit for bit —
+``.npz`` has no bfloat16, so those leaves lie there as their 16 bits
+(uint16) and ``array_dtypes`` says what they are.
 
 CLI (console script ``milnce-export`` /
 ``python -m milnce_tpu.serving.export``)::
@@ -143,6 +148,21 @@ def _flatten(tree, prefix: str) -> dict[str, np.ndarray]:
     return out
 
 
+def _stored(v: np.ndarray) -> np.ndarray:
+    """A leaf as the ``.npz`` holds it: bfloat16 as its bits (a view)."""
+    return v.view(np.uint16) if v.dtype.name == "bfloat16" else v
+
+
+def _restored(v: np.ndarray, dtype_name: Optional[str]) -> np.ndarray:
+    """Inverse of :func:`_stored`, by the leaf's ``array_dtypes`` entry
+    (None: an export from before the manifest, all as stored)."""
+    if dtype_name == "bfloat16" and v.dtype == np.uint16:
+        import ml_dtypes
+
+        return v.view(ml_dtypes.bfloat16)
+    return v
+
+
 def _unflatten(arrays: dict[str, np.ndarray], prefix: str) -> dict:
     """Inverse of :func:`_flatten` for dict-shaped trees (flax params /
     batch_stats are nested string-keyed dicts)."""
@@ -160,9 +180,10 @@ def _unflatten(arrays: dict[str, np.ndarray], prefix: str) -> dict:
 
 def _artifact_metadata(model_cfg, *, max_words: int, video_shape,
                        step: int, source: str, arrays: dict,
-                       format_version: int) -> dict:
-    """Shared metadata assembly for the f32 and quantized formats:
-    sanitized model config, tokenizer contract, video shape and the
+                       format_version: int, text_lm=None) -> dict:
+    """Shared metadata assembly for the float and quantized formats:
+    sanitized model config (and the language model's group, where the
+    sentence tower is one), tokenizer contract, video shape and the
     per-array dtype manifest."""
     from milnce_tpu.config import parse_conv_impl_map
 
@@ -172,12 +193,15 @@ def _artifact_metadata(model_cfg, *, max_words: int, video_shape,
     model_meta["conv_impl_map"] = ",".join(  # resolve file specs inline
         f"{s}={i}" for s, i in sorted(impl_map.items()))
     token_dict = model_meta.pop("token_dict_path", "")
+    lm_meta = {"text_lm": dataclasses.asdict(text_lm)} if (
+        model_meta.get("text_tower") == "lm") else {}
     return {
         "format_version": int(format_version),
         "generator": "milnce-export (milnce_tpu/serving/export.py)",
         "step": int(step),
         "source_checkpoint": source,
         "model": model_meta,
+        **lm_meta,
         "tokenizer": {"max_words": int(max_words),
                       "vocab_size": int(model_meta["vocab_size"]),
                       "token_dict_path": token_dict},
@@ -185,9 +209,8 @@ def _artifact_metadata(model_cfg, *, max_words: int, video_shape,
         "param_bytes": int(sum(v.nbytes for v in arrays.values())),
         # per-array dtype manifest: the on-disk precision contract a
         # loader (and scripts/precision_audit.py's quant-readiness
-        # report) can audit without opening the npz — float leaves are
-        # f32 (or int8, in the quantized format) by construction,
-        # everything else ships as stored
+        # report) can audit without opening the npz — each leaf's own
+        # type (bfloat16 leaves lie in the npz as their bits)
         "array_dtypes": {k: str(v.dtype) for k, v in arrays.items()},
     }
 
@@ -195,24 +218,26 @@ def _artifact_metadata(model_cfg, *, max_words: int, video_shape,
 def export_inference_checkpoint(out_dir: str, params, batch_stats,
                                 model_cfg, *, max_words: int,
                                 video_shape, step: int = 0,
-                                source: str = "") -> str:
+                                source: str = "", text_lm=None) -> str:
     """Write the frozen artifact; returns ``out_dir``.
 
     ``model_cfg`` is a ``milnce_tpu.config.ModelConfig``; host-specific
     fields (word2vec/token-dict paths, impl-map file paths) are
-    sanitized so the artifact is self-contained."""
+    sanitized so the artifact is self-contained.  ``text_lm``: the
+    ``TextLMConfig`` of a ``text_tower='lm'`` model.  Every leaf is
+    written in its own type (module docstring), none copied to another."""
     os.makedirs(out_dir, exist_ok=True)
     arrays = _flatten(params, "params")
     arrays.update(_flatten(batch_stats, "batch_stats"))
-    # float leaves stored f32 (bf16 is a load-time cast; f64 never ships)
-    arrays = {k: (v.astype(np.float32)
-                  if np.issubdtype(v.dtype, np.floating) else v)
+    arrays = {k: (v.astype(np.float32) if v.dtype.name == "float64" else v)
               for k, v in arrays.items()}
-    np.savez(os.path.join(out_dir, ARRAYS_FILE), **arrays)
+    np.savez(os.path.join(out_dir, ARRAYS_FILE),
+             **{k: _stored(v) for k, v in arrays.items()})
     meta = _artifact_metadata(model_cfg, max_words=max_words,
                               video_shape=video_shape, step=step,
                               source=source, arrays=arrays,
-                              format_version=FORMAT_VERSION)
+                              format_version=FORMAT_VERSION,
+                              text_lm=text_lm)
     with open(os.path.join(out_dir, METADATA_FILE), "w") as fh:
         json.dump(meta, fh, indent=2, sort_keys=True)
     return out_dir
@@ -307,8 +332,9 @@ def load_inference_checkpoint(export_dir: str) -> tuple[dict, dict]:
                          f"){hint}")
     # ModelConfig round-trips through JSON minus the serve-sanitized field
     meta["model"].pop("token_dict_path", None)
+    manifest = meta.get("array_dtypes", {})
     with np.load(os.path.join(export_dir, ARRAYS_FILE)) as z:
-        arrays = {k: z[k] for k in z.files}
+        arrays = {k: _restored(z[k], manifest.get(k)) for k in z.files}
     return meta, {"params": _unflatten(arrays, "params"),
                   "batch_stats": _unflatten(arrays, "batch_stats")}
 

@@ -208,9 +208,10 @@ class AdmissionController:
         self._f_shed = reg.counter(
             "milnce_serve_shed_total",
             "requests refused at admission (HTTP 429)", ("reason",))
-        reg.gauge("milnce_serve_admission_inflight",
-                  "rows admitted and not yet resolved",
-                  fn=lambda: float(self.inflight))
+        # callback gauges read this object: unbound again by close()
+        self._bound = [reg.gauge("milnce_serve_admission_inflight",
+                                 "rows admitted and not yet resolved",
+                                 fn=lambda: float(self.inflight))]
         self._f_tier_shed = None
         if self.tiers:
             self._f_tier_shed = reg.counter(
@@ -221,8 +222,14 @@ class AdmissionController:
                           "rows admitted and unresolved per SLO tier",
                           ("tier",))
             for name in self.tiers:
-                g.labels(tier=name).bind(
-                    lambda n=name: float(self.tier_inflight(n)))
+                child = g.labels(tier=name)
+                child.bind(lambda n=name: float(self.tier_inflight(n)))
+                self._bound.append(child)
+
+    def close(self) -> None:
+        """Leave the registry the last readings and no callback."""
+        for gauge in self._bound:
+            gauge.unbind()
 
     @property
     def inflight(self) -> int:
@@ -466,24 +473,28 @@ class RetrievalService:
             "milnce_serve_query_errors_total", "retrieval queries failed")
         # collect-time gauges: values owned by other components, read at
         # scrape/snapshot — never cached stale, never double-counted
-        reg.gauge("milnce_serve_uptime_seconds", "seconds since boot",
-                  fn=lambda: time.time() - self._started)
-        reg.gauge("milnce_serve_engine_recompiles",
-                  "jit-cache entries created since the warmup sweep "
-                  "(must stay 0; -1 = no introspection on this jax)",
-                  fn=engine.recompiles)
-        reg.gauge("milnce_serve_cache_hits",
-                  "text-embedding cache hits",
-                  fn=lambda: self.cache.stats()["hits"])
-        reg.gauge("milnce_serve_cache_misses",
-                  "text-embedding cache misses",
-                  fn=lambda: self.cache.stats()["misses"])
-        reg.gauge("milnce_serve_cache_hit_rate",
-                  "hits / (hits + misses), 0 before traffic",
-                  fn=lambda: self.cache.stats()["hit_rate"])
+        # unbound again by close(): the process-wide registry must not
+        # keep a closed service, its engine and its weights alive
+        self._bound = [
+            reg.gauge("milnce_serve_uptime_seconds", "seconds since boot",
+                      fn=lambda: time.time() - self._started),
+            reg.gauge("milnce_serve_engine_recompiles",
+                      "jit-cache entries created since the warmup sweep "
+                      "(must stay 0; -1 = no introspection on this jax)",
+                      fn=engine.recompiles),
+            reg.gauge("milnce_serve_cache_hits",
+                      "text-embedding cache hits",
+                      fn=lambda: self.cache.stats()["hits"]),
+            reg.gauge("milnce_serve_cache_misses",
+                      "text-embedding cache misses",
+                      fn=lambda: self.cache.stats()["misses"]),
+            reg.gauge("milnce_serve_cache_hit_rate",
+                      "hits / (hits + misses), 0 before traffic",
+                      fn=lambda: self.cache.stats()["hit_rate"])]
         if index is not None:
-            reg.gauge("milnce_serve_index_size", "corpus rows indexed",
-                      fn=lambda: self.index.stats()["size"])
+            self._bound.append(
+                reg.gauge("milnce_serve_index_size", "corpus rows indexed",
+                          fn=lambda: self.index.stats()["size"]))
 
     # ---- embedding path --------------------------------------------------
 
@@ -585,8 +596,11 @@ class RetrievalService:
 
     def _encode(self, sentences) -> np.ndarray:
         if self.tokenizer is None:
-            raise ValueError("service built without a tokenizer — send "
-                             "token_ids instead of sentences")
+            raise ValueError(
+                "service built without a tokenizer (the repo's is "
+                "word-level: an export whose sentence tower reads sub-word "
+                "ids, model.text_tower='lm', has none) — send token_ids "
+                "instead of sentences")
         return self.tokenizer.encode_batch(sentences,
                                            self.engine.text_words)
 
@@ -746,6 +760,9 @@ class RetrievalService:
         self._batcher.close()
         if self._scans is not None:
             self._scans.close()
+        self._admission.close()
+        for gauge in self._bound:
+            gauge.unbind()
 
 
 # ---------------------------------------------------------------------------
@@ -976,7 +993,15 @@ def build_server(cfg):
         meta = json.load(fh)
     tok_meta = meta.get("tokenizer", {})
     tokenizer = None
-    if s.token_dict_path:
+    if meta.get("model", {}).get("text_tower") == "lm":
+        # the repo's tokenizer is word-level; a language-model tower reads
+        # sub-word ids of a vocabulary the repo does not have: the service
+        # is built without one and refuses raw sentences
+        if s.token_dict_path:
+            raise SystemExit("--serve.token_dict_path names a word-level "
+                             "dictionary; this export's sentence tower "
+                             "(model.text_tower='lm') reads sub-word ids")
+    elif s.token_dict_path:
         if not os.path.exists(s.token_dict_path):
             # an explicit operator path must fail loudly at boot — the
             # export-recorded fallback below is the only silent degrade

@@ -215,6 +215,12 @@ def _in_training_eval(cfg: Config, model, state: TrainState, mesh,
 def run_training(cfg: Config, max_steps: Optional[int] = None) -> TrainResult:
     if max_steps is None:
         max_steps = cfg.train.max_steps
+    if cfg.model.text_tower != "bow":   # fail before any init
+        raise ValueError(
+            f"model.text_tower={cfg.model.text_tower!r} cannot be trained: "
+            "a language-model sentence tower is served from an export only "
+            "(no optimizer share or parameter mask for routed layers); "
+            "train with model.text_tower='bow'")
     if cfg.train.evaluate:
         from milnce_tpu.eval.runner import EVAL_TASKS
 

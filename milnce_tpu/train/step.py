@@ -558,6 +558,26 @@ def make_video_embed_fn(model, mesh: Mesh, data_axis: str = "data",
 
 
 def make_text_embed_fn(model, mesh: Mesh, data_axis: str = "data"):
+    """Jitted sentence tower: text_ids sharded on dim 0 -> sharded embeds.
+    A language-model tower (``model.text_lm``) is its own program,
+    ``text_lm_tower``, and returns beside the embeddings its expert
+    layers' counters, name -> int32 scalar over all the data shards
+    (``models/text_lm.py COUNTER_NAMES``)."""
+    if getattr(model, "text_lm", None) is not None:
+        from milnce_tpu.models.text_lm import COUNTERS, sum_counters
+
+        def text_lm_tower(variables, text_ids):
+            emb, sown = model.apply(variables, None, text_ids, mode="text",
+                                    mutable=[COUNTERS])
+            return emb, {
+                name: (jax.lax.pmax if name.endswith("_max")
+                       else jax.lax.psum)(value, data_axis)
+                for name, value in sum_counters(sown).items()}
+
+        return jax.jit(jax.shard_map(
+            text_lm_tower, mesh=mesh, in_specs=(P(), P(data_axis)),
+            out_specs=(P(data_axis), P()), check_vma=False))
+
     def local(variables, text_ids):
         return model.apply(variables, None, text_ids, mode="text")
 

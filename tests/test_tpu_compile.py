@@ -209,3 +209,65 @@ def test_full_width_train_step_fits_one_v5e(topo):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < need < V5E_HBM_BYTES, (
         f"full-width step at batch {batch} needs {need / 1e9:.2f} GB")
+
+
+# --------------------------------------------------------------------------
+# the language-model sentence tower at its published widths, top rung
+# --------------------------------------------------------------------------
+
+def test_axk1_sentence_tower_fits_beside_what_the_cell_holds(topo):
+    """``make_text_embed_fn``'s program (``text_lm_tower``) for the
+    ``text_lm`` group that ``benchmarks/drivers/serve_lm.py`` makes from
+    ``benchmarks/configs/s3dg-axk1-text-32f224.json`` — hidden 7168, MLA
+    ranks 1536/512, 192-wide router, 12 experts held, 8 layers, bfloat16 —
+    at the 64-row rung of 32 tokens, lowered from ``jax.eval_shape``
+    shapes onto a one-device mesh of the described topology.  It compiles
+    (the grouped expert products lower to the TPU's ragged-dot kernel),
+    and what it keeps and needs on the device leaves room, inside the
+    16,909,336,064 bytes the chip's ``memory_stats()`` gives as
+    ``bytes_limit`` (PERF.md), for what the cell holds beside it: while
+    serving, the index shard and two (64, rows) float32 score blocks; at
+    boot, the video tower's 64-row warm-up (4.95 + 0.37 GB, PR 28's
+    compile of it for this topology)."""
+    from benchmarks import harness
+    from benchmarks.drivers import serve_lm
+    from milnce_tpu.config import parse_cli
+    from milnce_tpu.models import text_lm
+    from milnce_tpu.models.build import build_model
+    from milnce_tpu.train.step import make_text_embed_fn
+
+    cell = harness.load_json("benchmarks/configs/s3dg-axk1-text-32f224.json")
+    cfg = parse_cli(serve_lm.text_lm_flags(cell) + [
+        "--model.text_tower", "lm", "--model.dtype", "bfloat16"])
+    rows, words = cell["serve"]["max_batch"], cell["data"]["max_words"]
+    assert (cfg.text_lm.hidden_size, cfg.text_lm.n_routed_experts,
+            cfg.text_lm.experts_held, rows, words) == (7168, 192, 12, 64, 32)
+    model = build_model(cfg.model, text_lm=cfg.text_lm)
+    tower = text_lm.TextLM(model.text_lm, embd_dim=512, dtype=jnp.bfloat16)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    repl, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    shapes = jax.eval_shape(tower.init, jax.random.PRNGKey(0),
+                            jnp.zeros((rows, words), jnp.int32))["params"]
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=repl),
+        {"text_module": shapes})
+    held = sum(s.size for s in jax.tree_util.tree_leaves(shapes))
+    assert 5.3e9 < held < 5.45e9            # 5.37 B parameters, 10.75 GB
+    compiled = make_text_embed_fn(model, mesh).lower(
+        {"params": params},
+        jax.ShapeDtypeStruct((rows, words), jnp.int32,
+                             sharding=data)).compile()
+    assert "ragged-dot" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    limit = 16_909_336_064
+    index = cell["index"]["rows"] * cell["index"]["dim"] * 4
+    scores = 2 * rows * cell["index"]["rows"] * 4
+    video_warm_up = 4.95e9 + 0.37e9
+    assert need + index + scores < limit, (
+        f"tower {need / 1e9:.2f} GB + index {index / 1e9:.2f} GB + scores "
+        f"{scores / 1e9:.2f} GB")
+    assert mem.argument_size_in_bytes + video_warm_up < limit, (
+        f"tower's weights {mem.argument_size_in_bytes / 1e9:.2f} GB beside "
+        "the video tower's warm-up")
