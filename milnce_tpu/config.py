@@ -462,6 +462,12 @@ class ServeConfig:
     ``max_delay_ms`` bounds how long a lone request waits for batch
     company, ``default_timeout_ms`` bounds total queue wait before a
     request errors (DeadlineExpired) instead of silently aging.
+    ``max_delay_ms`` and ``continuous_batching`` govern the text batcher
+    of a POOLED service only (``replicas`` > 1 or an edge tier): a single
+    engine's one device worker takes the text flush and the pass over the
+    index in turn and closes each batch at the instant it turns to it —
+    every row that waits, no window, no lane (serving/service.py
+    ``RetrievalService._drive``).
 
     Resilience tier (serving/pool.py, ROBUSTNESS.md "Serving request
     path"): ``replicas`` > 1 serves through a ReplicaPool — per-replica
@@ -472,7 +478,9 @@ class ServeConfig:
 
     max_batch: int = 64                 # top of the bucket ladder
     min_bucket: int = 0                 # smallest bucket (0 = mesh size)
-    max_delay_ms: float = 5.0           # batcher flush-on-delay bound
+    max_delay_ms: float = 5.0           # flush-on-delay bound of a POOLED
+                                        # service's text batcher (a single
+                                        # engine has no window)
     default_timeout_ms: float = 0.0     # per-request queue deadline (0 = none)
     cache_capacity: int = 4096          # LRU text-embedding cache entries
                                         # (<= 0 disables)
@@ -534,7 +542,10 @@ class ServeConfig:
                                         # requests shed with HTTP 429 +
                                         # Retry-After (0 = unbounded).
                                         # /healthz and /metrics never shed.
-    continuous_batching: bool = False   # admit requests into partially-
+    continuous_batching: bool = False   # POOLED service's text batcher
+                                        # only (a single engine's device
+                                        # worker always flushes what waits):
+                                        # admit requests into partially-
                                         # filled bucket slots: flush the
                                         # instant a dispatch lane is free,
                                         # accumulate while lanes are busy

@@ -46,16 +46,27 @@ a batch returns need not be one array either: ``take`` says how a
 request's rows are cut out of it (the scan coalescer of service.py gets
 scores, indices and the generation that ranked them).
 
+**Driven** (``wake=`` — service.py's device worker): the batcher starts
+no worker of its own.  ``wake()`` is called at every submit; the owner's
+one thread asks for ``take()`` — every request that waits NOW, up to the
+top bucket, blocks whole — at the instant it turns to this batcher, and
+runs it with ``flush(batch)``.  One thread can so take two batchers in
+turn (a text flush, then the pass that ranks its rows), with neither a
+window nor a lane between them: ``max_delay_ms``, ``continuous`` and
+``lanes`` govern a batcher that has its own worker.
+
 numpy-only on purpose: payloads and results are host arrays; every
 device interaction lives behind the injected ``run_batch`` callable.
 Thread safety: ``submit`` may be called from any number of threads;
-one worker thread owns the flush path; every counter lives on the obs
+one thread (the worker, or the owner of a driven batcher) owns the flush
+path; every counter lives on the obs
 metrics registry (lock-guarded there — OBSERVABILITY.md), so request
 threads and the worker can no longer race an unlocked dict.
 """
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -154,6 +165,10 @@ class DynamicBatcher:
       ``bucket_for`` then only names the bucket on the flush record.
     - ``span_name``: the flush record's name, so that two batchers of
       one service can be told apart by it (OBSERVABILITY.md).
+    - ``wake``: driven (module docstring): no worker is started,
+      ``wake()`` runs at every submit and at ``close``, and the owner's
+      thread calls :meth:`take` and :meth:`flush`; ``max_delay_ms``,
+      ``continuous`` and ``lanes`` are then ignored.
     """
 
     def __init__(self, run_batch: Callable[[np.ndarray], np.ndarray],
@@ -168,19 +183,21 @@ class DynamicBatcher:
                                                     Future]] = None,
                  continuous: bool = False, lanes: int = 1,
                  take: Optional[Callable] = None, pad: bool = True,
-                 span_name: str = "batcher.flush"):
+                 span_name: str = "batcher.flush",
+                 wake: Optional[Callable[[], None]] = None):
         assert max_batch >= 1
         self._run_batch = run_batch
         self._take = take
         self._pad = bool(pad)
         self._span_name = span_name
+        self._wake = wake
         self._run_batch_async = run_batch_async
-        self.continuous = bool(continuous)
+        self.continuous = bool(continuous) and wake is None
         # in-flight batch bound for continuous mode: acquired by the
         # worker before each flush, released when the flush resolves
         # (sync: after run_batch; async: in the completion callback)
         self._lane_sem = (threading.Semaphore(max(1, int(lanes)))
-                          if continuous else None)
+                          if self.continuous else None)
         # flush-latency observer ``(dur_ms, live_rows) -> None``: the
         # service feeds its EWMA spike detector here (anomaly-triggered
         # profiler capture).  Invoked on the worker thread AFTER the
@@ -197,10 +214,10 @@ class DynamicBatcher:
         self.default_timeout_ms = float(default_timeout_ms)
         self.name = name
         self._q: queue.Queue[_Request] = queue.Queue()
-        # a block that did not fit the batch being formed: it leads the
-        # next one.  The worker's alone (set, taken and failed at close
-        # on its thread)
-        self._carry: Optional[_Request] = None
+        # a block that did not fit the batch being formed (one at most):
+        # it leads the next one.  The flushing thread's alone (put, taken
+        # and failed at close on it)
+        self._held_over: collections.deque[_Request] = collections.deque()
         self._closed = threading.Event()
         self.registry = registry if registry is not None \
             else obs_metrics.MetricsRegistry()
@@ -255,38 +272,47 @@ class DynamicBatcher:
         # while the worker parks on busy lanes
         self._forming = 0                     # guarded-by: _forming_lock
         self._forming_lock = make_lock("serving.batcher.forming")
-        self._worker = threading.Thread(target=self._run, daemon=True,
-                                        name=f"{name}-worker")
-        self._worker.start()
+        self._worker = None
+        if wake is None:
+            self._worker = threading.Thread(target=self._run, daemon=True,
+                                            name=f"{name}-worker")
+            self._worker.start()
 
     # ---- client side ----------------------------------------------------
 
-    def submit(self, payload: np.ndarray,
-               timeout_ms: Optional[float] = None) -> Future:
+    def submit(self, payload: np.ndarray, timeout_ms: Optional[float] = None,
+               future: Optional[Future] = None) -> Future:
         """Enqueue one row; returns a Future resolving to its result row.
 
         ``timeout_ms``: deadline for THIS request (None = the batcher
-        default; <= 0 = no deadline)."""
-        return self._enqueue(np.asarray(payload)[None], timeout_ms, False)
+        default; <= 0 = no deadline).  ``future``: the caller's own, to
+        resolve in place of a new one — its done-callbacks, added before
+        the row can be flushed, run on the thread that flushes it."""
+        return self._enqueue(np.asarray(payload)[None], timeout_ms, False,
+                             future)
 
     def submit_block(self, rows: np.ndarray,
-                     timeout_ms: Optional[float] = None) -> Future:
+                     timeout_ms: Optional[float] = None,
+                     future: Optional[Future] = None) -> Future:
         """Enqueue ``(n, ...)`` rows that ride ONE batch; the Future
-        resolves to their share of it, in order.  More rows than the top
-        bucket fail with ``bucket_for``'s error."""
-        return self._enqueue(np.asarray(rows), timeout_ms, True)
+        (``future``, as :meth:`submit` takes it) resolves to their share
+        of it, in order.  More rows than the top bucket fail with
+        ``bucket_for``'s error."""
+        return self._enqueue(np.asarray(rows), timeout_ms, True, future)
 
     def _enqueue(self, payload: np.ndarray, timeout_ms: Optional[float],
-                 block: bool) -> Future:
+                 block: bool, future: Optional[Future]) -> Future:
         if self._closed.is_set():
             raise RuntimeError("batcher is closed")
         t_ms = self.default_timeout_ms if timeout_ms is None else timeout_ms
         now = time.monotonic()
         deadline = (now + t_ms / 1000.0) if t_ms > 0 else None
-        fut: Future = Future()
+        fut: Future = Future() if future is None else future
         n = payload.shape[0]
         self._m_requests.inc(n)
         self._q.put(_Request(payload, fut, deadline, now, n, block))
+        if self._wake is not None:
+            self._wake()
         if self._closed.is_set():
             # close() raced the put above: the worker may already have
             # drained and exited, so this request would hang forever —
@@ -299,17 +325,34 @@ class DynamicBatcher:
 
     def _run(self) -> None:
         (self._run_continuous if self.continuous else self._run_windowed)()
-        if self._carry is not None:
-            self._fail_closed(self._carry)
-            self._carry = None
+        self._fail_waiting()
+
+    def _fail_waiting(self) -> None:
+        """Closed: fail the held-over block and the queue (on the
+        flushing thread, the held-over block's owner)."""
+        while self._held_over:
+            self._fail_closed(self._held_over.popleft())
         self._drain_closed()
+
+    def take(self) -> list:
+        """Driven: every request that waits NOW — the held-over block,
+        then the queue's, up to the top bucket, blocks whole — less those
+        whose deadline has passed, failed here (the owner has just come
+        back from the device).  Empty when nothing waits; once closed,
+        what waited is failed and nothing is handed out."""
+        if self._closed.is_set():
+            self._fail_waiting()
+            return []
+        batch: list = []
+        self._drain_into(batch)
+        self._set_forming(sum(r.rows for r in self._held_over))
+        return self._expire(batch)
 
     def _next(self, timeout: Optional[float] = None) -> _Request:
         """The held-over block first, else the queue's next request
         (``queue.Empty`` after ``timeout``; None = without waiting)."""
-        if self._carry is not None:
-            r, self._carry = self._carry, None
-            return r
+        if self._held_over:
+            return self._held_over.popleft()
         if timeout is None:
             return self._q.get_nowait()
         return self._q.get(timeout=timeout)
@@ -320,7 +363,7 @@ class DynamicBatcher:
         batch takes anything — a block larger than the top bucket fails
         alone, at ``bucket_for``."""
         if batch and n + r.rows > self.max_batch:
-            self._carry = r
+            self._held_over.append(r)
             return False
         batch.append(r)
         return True
@@ -348,7 +391,7 @@ class DynamicBatcher:
                 if not self._offer(batch, n, r):
                     break
                 n += r.rows
-            self._flush(batch)
+            self.flush(batch)
 
     def _run_continuous(self) -> None:
         """Continuous batching: flush as soon as a lane is free, fill
@@ -372,7 +415,7 @@ class DynamicBatcher:
                 for r in batch:
                     self._fail_closed(r)
                 break
-            self._flush(batch)      # the flush resolution frees the lane
+            self.flush(batch)       # the flush resolution frees the lane
 
     def _set_forming(self, n: int) -> None:
         with self._forming_lock:
@@ -391,8 +434,7 @@ class DynamicBatcher:
             if not self._offer(batch, n, r):
                 break
             n += r.rows
-        self._set_forming(
-            n + (self._carry.rows if self._carry is not None else 0))
+        self._set_forming(n + sum(r.rows for r in self._held_over))
 
     def _release_lane(self) -> None:
         if self._lane_sem is not None:
@@ -416,7 +458,10 @@ class DynamicBatcher:
             self._m_expired.inc(expired)
         return live
 
-    def _flush(self, batch: list[_Request]) -> None:
+    def flush(self, batch: list[_Request], **attrs) -> None:
+        """Run ``batch`` (the worker's, or what :meth:`take` handed a
+        driven batcher's owner) and scatter what it returns; ``attrs``
+        join the flush record."""
         live = self._expire(batch)
         if not live:
             self._release_lane()
@@ -427,7 +472,8 @@ class DynamicBatcher:
         t0 = time.monotonic()
         waits_ms = [(t0 - r.submitted) * 1e3 for r in live]
         waited = {"queue_wait_ms": round(max(waits_ms), 4),
-                  "queue_wait_mean_ms": round(sum(waits_ms) / len(live), 4)}
+                  "queue_wait_mean_ms": round(sum(waits_ms) / len(live), 4),
+                  **attrs}
         try:
             # the whole batch computation is inside the try: a bad
             # payload (mixed row shapes -> np.concatenate raises) must fail
@@ -547,8 +593,15 @@ class DynamicBatcher:
     # ---- lifecycle / observability --------------------------------------
 
     def close(self, timeout: float = 5.0) -> None:
+        """Refuse new submits and fail what waits: the worker does on its
+        way out; a driven batcher's queue is failed here and its
+        held-over block by the owner's next :meth:`take`."""
         self._closed.set()
-        self._worker.join(timeout)
+        if self._worker is not None:
+            self._worker.join(timeout)
+        else:
+            self._wake()
+            self._drain_closed()
 
     def depth(self) -> int:
         """Requests currently queued (approximate — stdlib qsize) plus

@@ -461,7 +461,7 @@ class ReplicaPool:
                 # injected on_latency callback).  A raising callback
                 # must never kill the lane — a dead worker would strand
                 # every queued dispatch while the replica still reads
-                # SERVING (the exact failure DynamicBatcher._flush
+                # SERVING (the exact failure DynamicBatcher.flush
                 # defends against).  Resolve the caller (no-op if the
                 # dispatch already resolved) and keep draining.
                 self._resolve(d, exc=exc)

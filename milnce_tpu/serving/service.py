@@ -3,11 +3,15 @@
 Request flow for a text query (the full tentpole path)::
 
     sentence --tokenizer--> token row --cache?--> hit: cached embedding
-                                      \\--miss--> DynamicBatcher (pad to
-                                      bucket) --> InferenceEngine.embed_text
-    embedding --> scan coalescer (every row that is waiting, up to the
-                  index's top bucket, in ONE pass) -->
-                  DeviceRetrievalIndex.topk --> (scores, corpus indices)
+                                      \\--miss--> text queue
+    device worker (single engine): pass, flush, pass, flush ...
+      flush: every row of the text queue, padded to its bucket -->
+             InferenceEngine.embed_text --> cache; a call whose last
+             embedding this was joins the scan queue, there and then
+      pass:  every block of the scan queue (hits straight from the
+             caller, and what the last flush embedded), up to the
+             index's top bucket, ONE DeviceRetrievalIndex.topk -->
+             (scores, corpus indices) to each caller
 
 Everything device-side is pre-traced and transfer-guarded (engine.py /
 index.py); everything host-side is stdlib + numpy.  The HTTP front is
@@ -76,7 +80,10 @@ import contextlib
 import json
 import logging
 import math
+import threading
 import time
+from concurrent.futures import Future, InvalidStateError
+from functools import partial
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -354,8 +361,110 @@ class AdmissionController:
         return out
 
 
+class _Call(Future):
+    """One call's rows from the cache to its answer: the caller waits on
+    this and on nothing else.  Rows the cache lacked go to the text
+    batcher one by one; the thread that embeds the LAST of them (the
+    device worker, inside its flush) stacks the call's embeddings and,
+    for a query, puts them in the scan queue as one block that resolves
+    this Future — the pass after that flush finds them there, and no
+    caller thread stands between the tower and the scan.  An embed-only
+    call resolves to the embeddings.  The admission slot is released when
+    the last embedding exists, or with the first failure."""
+
+    def __init__(self, svc: "RetrievalService", keys: Optional[list],
+                 emb: list, timeout_ms: Optional[float], ranked: bool,
+                 slot: contextlib.ExitStack):
+        """``emb``: the call's embeddings, None where the cache had none
+        (``keys``: their cache keys; None = nothing of this is cached)."""
+        super().__init__()
+        self._svc, self._keys, self._emb = svc, keys, emb
+        self._timeout_ms, self._ranked = timeout_ms, ranked
+        self._lock = make_lock("serving.call")
+        self._slot: Optional[contextlib.ExitStack] = slot  # guarded-by: _lock
+        self._missing = sum(e is None for e in emb)        # guarded-by: _lock
+        # the last flush that embedded rows of this call, and how many
+        # (the device worker's ``chained_rows``)
+        self.epoch, self.fresh = -1, 0                     # guarded-by: _lock
+        self.embedded_at: Optional[float] = None    # obs_spans.now()
+
+    def start(self, rows: np.ndarray) -> None:
+        """Hand the rows that lack an embedding to the text batcher (the
+        caller's thread); a call that lacks none goes straight to the
+        scan queue."""
+        missing = [i for i, hit in enumerate(self._emb) if hit is None]
+        if not missing:
+            self._embedded()
+        for i in missing:
+            row: Future = Future()
+            row.add_done_callback(partial(self._row_done, i))
+            self._svc._batcher.submit(rows[i], self._timeout_ms, future=row)
+
+    def _row_done(self, i: int, row: Future) -> None:
+        """On the thread that resolved ``row``: the device worker (a pool
+        worker's completion callback on a pooled service)."""
+        exc = row.exception()
+        if exc is not None:
+            self.release()
+            self._settle(exc=exc)
+            return
+        emb = row.result()
+        self._svc.cache.put(self._keys[i], emb)
+        epoch = self._svc._epoch
+        with self._lock:
+            self._emb[i] = emb
+            if self.epoch != epoch:
+                self.epoch, self.fresh = epoch, 0
+            self.fresh += 1
+            self._missing -= 1
+            last = self._missing == 0
+        if last:
+            self._embedded()
+
+    def _embedded(self) -> None:
+        self.embedded_at = obs_spans.now()
+        self.release()
+        if self.done():                 # an earlier row failed the call
+            return
+        emb = np.stack(self._emb) if self._emb else np.zeros(
+            (0, self._svc.engine.embed_dim or 0), np.float32)
+        if not self._ranked:
+            self._settle(emb)
+            return
+        try:
+            self._svc._scans.submit_block(emb, self._timeout_ms, future=self)
+        except RuntimeError as exc:     # the service is closed
+            self._settle(exc=exc)
+
+    def _settle(self, result=None, exc: Optional[BaseException] = None):
+        try:
+            if exc is not None:
+                self.set_exception(exc)
+            else:
+                self.set_result(result)
+        except InvalidStateError:
+            pass                        # the first failure keeps the call
+
+    def release(self) -> None:
+        """Give the admission slot back, once, from whichever thread."""
+        with self._lock:
+            slot, self._slot = self._slot, None
+        if slot is not None:
+            slot.close()
+
+
 class RetrievalService:
-    """Programmatic API over engine + batcher + cache + index."""
+    """Programmatic API over engine + batcher + cache + index.
+
+    On a single engine ONE thread (``device-worker``) owns the device for
+    the query path and takes the two programs in turn — pass, flush,
+    pass, flush — closing each batch at the instant it turns to it
+    (:meth:`_drive`): ``max_delay_ms`` and ``continuous`` then govern
+    nothing.  Over a :class:`~milnce_tpu.serving.pool.ReplicaPool` the
+    text batcher keeps its own worker (those two arguments are its), its
+    flushes resolve on the pool's workers, and the scan coalescer is a
+    continuous one-lane batcher with a worker of its own; which of the
+    two a service is follows from what it was built over, nothing else."""
 
     def __init__(self, engine, index=None, *, tokenizer=None,
                  cache: Optional[EmbeddingLRUCache] = None,
@@ -419,6 +528,17 @@ class RetrievalService:
             if self._pool is None:
                 self._admission.observe_flush(dur_ms, rows)
 
+        # a single engine's two batchers are DRIVEN by the device worker
+        # (started last, below): a submit wakes it, it takes what waits
+        driven = self._pool is None
+        self._wake = threading.Event()
+        self._stop = threading.Event()
+        # counts the device worker's turns: +1 before a flush and after
+        # a pass, so that a call embedded in THIS epoch was embedded by
+        # the flush immediately before the pass that reads it.  The
+        # device worker's alone to write
+        self._epoch = 0
+        wake = self._wake.set if driven else None
         self._batcher = DynamicBatcher(
             engine.embed_text, engine.bucket_for, max_batch=engine.max_batch,
             max_delay_ms=max_delay_ms, default_timeout_ms=default_timeout_ms,
@@ -429,10 +549,10 @@ class RetrievalService:
             run_batch_async=(self._pool.submit_text
                              if self._pool is not None else None),
             # continuous batching (SERVING.md): one dispatch lane per
-            # pool replica; the single-engine path has exactly one
+            # pool replica (a driven batcher has neither window nor lane)
             continuous=continuous,
             lanes=(len(self._pool.replicas)
-                   if self._pool is not None else 1))
+                   if self._pool is not None else 1), wake=wake)
         if self._pool is not None:
             # the pool's per-dispatch latencies feed the same spike
             # detector (the anomaly->capture path sees replica-level
@@ -444,13 +564,13 @@ class RetrievalService:
                 self._admission.observe_flush(dur_ms, rows)
 
             self._pool.set_on_latency(_on_dispatch)
-        # The scan coalescer: ONE worker owns this service's calls of
-        # ``index.topk``.  It scans the instant no scan is in flight with
-        # whatever rows are waiting (hits and misses alike, a call's rows
-        # together), and rows that arrive meanwhile ride the next pass —
-        # the text batcher's continuous mode with one lane, so a lone
-        # caller pays a thread hand-off and no window.  Always on:
-        # ``max_delay_ms`` and ``continuous`` govern the text batcher only.
+        # The scan coalescer: ONE thread owns this service's calls of
+        # ``index.topk`` and scans with whatever blocks are waiting (hits
+        # and misses alike, a call's rows together); rows that arrive
+        # meanwhile ride the next pass.  On a single engine that thread
+        # is the device worker, between two text flushes; over a pool,
+        # the batcher's own (continuous, one lane).  A lone caller pays a
+        # thread hand-off and no window either way.
         self._scans = None
         if index is not None:
             ladder = getattr(index, "query_buckets", None) or engine.buckets
@@ -460,7 +580,7 @@ class RetrievalService:
                 name="topk", registry=self.registry, buckets=engine.buckets,
                 recorder=recorder, continuous=True, lanes=1, pad=False,
                 take=lambda out, at: (out[0][at], out[1][at], out[2]),
-                span_name="topk.flush")
+                span_name="topk.flush", wake=wake)
         self._default_timeout_ms = float(default_timeout_ms)
         self._m_degraded = self.registry.counter(
             "milnce_serve_degraded_total",
@@ -495,6 +615,44 @@ class RetrievalService:
             self._bound.append(
                 reg.gauge("milnce_serve_index_size", "corpus rows indexed",
                           fn=lambda: self.index.stats()["size"]))
+        self._device_worker = None
+        if driven:
+            self._device_worker = threading.Thread(
+                target=self._drive, daemon=True, name="device-worker")
+            self._device_worker.start()
+
+    # ---- the device worker (single engine) ---------------------------------
+
+    def _drive(self) -> None:
+        """Pass, flush, pass, flush: while blocks wait to be ranked, ALL
+        of them (up to the top bucket) in one pass; then, while rows wait
+        to be embedded, ALL of them in one flush, whose scatter — on this
+        thread — puts each call it completed into the scan queue before
+        anything else may have the device.  So a flush's rows ride the
+        very next pass, the two alternate when both queues hold rows
+        (neither starves), and with both empty the worker sleeps until a
+        submit wakes it: a lone caller pays a thread hand-off, no timer.
+        ``chained_rows`` on the ``topk.flush`` record: the rows of this
+        pass that the flush immediately before it embedded."""
+        text, scans = self._batcher, self._scans
+        while True:
+            self._wake.clear()
+            stopping = self._stop.is_set()      # read AFTER the clear
+            blocks = scans.take() if scans is not None else []
+            if blocks:
+                epoch = self._epoch
+                scans.flush(blocks, chained_rows=sum(
+                    b.future.fresh for b in blocks
+                    if b.future.epoch == epoch))
+                self._epoch += 1        # a block held over rides unchained
+            rows = text.take()
+            if rows:
+                self._epoch += 1
+                text.flush(rows)
+            if stopping:                # closed batchers: the takes above
+                return                  # failed whatever waited
+            if not (blocks or rows):
+                self._wake.wait()
 
     # ---- embedding path --------------------------------------------------
 
@@ -502,7 +660,7 @@ class RetrievalService:
                        timeout_ms: Optional[float] = None,
                        tier: Optional[str] = None,
                        replica_class: Optional[str] = None,
-                       note: Optional[dict] = None) -> np.ndarray:
+                       note: Optional[dict] = None, ranked: bool = False):
         """(n, W) int32 -> (n, D): cache hits answered on host, misses
         batched through the engine; results land back in the cache.
 
@@ -521,9 +679,15 @@ class RetrievalService:
         full-precision request would mix tiers.  None (the default)
         batches across every class as usual.
 
-        ``note``: a record (the ``query`` span) that gets ``cache_hits``
-        and ``embed_wait_ms`` — first submit to last row resolved, 0
-        for a call of hits only."""
+        The caller waits for ONE result (:class:`_Call`).  ``ranked`` is
+        :meth:`query_ids_with_gen`'s way in (every entry embeds through
+        this method, looked up on the instance): the embeddings go on to
+        the scan queue from the thread that embeds the last of them, and
+        what returns is the pass's answer (scores, indices, generation).
+        ``note`` (the ``query`` span) gets ``cache_hits``,
+        ``embed_wait_ms`` — first submit to last row embedded, 0 for a
+        call of hits only — and, ranked, ``topk_ms``: from the instant
+        the call's last embedding exists to its answer."""
         note = {} if note is None else note
         rows = np.ascontiguousarray(token_ids, dtype=np.int32)
         if rows.ndim != 2:
@@ -537,39 +701,42 @@ class RetrievalService:
         # the check for every default-deadline client)
         eff_timeout_ms = (self._default_timeout_ms if timeout_ms is None
                           else float(timeout_ms))
-        with self._admission.admit(rows.shape[0], eff_timeout_ms, tier):
-            t0 = obs_spans.now()
+        # the slot covers the cache and the wait for the embeddings, not
+        # the scan: the call gives it back when its last embedding exists
+        t0 = obs_spans.now()
+        with contextlib.ExitStack() as slot:
+            slot.enter_context(
+                self._admission.admit(rows.shape[0], eff_timeout_ms, tier))
             if replica_class is not None:
-                try:
-                    return self._embed_class_pinned(rows, replica_class)
-                finally:
-                    note.update(cache_hits=0,
-                                embed_wait_ms=obs_spans.ms_since(t0))
-            keys = [token_key(r) for r in rows]
-            out: list[Optional[np.ndarray]] = [self.cache.get(k)
-                                               for k in keys]
-            note.update(cache_hits=sum(hit is not None for hit in out),
-                        embed_wait_ms=0.0)
-            pending = [(i, self._batcher.submit(rows[i], timeout_ms))
-                       for i, hit in enumerate(out) if hit is None]
-            wait = self._result_wait_s(timeout_ms)
-            for i, fut in pending:
-                try:
-                    row = fut.result(timeout=wait)
-                except PoolUnavailable as exc:
-                    reason = ("cache_only" if self.cache.capacity > 0
-                              else exc.reason)
-                    self._m_degraded.labels(reason=reason).inc()
-                    raise DegradedError(
-                        f"no healthy replica to embed this request "
-                        f"({exc}); cache hits are still served",
-                        reason) from exc
-                self.cache.put(keys[i], row)
-                out[i] = row
-            if pending:
-                note["embed_wait_ms"] = obs_spans.ms_since(t0)
-            return np.stack(out) if out else np.zeros(
-                (0, self.engine.embed_dim or 0), np.float32)
+                keys = None
+                emb = list(self._embed_class_pinned(rows, replica_class))
+            else:
+                keys = [token_key(r) for r in rows]
+                emb = [self.cache.get(k) for k in keys]
+            call = _Call(self, keys, emb, timeout_ms, ranked, slot.pop_all())
+        note.update(cache_hits=(0 if keys is None else
+                                sum(hit is not None for hit in emb)),
+                    embed_wait_ms=0.0)
+        waited = keys is None or any(hit is None for hit in emb)
+        try:
+            call.start(rows)
+            return call.result(timeout=self._result_wait_s(timeout_ms))
+        except PoolUnavailable as exc:
+            reason = ("cache_only" if self.cache.capacity > 0
+                      else exc.reason)
+            self._m_degraded.labels(reason=reason).inc()
+            raise DegradedError(
+                f"no healthy replica to embed this request "
+                f"({exc}); cache hits are still served",
+                reason) from exc
+        finally:
+            call.release()      # its own came first, unless it failed here
+            if call.embedded_at is not None:
+                if waited:
+                    note["embed_wait_ms"] = round(
+                        (call.embedded_at - t0) * 1e3, 4)
+                if ranked:
+                    note["topk_ms"] = obs_spans.ms_since(call.embedded_at)
 
     def _embed_class_pinned(self, rows: np.ndarray,
                             replica_class: str) -> np.ndarray:
@@ -621,13 +788,16 @@ class RetrievalService:
 
         The call's rows ride one scan of the coalescer, with whatever
         other callers' rows are waiting, so the generation is the one
-        that ranked every row of the call; ``timeout_ms`` bounds the
-        wait for that scan as it bounds the wait for the text flush.
+        that ranked every row of the call — a call of hits and misses
+        joins the scan queue whole, when its last row is embedded;
+        ``timeout_ms`` bounds the wait for that scan as it bounds the
+        wait for the text flush.
 
         One ``query`` span per call (``rows``, ``cache_hits``,
-        ``embed_wait_ms``, ``topk_ms``: handed to the coalescer until
-        answered; ``error`` on a refusal or a failure): the record's
-        ``mono`` is the answer's instant on the program's own clock."""
+        ``embed_wait_ms``, ``topk_ms``: from the call's last embedding
+        to its answer; ``error`` on a refusal or a failure): the
+        record's ``mono`` is the answer's instant on the program's own
+        clock."""
         if self.index is None:
             raise ValueError("service built without a retrieval index")
         k = self.index.k if k is None else int(k)
@@ -636,13 +806,9 @@ class RetrievalService:
         self._m_queries.inc(len(token_ids))
         with self.recorder.span("query", rows=len(token_ids)) as span:
             try:
-                emb = self.embed_text_ids(token_ids, timeout_ms, tier,
-                                          replica_class, note=span)
-                t0 = obs_spans.now()
-                scores, idx, gen = self._scans.submit_block(
-                    emb, timeout_ms).result(
-                        timeout=self._result_wait_s(timeout_ms))
-                span["topk_ms"] = obs_spans.ms_since(t0)
+                scores, idx, gen = self.embed_text_ids(
+                    token_ids, timeout_ms, tier, replica_class, note=span,
+                    ranked=True)
             except (ShedError, DegradedError, PoolSaturated,
                     PoolUnavailable):
                 raise    # refusals, not failures: counted on their own
@@ -760,6 +926,10 @@ class RetrievalService:
         self._batcher.close()
         if self._scans is not None:
             self._scans.close()
+        if self._device_worker is not None:
+            self._stop.set()            # after the batchers are closed:
+            self._wake.set()            # the worker's last takes fail
+            self._device_worker.join(5.0)   # whatever it had held over
         self._admission.close()
         for gauge in self._bound:
             gauge.unbind()

@@ -1,7 +1,10 @@
-"""The scan coalescer of ``RetrievalService`` (ISSUE 27): every caller
-that is waiting shares ONE pass over the index.  Stub engine and stub
-index (numpy only, no device): the index counts its scans and can hold
-one in flight while the test lines callers up behind it."""
+"""The query path of a single-engine ``RetrievalService``: every caller
+that is waiting shares ONE pass over the index (ISSUE 27), and ONE device
+worker takes the tower's flush and the pass in turn, so that a flush's
+rows ride the very next pass (ISSUE 29).  Stub engine and stub index
+(numpy only, no device): each lists what it ran in ``order``, and can
+hold a flush or a pass in flight while the test lines callers up behind
+it.  Decided by order and counts, never by wall time."""
 
 import threading
 import time
@@ -19,50 +22,20 @@ _WORDS, _DIM, _K = 4, 8, 3
 _LADDER = (4, 8)
 
 
-def _bucket_for(n):
-    for b in _LADDER:
+def _bucket_for(n, ladder=_LADDER):
+    for b in ladder:
         if n <= b:
             return b
     raise ValueError(f"{n} queries exceeds the top query bucket "
-                     f"{_LADDER[-1]}")
+                     f"{ladder[-1]}")
 
 
-class _Engine:
-    """``embed_text`` is a fixed linear map of the token ids."""
-
-    buckets = _LADDER
-    max_batch = _LADDER[-1]
-    text_words = _WORDS
-    embed_dim = _DIM
-    bucket_for = staticmethod(_bucket_for)
+class _Held:
+    """``hold()`` makes the next run wait, inside it, until
+    ``release()``; ``step()`` lets exactly that one through and holds
+    the one after it."""
 
     def __init__(self):
-        self._w = np.random.default_rng(0).normal(
-            size=(_WORDS, _DIM)).astype(np.float32)
-
-    def embed_text(self, rows):
-        return rows.astype(np.float32) @ self._w
-
-    def recompiles(self):
-        return 0
-
-    def stats(self):
-        return {}
-
-
-class _Index:
-    """Brute-force top-k over a small corpus.  ``scans`` lists the rows
-    of every pass; ``hold()`` makes the next pass wait, inside the scan,
-    until ``release()``."""
-
-    k = _K
-    query_buckets = _LADDER
-    bucket_for = staticmethod(_bucket_for)
-
-    def __init__(self):
-        self.corpus = np.random.default_rng(1).normal(
-            size=(32, _DIM)).astype(np.float32)
-        self.scans = []
         self.entered = threading.Event()
         self._gate = None
 
@@ -74,6 +47,62 @@ class _Index:
         gate, self._gate = self._gate, None
         gate.set()
 
+    def step(self):
+        gate, self._gate = self._gate, threading.Event()
+        self.entered.clear()
+        gate.set()
+
+    def _wait_here(self):
+        gate = self._gate
+        if gate is not None:
+            self.entered.set()
+            assert gate.wait(10), "the test never released the run"
+
+
+class _Engine(_Held):
+    """``embed_text`` is a fixed linear map of the token ids.  ``order``
+    (shared with the index when the fixture makes both) lists every run:
+    ``("flush", real rows)`` here — the batcher pads, so the real rows
+    are those that are not all zero."""
+
+    text_words = _WORDS
+    embed_dim = _DIM
+
+    def __init__(self, order=None, ladder=_LADDER):
+        super().__init__()
+        self.buckets, self.max_batch = ladder, ladder[-1]
+        self.bucket_for = lambda n: _bucket_for(n, ladder)
+        self.order = [] if order is None else order
+        self._w = np.random.default_rng(0).normal(
+            size=(_WORDS, _DIM)).astype(np.float32)
+
+    def embed_text(self, rows):
+        self.order.append(("flush", int(rows.any(axis=1).sum())))
+        self._wait_here()
+        return rows.astype(np.float32) @ self._w
+
+    def recompiles(self):
+        return 0
+
+    def stats(self):
+        return {}
+
+
+class _Index(_Held):
+    """Brute-force top-k over a small corpus.  ``scans`` lists the rows
+    of every pass (``order`` has them as ``("pass", rows)``)."""
+
+    k = _K
+
+    def __init__(self, ladder=_LADDER):
+        super().__init__()
+        self.query_buckets = ladder
+        self.bucket_for = lambda n: _bucket_for(n, ladder)
+        self.order = []
+        self.corpus = np.random.default_rng(1).normal(
+            size=(32, _DIM)).astype(np.float32)
+        self.scans = []
+
     def rank(self, q):
         scores = q @ self.corpus.T
         idx = np.argsort(-scores, axis=1)[:, :_K].astype(np.int32)
@@ -81,10 +110,8 @@ class _Index:
 
     def topk(self, q):
         self.scans.append(q.shape[0])
-        gate = self._gate
-        if gate is not None:
-            self.entered.set()
-            assert gate.wait(10), "the test never released the scan"
+        self.order.append(("pass", q.shape[0]))
+        self._wait_here()
         return self.rank(q)
 
     def stats(self):
@@ -95,8 +122,8 @@ class _LiveIndex(_Index):
     """The live index's surface: the generation is read when the scan
     starts, as ``LiveRetrievalIndex.topk_with_gen`` captures it."""
 
-    def __init__(self):
-        super().__init__()
+    def __init__(self, ladder=_LADDER):
+        super().__init__(ladder)
         self.generation = 1
 
     def topk_with_gen(self, q):
@@ -120,15 +147,17 @@ def made():
         ring = obs_spans.SpanRecorder(ring=4096)
         kw.setdefault("max_delay_ms", 1.0)
         kw.setdefault("cache", EmbeddingLRUCache(64))
-        svc = RetrievalService(_Engine(), index, recorder=ring,
-                               registry=obs_metrics.MetricsRegistry(), **kw)
+        svc = RetrievalService(
+            _Engine(index.order, index.query_buckets), index, recorder=ring,
+            registry=obs_metrics.MetricsRegistry(), **kw)
         services.append((svc, index))
         return svc, index, ring
 
     yield make
     for svc, index in services:
-        if index._gate is not None:
-            index.release()
+        for held in (index, svc.engine):
+            if held._gate is not None:
+                held.release()
         svc.close()
 
 
@@ -161,8 +190,14 @@ def _wait_for(cond, what, timeout=5.0):
 
 
 def _handed_over(svc):
-    """Rows handed to the coalescer so far."""
+    """Rows handed to the coalescer so far: hits by their callers, the
+    rest by the device worker as its flush embeds them."""
     return svc.health()["scans"]["requests"]
+
+
+def _to_embed(svc):
+    """Rows handed to the text batcher so far (the misses)."""
+    return svc.health()["batcher"]["requests"]
 
 
 def _alone(svc, index, rows):
@@ -177,8 +212,8 @@ def test_waiting_callers_share_at_most_two_scans(made):
     n = 7
     index.hold()
     callers = [_Caller(svc, _rows(100 + i)) for i in range(n)]
-    _wait_for(lambda: _handed_over(svc) == n and index.entered.is_set(),
-              "all rows handed to the coalescer behind the first scan")
+    _wait_for(lambda: _to_embed(svc) == n and index.entered.is_set(),
+              "all rows in a queue behind the first scan")
     index.release()
     for c in callers:
         assert c.done().error is None, c.error
@@ -204,8 +239,8 @@ def test_a_pass_never_carries_more_than_the_top_bucket(made):
     n = 2 * _LADDER[-1] + 3
     index.hold()
     callers = [_Caller(svc, _rows(200 + i)) for i in range(n)]
-    _wait_for(lambda: _handed_over(svc) == n and index.entered.is_set(),
-              "all rows handed over")
+    _wait_for(lambda: _to_embed(svc) == n and index.entered.is_set(),
+              "all rows in a queue")
     index.release()
     for c in callers:
         assert c.done().error is None, c.error
@@ -273,7 +308,7 @@ def test_a_failing_scan_fails_its_rows_and_the_worker_lives(made,
     head = _Caller(svc, _rows(500))
     _wait_for(index.entered.is_set, "the first scan in flight")
     riders = [_Caller(svc, _rows(501 + i)) for i in range(3)]
-    _wait_for(lambda: _handed_over(svc) == 4, "three rows behind it")
+    _wait_for(lambda: _to_embed(svc) == 4, "three rows behind it")
     index.release()
     assert head.done().error is None
     for c in riders:
@@ -313,7 +348,7 @@ def test_a_swap_between_two_scans_stamps_each_call_with_its_own(made):
     a = _Caller(svc, _rows(700, n=2))
     _wait_for(index.entered.is_set, "the first scan in flight")
     b = _Caller(svc, _rows(701, n=3))
-    _wait_for(lambda: _handed_over(svc) == 5, "the second call behind it")
+    _wait_for(lambda: _to_embed(svc) == 5, "the second call behind it")
     index.generation = 2                  # the swap lands between the two
     index.release()
     assert a.done().error is None and b.done().error is None
@@ -330,7 +365,7 @@ def test_close_resolves_every_waiting_future(made):
     head = _Caller(svc, _rows(800))
     _wait_for(index.entered.is_set, "the first scan in flight")
     waiting = [_Caller(svc, _rows(801 + i)) for i in range(4)]
-    _wait_for(lambda: _handed_over(svc) == 5, "four rows behind it")
+    _wait_for(lambda: _to_embed(svc) == 5, "four rows behind it")
     closer = threading.Thread(target=svc.close, daemon=True)
     closer.start()
     time.sleep(0.05)
@@ -356,7 +391,7 @@ def test_a_calls_rows_are_never_split_over_two_scans(made):
     # 5 + 5 rows do not fit the top bucket of 8: two passes of 5, never
     # 8 and 2
     calls = [_Caller(svc, _rows(901 + i, n=5)) for i in range(2)]
-    _wait_for(lambda: _handed_over(svc) == 11, "both calls behind it")
+    _wait_for(lambda: _to_embed(svc) == 11, "both calls behind it")
     index.release()
     for c in [head] + calls:
         assert c.done().error is None, c.error
@@ -385,15 +420,272 @@ def test_a_shed_call_touches_neither_queue(made):
 
 
 def test_a_service_without_an_index_has_no_coalescer():
-    svc = RetrievalService(_Engine(), None,
+    engine = _Engine()
+    svc = RetrievalService(engine, None,
                            registry=obs_metrics.MetricsRegistry())
     try:
         assert svc.health()["scans"] is None
         with pytest.raises(ValueError, match="without a retrieval index"):
             svc.query_ids(_rows(1200))
+        # the embedding-only entry rides the device worker's flush
+        rows = _rows(1201, n=3)
+        got = svc.embed_text_ids(rows)
+        assert {kind for kind, _ in engine.order} == {"flush"}
+        assert sum(n for _, n in engine.order) == 3
+        np.testing.assert_allclose(got, engine.embed_text(rows), rtol=1e-6)
     finally:
         svc.close()
 
+
+
+# ---- the device worker: flush, pass, flush, pass (ISSUE 29) -------------------
+
+def _passes(ring):
+    return [r for r in ring.tail() if r["name"] == "topk.flush"]
+
+
+def test_a_flushs_rows_ride_the_very_next_pass(made):
+    """(a) and (h): what a flush embedded is ranked before the next flush
+    starts, and the pass's record says how many of its rows came so."""
+    svc, index, ring = made()
+    engine = svc.engine
+    engine.hold()
+    first = _Caller(svc, _rows(1300))
+    _wait_for(engine.entered.is_set, "the first flush in flight")
+    rest = [_Caller(svc, _rows(1301 + i)) for i in range(3)]
+    _wait_for(lambda: _to_embed(svc) == 4, "three rows behind the flush")
+    engine.step()                 # the first flush ends; the next is held
+    _wait_for(engine.entered.is_set, "the second flush in flight")
+    # the first flush's row was ranked, and answered, before it started
+    assert index.order == [("flush", 1), ("pass", 1), ("flush", 3)]
+    assert first.done().error is None
+    engine.release()
+    for c in rest:
+        assert c.done().error is None, c.error
+    assert index.order == [("flush", 1), ("pass", 1), ("flush", 3),
+                           ("pass", 3)]
+    assert [(r["rows"], r["chained_rows"]) for r in _passes(ring)] == [
+        (1, 1), (3, 3)]
+    # the flush's own record carries no such attribute
+    assert all("chained_rows" not in r for r in ring.tail()
+               if r["name"] == "batcher.flush")
+
+
+def test_a_hit_that_waits_during_a_flush_shares_its_pass(made):
+    """(b): the cached row goes straight to the scan queue and rides the
+    ONE pass that ranks what the flush in flight embeds."""
+    svc, index, ring = made()
+    hit, miss = _rows(1400), _rows(1401)
+    svc.query_ids(hit)                                  # cached now
+    del index.order[:]
+    svc.engine.hold()
+    m = _Caller(svc, miss)
+    _wait_for(svc.engine.entered.is_set, "the flush in flight")
+    h = _Caller(svc, hit)
+    _wait_for(lambda: _handed_over(svc) == 2, "the hit in the scan queue")
+    svc.engine.release()
+    assert m.done().error is None and h.done().error is None
+    assert index.order == [("flush", 1), ("pass", 2)]
+    last = _passes(ring)[-1]
+    assert (last["rows"], last["chained_rows"]) == (2, 1)
+    np.testing.assert_array_equal(h.answer[1], _alone(svc, index, hit)[1])
+    np.testing.assert_array_equal(m.answer[1], _alone(svc, index, miss)[1])
+
+
+def test_flushes_and_passes_alternate_while_both_queues_hold_rows(made):
+    """(c): 20 hits and 20 misses wait behind a pass in flight, more than
+    two top buckets of each.  Neither program runs twice in a row until
+    the other's queue is empty: the flush does not starve under hits that
+    never stop, nor the pass under misses."""
+    svc, index, _ = made(cache=EmbeddingLRUCache(64))
+    top = _LADDER[-1]
+    hits = [_rows(1500 + i) for i in range(20)]
+    for lo in range(0, 20, top):
+        svc.embed_text_ids(np.concatenate(hits[lo:lo + top]))   # cached
+    before = _handed_over(svc)
+    index.hold()
+    callers = [_Caller(svc, hits[0])]
+    _wait_for(index.entered.is_set, "a pass in flight")
+    del index.order[:]
+    callers += [_Caller(svc, r) for r in hits[1:]]
+    callers += [_Caller(svc, _rows(1600 + i)) for i in range(20)]
+    _wait_for(lambda: _handed_over(svc) - before == 20
+              and _to_embed(svc) - 20 == 20, "everything in a queue")
+    index.release()
+    for c in callers:
+        assert c.done().error is None, c.error
+    # 20 rows to embed: three flushes; 19 + 20 blocks to rank: five passes
+    assert index.order == [
+        ("flush", 8), ("pass", 8), ("flush", 8), ("pass", 8),
+        ("flush", 4), ("pass", 8), ("pass", 8), ("pass", 7)]
+
+
+def test_64_callers_of_misses_settle_into_two_cohorts():
+    """(d): a flush that lasts until every other caller has sent its next
+    query (a LONG flush, told by counts: the stub waits for them) leaves
+    two cohorts that take the tower in turn — every query is embedded by
+    the first or the second flush that starts after it was sent, and a
+    flush carries half the callers — where a pass that waits out the
+    NEXT cohort's flush makes three."""
+    ladder, n_callers, n_calls = (16, 32, 64), 64, 6
+    index = _Index(ladder)
+    carried = {}                      # a query's token -> its flush
+
+    class LongFlush(_Engine):
+        def embed_text(self, rows):
+            out = super().embed_text(rows)
+            flush_no = sum(kind == "flush" for kind, _ in self.order)
+            # what the flushes before this one embedded has been ranked
+            # and answered: each of those callers sends its next query,
+            # if it has one left
+            answered = np.bincount(
+                [(t - 1) // n_calls for t in carried], minlength=n_callers)
+            for r in rows[rows.any(axis=1)]:
+                carried[int(r[0])] = flush_no
+            expected = int(np.minimum(answered + 1, n_calls).sum())
+            deadline = time.monotonic() + 10
+            while _to_embed(svc) < expected:
+                assert time.monotonic() < deadline, "callers never came"
+                time.sleep(0.0005)
+            return out
+
+    engine = LongFlush(index.order, ladder)
+    svc = RetrievalService(engine, index, cache=EmbeddingLRUCache(0),
+                           registry=obs_metrics.MetricsRegistry(),
+                           recorder=obs_spans.SpanRecorder(ring=8192))
+    sent_at, errors = {}, []
+
+    def loop(c):
+        try:
+            for i in range(n_calls):
+                token = 1 + c * n_calls + i                 # unique, not 0
+                sent_at[token] = sum(k == "flush" for k, _ in index.order)
+                svc.query_ids(np.full((1, _WORDS), token, np.int32))
+        except Exception as exc:                            # noqa: BLE001
+            errors.append(exc)
+
+    threads = [threading.Thread(target=loop, args=(c,), daemon=True)
+               for c in range(n_callers)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not [t for t in threads if t.is_alive()]
+        assert not errors, errors[:3]
+    finally:
+        svc.close()
+    assert len(carried) == n_callers * n_calls
+    late = {t: carried[t] - sent_at[t] for t in carried
+            if carried[t] - sent_at[t] > 2}
+    assert not late, late
+    flushes = [n for kind, n in index.order if kind == "flush"]
+    # the first flush carries whoever came first; from then on two
+    # cohorts alternate, until callers begin to leave
+    settled = flushes[1:2 * (n_calls - 1)]
+    assert sum(settled) / len(settled) >= 28, flushes
+    assert all(a + b == n_callers for a, b in zip(settled, settled[1:])), \
+        flushes
+    # and every flush's rows were ranked by the pass right after it
+    kinds = [kind for kind, _ in index.order]
+    assert "flush,flush" not in ",".join(kinds), index.order
+
+
+def test_a_call_of_hits_and_misses_rides_one_pass_one_generation(made):
+    """(e): the call's misses are embedded by two different flushes; its
+    block enters the scan queue when the LAST of them exists, whole."""
+    svc, index, ring = made(index=_LiveIndex())
+    engine = svc.engine
+    mixed = _rows(1700, n=5)
+    svc.query_ids(mixed[:2])                            # two of five cached
+    del index.order[:]
+    engine.hold()
+    head = _Caller(svc, _rows(1710))
+    _wait_for(engine.entered.is_set, "a flush in flight")
+    singles = [_Caller(svc, _rows(1711 + i)) for i in range(6)]
+    _wait_for(lambda: _to_embed(svc) == 2 + 7, "six rows behind it")
+    call = _Caller(svc, mixed)
+    _wait_for(lambda: _to_embed(svc) == 2 + 10, "the call's three misses")
+    engine.step()             # the 8-row flush: six singles + two of three
+    _wait_for(engine.entered.is_set, "the call's last miss in a flush")
+    index.generation = 2      # a swap lands before the call is ranked
+    engine.release()
+    for c in [head, call] + singles:
+        assert c.done().error is None, c.error
+    assert index.order == [("flush", 1), ("pass", 1), ("flush", 8),
+                           ("pass", 6), ("flush", 1), ("pass", 5)]
+    scores, idx, gen = call.answer
+    assert gen == 2 and idx.shape == (5, _K)
+    np.testing.assert_array_equal(idx, _alone(svc, index, mixed)[1])
+    # of the last pass's five rows the flush before it embedded one
+    assert [r["chained_rows"] for r in _passes(ring)][-3:] == [1, 6, 1]
+    query = [r for r in ring.tail() if r["name"] == "query"
+             and r["rows"] == 5][-1]
+    assert query["cache_hits"] == 2 and query["embed_wait_ms"] > 0.0
+    assert 0.0 <= query["topk_ms"] <= query["embed_wait_ms"] + query["dur_ms"]
+
+
+def test_a_lone_callers_miss_is_flushed_with_no_timer(made):
+    """(f): the text window is a minute long and governs nothing here."""
+    svc, index, ring = made(max_delay_ms=60_000.0, continuous=False)
+    c = _Caller(svc, _rows(1800))
+    assert c.done(10.0).error is None
+    assert index.order == [("flush", 1), ("pass", 1)]
+    flush = [r for r in ring.tail() if r["name"] == "batcher.flush"][-1]
+    assert flush["queue_wait_ms"] < 5_000.0
+
+
+def test_a_row_whose_deadline_passes_is_never_embedded(made):
+    """(g): ``timeout_ms`` bounds the wait in the text queue too; the
+    worker finds out when it comes back from the device."""
+    svc, index, _ = made()
+    svc.engine.hold()
+    a = _Caller(svc, _rows(1900))
+    _wait_for(svc.engine.entered.is_set, "a flush in flight")
+    b = _Caller(svc, _rows(1901), timeout_ms=30.0)
+    _wait_for(lambda: _to_embed(svc) == 2, "the late row in the queue")
+    time.sleep(0.06)          # its deadline passes behind the flush
+    svc.engine.release()
+    assert a.done().error is None
+    assert isinstance(b.done().error, DeadlineExpired), b.error
+    assert index.order == [("flush", 1), ("pass", 1)]
+    health = svc.health()
+    assert health["batcher"]["deadline_expired"] == 1
+    assert health["admission"]["inflight"] == 0
+
+
+def test_a_failing_flush_fails_its_rows_and_the_worker_lives(made,
+                                                             monkeypatch):
+    svc, index, _ = made()
+    engine = svc.engine
+    real, calls = engine.embed_text, []
+
+    def failing_once(rows):
+        calls.append(int(rows.any(axis=1).sum()))
+        if len(calls) == 2:
+            raise RuntimeError("flush failed")
+        return real(rows)
+
+    monkeypatch.setattr(engine, "embed_text", failing_once)
+    svc._batcher._run_batch = failing_once      # bound at construction
+    engine.hold()
+    head = _Caller(svc, _rows(2000))
+    _wait_for(engine.entered.is_set, "the first flush in flight")
+    riders = [_Caller(svc, _rows(2001 + i, n=2)) for i in range(2)]
+    _wait_for(lambda: _to_embed(svc) == 5, "four rows behind it")
+    engine.release()
+    assert head.done().error is None
+    for c in riders:
+        assert isinstance(c.done().error, RuntimeError)
+        assert "flush failed" in str(c.error)
+    assert calls == [1, 4] and index.scans == [1]   # no pass for them
+    scores, idx = svc.query_ids(_rows(2001, n=2))   # embedded again
+    np.testing.assert_array_equal(
+        idx, _alone(svc, index, _rows(2001, n=2))[1])
+    health = svc.health()
+    assert health["batcher"]["batch_errors"] == 1
+    assert health["query_errors"] == 4
+    assert health["admission"]["inflight"] == 0
 
 # ---- stress ------------------------------------------------------------------
 

@@ -514,3 +514,109 @@ def test_close_fails_the_block_that_was_held_over():
     for f in (first, held):
         with pytest.raises(RuntimeError, match="closed"):
             f.result(timeout=5)
+
+
+# ---- driven: the owner's thread takes and flushes (ISSUE 29) -----------------
+
+def _driven(eng, **kw):
+    woken = []
+    b = _mk(eng, wake=lambda: woken.append(1), max_delay_ms=60_000.0, **kw)
+    return b, woken
+
+
+def test_driven_starts_no_worker_and_wakes_its_owner_at_every_submit():
+    eng = _FakeEngine()
+    b, woken = _driven(eng)
+    futs = [b.submit(r) for r in _rows(3)]
+    time.sleep(0.05)
+    assert len(woken) == 3 and not eng.batches     # nobody flushes for it
+    assert not any(t.name == "batcher-worker" for t in threading.enumerate())
+    assert b.depth() == 3
+    batch = b.take()                    # every row that waits now
+    assert len(batch) == 3 and b.take() == [] and b.depth() == 0
+    b.flush(batch, chained_rows=2)
+    for i, f in enumerate(futs):
+        assert np.array_equal(f.result(timeout=0), np.full((3,), 2.0 * i))
+    assert eng.batches[0].shape == (4, 3)           # padded to its bucket
+    assert b.stats()["flushes"] == 1
+    b.close()
+
+
+def test_driven_take_hands_out_blocks_whole_up_to_the_top_bucket():
+    b, _ = _driven(_FakeEngine())
+    first = b.submit_block(_block(0, 5))
+    held = b.submit_block(_block(5, 5))             # 5 + 5 > 8: held over
+    tail = b.submit(np.ones((3,), np.float32))
+    one = b.take()
+    assert [r.rows for r in one] == [5] and b.depth() == 6
+    two = b.take()                                  # it leads the next
+    assert [r.rows for r in two] == [5, 1] and b.depth() == 0
+    b.flush(one), b.flush(two)
+    assert np.array_equal(held.result(timeout=0), _block(5, 5) * 2.0)
+    first.result(timeout=0), tail.result(timeout=0)
+    b.close()
+
+
+def test_driven_take_fails_what_expired_while_the_owner_was_away():
+    b, _ = _driven(_FakeEngine())
+    late = b.submit(np.ones((3,), np.float32), timeout_ms=10.0)
+    live = b.submit(np.ones((3,), np.float32))
+    time.sleep(0.03)                    # the owner is on the device
+    batch = b.take()
+    assert len(batch) == 1
+    with pytest.raises(DeadlineExpired):
+        late.result(timeout=0)
+    b.flush(batch)
+    assert live.result(timeout=0) is not None
+    assert b.stats()["deadline_expired"] == 1
+    b.close()
+
+
+def test_driven_flush_record_carries_the_owners_attributes():
+    from milnce_tpu.obs import spans as obs_spans
+
+    rec = obs_spans.SpanRecorder(ring=64)
+    b, _ = _driven(_FakeEngine(), recorder=rec, span_name="topk.flush",
+                   name="topk")
+    b.submit(np.ones((3,), np.float32))
+    b.flush(b.take(), chained_rows=1)
+    b.submit(np.ones((3,), np.float32))
+    b.flush(b.take())
+    first, second = [r for r in rec.tail() if r["name"] == "topk.flush"]
+    assert first["chained_rows"] == 1 and "chained_rows" not in second
+    b.close()
+
+
+def test_a_callers_own_future_is_resolved_on_the_flushing_thread():
+    """``future=``: callbacks added before the submit run where the row
+    is flushed — never on the submitting thread, however early the flush."""
+    from concurrent.futures import Future
+
+    ran_on = []
+    b = _mk(_FakeEngine(), continuous=True)
+    mine: Future = Future()
+    mine.add_done_callback(
+        lambda f: ran_on.append(threading.current_thread().name))
+    assert b.submit(np.ones((3,), np.float32), future=mine) is mine
+    assert np.array_equal(mine.result(timeout=5), np.full((3,), 2.0))
+    b.close()
+    assert ran_on == ["batcher-worker"]
+
+
+def test_driven_close_fails_the_queue_and_the_next_take_the_held_block():
+    b, woken = _driven(_FakeEngine())
+    b.submit_block(_block(0, 5))
+    held = b.submit_block(_block(5, 5))
+    b.take()                            # the first leaves; the second is held
+    queued = b.submit(np.ones((3,), np.float32))
+    n = len(woken)
+    b.close()
+    assert len(woken) == n + 1          # the owner is told
+    with pytest.raises(RuntimeError, match="closed"):
+        queued.result(timeout=0)
+    assert not held.done()              # the owner's to fail ...
+    assert b.take() == []               # ... when it comes round
+    with pytest.raises(RuntimeError, match="closed"):
+        held.result(timeout=0)
+    with pytest.raises(RuntimeError, match="closed"):
+        b.submit(np.ones((3,), np.float32))

@@ -119,6 +119,65 @@ def _expected(rows, dim=8):
     return np.tile(np.asarray(rows)[:, :1].astype(np.float32), (1, dim))
 
 
+class _TinyIndex:
+    """Brute-force top-2 over 16 rows (numpy; counts its passes)."""
+
+    k = 2
+    buckets = query_buckets = FakeEngine.buckets
+    bucket_for = FakeEngine.bucket_for
+
+    def __init__(self):
+        self.corpus = np.random.default_rng(7).normal(
+            size=(16, 8)).astype(np.float32)
+        self.passes = []
+
+    def topk(self, q):
+        self.passes.append(q.shape[0])
+        scores = q @ self.corpus.T
+        idx = np.argsort(-scores, axis=1)[:, :2].astype(np.int32)
+        return np.take_along_axis(scores, idx, axis=1), idx
+
+    def stats(self):
+        return {"size": 16}
+
+
+def test_a_pooled_service_hands_a_flushs_rows_over_from_the_pools_worker():
+    """Over a pool the text batcher and the scan coalescer keep a worker
+    each (no device worker: there are several devices); what ISSUE 29
+    took out of the caller's hands falls out of the same callback — the
+    pool worker that resolves a flush puts the call in the scan queue."""
+    from milnce_tpu.obs import spans as obs_spans
+
+    engines, pool = _fake_pool(2)
+    ring = obs_spans.SpanRecorder(ring=256)
+    service = RetrievalService(pool, _TinyIndex(), max_delay_ms=1.0,
+                               recorder=ring,
+                               registry=obs_metrics.MetricsRegistry())
+    handed_over_on = []
+    real = service._scans.submit_block
+
+    def spying(rows, timeout_ms=None, future=None):
+        handed_over_on.append(threading.current_thread().name)
+        return real(rows, timeout_ms, future=future)
+
+    service._scans.submit_block = spying
+    try:
+        assert service._device_worker is None
+        names = {t.name for t in threading.enumerate()}
+        assert {"text-worker", "topk-worker"} <= names
+        scores, idx = service.query_ids(_rows(3, fill=5))
+        assert idx.shape == (3, 2) and service.index.passes == [3]
+        assert handed_over_on and all(
+            name.startswith("pool-replica") for name in handed_over_on)
+        (flush,) = [r for r in ring.tail() if r["name"] == "topk.flush"]
+        assert "chained_rows" not in flush      # the device worker's count
+        query = [r for r in ring.tail() if r["name"] == "query"][-1]
+        assert query["embed_wait_ms"] > 0.0 and query["topk_ms"] >= 0.0
+    finally:
+        service.close()
+        pool.close()
+
+
 # ---------------------------------------------------------------------------
 # unit chaos: routing, requeue, quarantine/recovery, hedge, saturation
 # ---------------------------------------------------------------------------
@@ -511,12 +570,23 @@ class TestHTTPErrorContract:
             service.close()
 
     def test_deadline_expiry_is_504_with_retry_hint(self):
-        service = RetrievalService(FakeEngine(), None, max_delay_ms=40.0,
+        # a single engine flushes a lone row at once (no window to age
+        # in): the deadline passes behind a flush in flight
+        slow = FakeEngine(delay_s=0.3)
+        service = RetrievalService(slow, None,
                                    registry=obs_metrics.MetricsRegistry())
         server = serve_http(service, port=0)
         threading.Thread(target=server.serve_forever, daemon=True).start()
         base = f"http://127.0.0.1:{server.server_address[1]}"
         try:
+            threading.Thread(
+                target=lambda: _post(base, "/v1/embed_text",
+                                     {"token_ids": [[2, 2, 2, 2]]}).close(),
+                daemon=True).start()
+            deadline = time.monotonic() + 10
+            while slow.calls < 1:
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
             with pytest.raises(urllib.error.HTTPError) as exc_info:
                 _post(base, "/v1/embed_text",
                       {"token_ids": [[3, 3, 3, 3]], "timeout_ms": 1})
