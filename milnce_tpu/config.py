@@ -457,16 +457,13 @@ class TrainConfig:
 class ServeConfig:
     """Online-serving knobs (milnce_tpu/serving/, SERVING.md).
 
-    The three SLO levers: ``max_batch`` trades per-request latency for
+    The two SLO levers: ``max_batch`` trades per-request latency for
     device efficiency (taller ladder = fuller MXU at high load),
-    ``max_delay_ms`` bounds how long a lone request waits for batch
-    company, ``default_timeout_ms`` bounds total queue wait before a
-    request errors (DeadlineExpired) instead of silently aging.
-    ``max_delay_ms`` and ``continuous_batching`` govern the text batcher
-    of a POOLED service only (``replicas`` > 1 or an edge tier): a single
-    engine's one device worker takes the text flush and the pass over the
-    index in turn and closes each batch at the instant it turns to it —
-    every row that waits, no window, no lane (serving/service.py
+    ``default_timeout_ms`` bounds total queue wait before a request
+    errors (DeadlineExpired) instead of silently aging.  There is no
+    batching window: the service's one device worker takes the text
+    flush and the pass over the index in turn and closes each batch at
+    the instant it turns to it — every row that waits (serving/service.py
     ``RetrievalService._drive``).
 
     Resilience tier (serving/pool.py, ROBUSTNESS.md "Serving request
@@ -478,9 +475,6 @@ class ServeConfig:
 
     max_batch: int = 64                 # top of the bucket ladder
     min_bucket: int = 0                 # smallest bucket (0 = mesh size)
-    max_delay_ms: float = 5.0           # flush-on-delay bound of a POOLED
-                                        # service's text batcher (a single
-                                        # engine has no window)
     default_timeout_ms: float = 0.0     # per-request queue deadline (0 = none)
     cache_capacity: int = 4096          # LRU text-embedding cache entries
                                         # (<= 0 disables)
@@ -542,16 +536,6 @@ class ServeConfig:
                                         # requests shed with HTTP 429 +
                                         # Retry-After (0 = unbounded).
                                         # /healthz and /metrics never shed.
-    continuous_batching: bool = False   # POOLED service's text batcher
-                                        # only (a single engine's device
-                                        # worker always flushes what waits):
-                                        # admit requests into partially-
-                                        # filled bucket slots: flush the
-                                        # instant a dispatch lane is free,
-                                        # accumulate while lanes are busy
-                                        # (vLLM-style slot reuse on the
-                                        # fixed ladder; max_delay_ms is
-                                        # then ignored — serving/batcher.py)
     tiers: str = ""                     # per-tenant SLO classes on the
                                         # admission controller: priority-
                                         # ordered 'name:share[,...]' (e.g.

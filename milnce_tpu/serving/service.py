@@ -4,10 +4,12 @@ Request flow for a text query (the full tentpole path)::
 
     sentence --tokenizer--> token row --cache?--> hit: cached embedding
                                       \\--miss--> text queue
-    device worker (single engine): pass, flush, pass, flush ...
+    device worker: pass, flush, pass, flush ...
       flush: every row of the text queue, padded to its bucket -->
-             InferenceEngine.embed_text --> cache; a call whose last
-             embedding this was joins the scan queue, there and then
+             InferenceEngine.embed_text (over a pool: submitted to a
+             replica, at most one batch a replica in flight) --> cache;
+             a call whose last embedding this was joins the scan queue,
+             there and then
       pass:  every block of the scan queue (hits straight from the
              caller, and what the last flush embedded), up to the
              index's top bucket, ONE DeviceRetrievalIndex.topk -->
@@ -365,7 +367,8 @@ class _Call(Future):
     """One call's rows from the cache to its answer: the caller waits on
     this and on nothing else.  Rows the cache lacked go to the text
     batcher one by one; the thread that embeds the LAST of them (the
-    device worker, inside its flush) stacks the call's embeddings and,
+    device worker, inside its flush; over a pool, the replica's worker
+    that completes the flush) stacks the call's embeddings and,
     for a query, puts them in the scan queue as one block that resolves
     this Future — the pass after that flush finds them there, and no
     caller thread stands between the tower and the scan.  An embed-only
@@ -401,8 +404,8 @@ class _Call(Future):
             self._svc._batcher.submit(rows[i], self._timeout_ms, future=row)
 
     def _row_done(self, i: int, row: Future) -> None:
-        """On the thread that resolved ``row``: the device worker (a pool
-        worker's completion callback on a pooled service)."""
+        """On the thread that resolved ``row``: the one that scatters the
+        text flush."""
         exc = row.exception()
         if exc is not None:
             self.release()
@@ -456,34 +459,33 @@ class _Call(Future):
 class RetrievalService:
     """Programmatic API over engine + batcher + cache + index.
 
-    On a single engine ONE thread (``device-worker``) owns the device for
-    the query path and takes the two programs in turn — pass, flush,
-    pass, flush — closing each batch at the instant it turns to it
-    (:meth:`_drive`): ``max_delay_ms`` and ``continuous`` then govern
-    nothing.  Over a :class:`~milnce_tpu.serving.pool.ReplicaPool` the
-    text batcher keeps its own worker (those two arguments are its), its
-    flushes resolve on the pool's workers, and the scan coalescer is a
-    continuous one-lane batcher with a worker of its own; which of the
-    two a service is follows from what it was built over, nothing else."""
+    ONE thread (``device-worker``) drives the query path and takes the
+    two programs in turn — pass, flush, pass, flush — closing each batch
+    at the instant it turns to it (:meth:`_drive`).  Over a
+    :class:`~milnce_tpu.serving.pool.ReplicaPool` the text flush is a
+    submit to a replica and resolves on the pool's worker, at most one
+    batch a replica in flight; the pass stays on the device worker."""
 
     def __init__(self, engine, index=None, *, tokenizer=None,
                  cache: Optional[EmbeddingLRUCache] = None,
-                 max_delay_ms: float = 5.0, default_timeout_ms: float = 0.0,
+                 default_timeout_ms: float = 0.0,
                  registry: Optional[obs_metrics.MetricsRegistry] = None,
                  recorder: Optional[obs_spans.SpanRecorder] = None,
                  capture=None, anomaly_ratio: float = 3.0,
-                 max_inflight: int = 0, tiers="", continuous: bool = False):
+                 max_inflight: int = 0, tiers=""):
         self.engine = engine
         self.index = index
         self.tokenizer = tokenizer
         self.cache = cache if cache is not None else EmbeddingLRUCache(0)
         # engine may be a single InferenceEngine or a ReplicaPool —
-        # the pool adds the Future-returning submit surface (pipelined
-        # batcher flushes) and per-replica health (serving/pool.py)
+        # the pool adds the Future-returning submit surface (text flushes
+        # in flight on several replicas) and per-replica health
+        # (serving/pool.py)
         self._pool = engine if hasattr(engine, "pool_stats") else None
+        lanes = len(self._pool.replicas) if self._pool is not None else 1
         # Anomaly-triggered profiler capture (obs/anomaly.py + obs/
         # capture.py): an EWMA detector watches per-flush latency (fed
-        # by the batcher worker) and — when a ProfilerCapture is
+        # by the batchers' flushes) and — when a ProfilerCapture is
         # injected — arms ONE bounded capture on a spike; POST
         # /obs/capture arms it manually.  None = events only / 404.
         self.capture = capture
@@ -492,8 +494,8 @@ class RetrievalService:
             on_anomaly=((lambda v, e: capture.arm(reason="flush_spike"))
                         if capture is not None else None))
         # Every counter on the request path lives on ONE obs registry
-        # (the old per-component dicts raced request threads against the
-        # batcher worker; registry metrics are lock-guarded).  None = a
+        # (registry metrics are lock-guarded: request threads and the
+        # flushing thread both write them).  None = a
         # private registry, so multiple services in one process stay
         # isolated; the milnce-serve CLI passes the process-wide
         # ``obs.metrics.registry()``.
@@ -512,25 +514,29 @@ class RetrievalService:
         # shed): max_inflight=0 keeps the overload bound off but the
         # controller still meters in-flight rows for /healthz
         self._admission = AdmissionController(
-            max_inflight, max_batch=engine.max_batch,
-            lanes=(len(self._pool.replicas) if self._pool is not None else 1),
+            max_inflight, max_batch=engine.max_batch, lanes=lanes,
             depth_fn=lambda: self._batcher.depth(),
             registry=self.registry, tiers=tiers)
 
         def _on_flush(dur_ms: float, rows: int) -> None:
-            # one hook, two consumers: the EWMA spike detector (anomaly
-            # -> profiler capture) and — single-engine mode only — the
-            # admission feasibility floor (a sync flush IS the dispatch;
-            # a pooled async flush spans replica queue wait too, so the
-            # pooled floor feeds from the pool's dispatch latencies
-            # below instead)
             self._flush_detector.observe(dur_ms, rows=rows)
-            if self._pool is None:
-                self._admission.observe_flush(dur_ms, rows)
 
-        # a single engine's two batchers are DRIVEN by the device worker
-        # (started last, below): a submit wakes it, it takes what waits
-        driven = self._pool is None
+        def _on_dispatch(dur_ms: float, rows: int) -> None:
+            # two consumers: the EWMA spike detector (anomaly -> profiler
+            # capture) and the admission feasibility floor, which wants
+            # PURE execution time — the honest "fastest the service has
+            # ever dispatched".  On a single engine the text flush IS the
+            # dispatch; a pooled flush spans replica queue wait too, so
+            # there the pool's per-dispatch latencies feed both and the
+            # flush feeds the detector alone (it sees the queueing that
+            # the replicas' own latencies hide)
+            _on_flush(dur_ms, rows)
+            self._admission.observe_flush(dur_ms, rows)
+
+        if self._pool is not None:
+            self._pool.set_on_latency(_on_dispatch)
+        # the device worker (started last, below) takes and flushes the
+        # two batchers: a submit wakes it, it takes what waits
         self._wake = threading.Event()
         self._stop = threading.Event()
         # counts the device worker's turns: +1 before a flush and after
@@ -538,49 +544,32 @@ class RetrievalService:
         # the flush immediately before the pass that reads it.  The
         # device worker's alone to write
         self._epoch = 0
-        wake = self._wake.set if driven else None
         self._batcher = DynamicBatcher(
             engine.embed_text, engine.bucket_for, max_batch=engine.max_batch,
-            max_delay_ms=max_delay_ms, default_timeout_ms=default_timeout_ms,
-            name="text", registry=self.registry, buckets=engine.buckets,
-            recorder=recorder, on_flush=_on_flush,
+            wake=self._wake.set, default_timeout_ms=default_timeout_ms,
+            name="text", registry=self.registry, recorder=recorder,
+            on_flush=_on_flush if self._pool is not None else _on_dispatch,
             # pooled: submit-and-move-on so batches pipeline across
-            # replicas and one wedged replica never blocks the flush loop
+            # replicas, one a replica, and one wedged replica never
+            # blocks the device worker
             run_batch_async=(self._pool.submit_text
                              if self._pool is not None else None),
-            # continuous batching (SERVING.md): one dispatch lane per
-            # pool replica (a driven batcher has neither window nor lane)
-            continuous=continuous,
-            lanes=(len(self._pool.replicas)
-                   if self._pool is not None else 1), wake=wake)
-        if self._pool is not None:
-            # the pool's per-dispatch latencies feed the same spike
-            # detector (the anomaly->capture path sees replica-level
-            # slowness even when batcher queueing hides it) AND the
-            # admission feasibility floor (pure execution time — the
-            # honest "fastest the service has ever dispatched")
-            def _on_dispatch(dur_ms: float, rows: int) -> None:
-                self._flush_detector.observe(dur_ms, rows=rows)
-                self._admission.observe_flush(dur_ms, rows)
-
-            self._pool.set_on_latency(_on_dispatch)
-        # The scan coalescer: ONE thread owns this service's calls of
-        # ``index.topk`` and scans with whatever blocks are waiting (hits
-        # and misses alike, a call's rows together); rows that arrive
-        # meanwhile ride the next pass.  On a single engine that thread
-        # is the device worker, between two text flushes; over a pool,
-        # the batcher's own (continuous, one lane).  A lone caller pays a
-        # thread hand-off and no window either way.
+            lanes=lanes)
+        # The scan coalescer: the device worker owns this service's calls
+        # of ``index.topk`` and scans, between two text flushes, with
+        # whatever blocks are waiting (hits and misses alike, a call's
+        # rows together); rows that arrive meanwhile ride the next pass.
+        # A lone caller pays a thread hand-off and no window.
         self._scans = None
         if index is not None:
             ladder = getattr(index, "query_buckets", None) or engine.buckets
             self._scans = DynamicBatcher(
                 self._scan, getattr(index, "bucket_for", engine.bucket_for),
-                max_batch=ladder[-1], default_timeout_ms=default_timeout_ms,
-                name="topk", registry=self.registry, buckets=engine.buckets,
-                recorder=recorder, continuous=True, lanes=1, pad=False,
+                max_batch=ladder[-1], wake=self._wake.set,
+                default_timeout_ms=default_timeout_ms, name="topk",
+                registry=self.registry, recorder=recorder, pad=False,
                 take=lambda out, at: (out[0][at], out[1][at], out[2]),
-                span_name="topk.flush", wake=wake)
+                span_name="topk.flush")
         self._default_timeout_ms = float(default_timeout_ms)
         self._m_degraded = self.registry.counter(
             "milnce_serve_degraded_total",
@@ -615,13 +604,11 @@ class RetrievalService:
             self._bound.append(
                 reg.gauge("milnce_serve_index_size", "corpus rows indexed",
                           fn=lambda: self.index.stats()["size"]))
-        self._device_worker = None
-        if driven:
-            self._device_worker = threading.Thread(
-                target=self._drive, daemon=True, name="device-worker")
-            self._device_worker.start()
+        self._device_worker = threading.Thread(
+            target=self._drive, daemon=True, name="device-worker")
+        self._device_worker.start()
 
-    # ---- the device worker (single engine) ---------------------------------
+    # ---- the device worker -------------------------------------------------
 
     def _drive(self) -> None:
         """Pass, flush, pass, flush: while blocks wait to be ranked, ALL
@@ -633,7 +620,10 @@ class RetrievalService:
         (neither starves), and with both empty the worker sleeps until a
         submit wakes it: a lone caller pays a thread hand-off, no timer.
         ``chained_rows`` on the ``topk.flush`` record: the rows of this
-        pass that the flush immediately before it embedded."""
+        pass that the flush immediately before it embedded.  Over a pool
+        a flush returns when its batch is submitted and this loop goes
+        on, so the same counter gives the rows embedded since the
+        worker's latest turn (a flush sent or a pass run)."""
         text, scans = self._batcher, self._scans
         while True:
             self._wake.clear()
@@ -926,10 +916,9 @@ class RetrievalService:
         self._batcher.close()
         if self._scans is not None:
             self._scans.close()
-        if self._device_worker is not None:
-            self._stop.set()            # after the batchers are closed:
-            self._wake.set()            # the worker's last takes fail
-            self._device_worker.join(5.0)   # whatever it had held over
+        self._stop.set()                # after the batchers are closed:
+        self._wake.set()                # the worker's last takes fail
+        self._device_worker.join(5.0)   # whatever it had held over
         self._admission.close()
         for gauge in self._bound:
             gauge.unbind()
@@ -1235,13 +1224,12 @@ def build_server(cfg):
     service = RetrievalService(
         engine, index, tokenizer=tokenizer,
         cache=EmbeddingLRUCache(s.cache_capacity),
-        max_delay_ms=s.max_delay_ms, default_timeout_ms=s.default_timeout_ms,
+        default_timeout_ms=s.default_timeout_ms,
         # the live process has ONE registry: /metrics on this server
         # also exposes anything other subsystems record process-wide
         registry=obs_metrics.registry(),
         capture=capture, anomaly_ratio=s.anomaly_ratio,
-        max_inflight=s.max_inflight, tiers=s.tiers,
-        continuous=s.continuous_batching)
+        max_inflight=s.max_inflight, tiers=s.tiers)
     service.gc_pauses = obs_spans.GcPauseEvents().install()
     return serve_http(service, s.host, s.port), service, index, engine
 

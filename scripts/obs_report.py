@@ -11,7 +11,7 @@ One tool reads everything the obs subsystem emits (OBSERVABILITY.md):
 Usage::
 
     python scripts/obs_report.py RUN_EVENTS.jsonl            # summarize
-    python scripts/obs_report.py SERVE_BENCH_tiny_closed.json
+    python scripts/obs_report.py SERVE_BENCH_tiny_tiers.json
     python scripts/obs_report.py --check CURRENT --baseline BASELINE \
         [--tolerance 0.10]                                   # CI gate
     python scripts/obs_report.py --check CURRENT --baseline latest

@@ -41,7 +41,6 @@ generation-swapped ``LiveRetrievalIndex`` and ``--ingest_rows N
 --ingest_interval_s S`` runs a background ingest job (N random rows
 every S seconds through ``service.index_add``), so a chaos spec like
 ``--faults 'index.swap_raise@%3'`` exercises swap failures UNDER load.
-``--continuous`` turns on continuous batching (SERVING.md).
 
 Queries are drawn from a ``--distinct``-sized pool with a Zipf-ish
 (1/rank) distribution, so the text-embedding cache sees a realistic
@@ -179,10 +178,8 @@ def build_service(args, tier_class=""):
                                      query_buckets=engine.buckets)
     service = RetrievalService(
         engine, index, cache=EmbeddingLRUCache(args.cache_capacity),
-        max_delay_ms=args.max_delay_ms,
         default_timeout_ms=args.timeout_ms, registry=registry,
-        max_inflight=args.max_inflight, tiers=args.tier_shares,
-        continuous=args.continuous)
+        max_inflight=args.max_inflight, tiers=args.tier_shares)
     return cfg, service
 
 
@@ -603,7 +600,6 @@ def main(argv=None) -> int:
                          "raise it to shrink the ladder's compile bill — "
                          "single-device pool replicas otherwise start "
                          "their ladder at 1)")
-    ap.add_argument("--max_delay_ms", type=float, default=3.0)
     ap.add_argument("--timeout_ms", type=float, default=0.0)
     ap.add_argument("--cache_capacity", type=int, default=4096)
     ap.add_argument("--export_dir", default="",
@@ -625,10 +621,6 @@ def main(argv=None) -> int:
     ap.add_argument("--max_inflight", type=int, default=0,
                     help="admission bound: rows in flight before requests "
                          "shed with 429 (0 = unbounded)")
-    ap.add_argument("--continuous", action="store_true",
-                    help="continuous batching: flush the instant a "
-                         "dispatch lane is free, accumulate while lanes "
-                         "are busy (SERVING.md; default = flush-and-wait)")
     ap.add_argument("--live_index", action="store_true",
                     help="serve through the generation-swapped "
                          "LiveRetrievalIndex (ingest-capable)")

@@ -94,7 +94,6 @@ def main() -> int:
                                  query_buckets=pool.buckets)
     service = RetrievalService(pool, index,
                                cache=EmbeddingLRUCache(128),
-                               max_delay_ms=2.0,
                                registry=obs_metrics.registry())
     server = serve_http(service, port=0)
     threading.Thread(target=server.serve_forever, daemon=True).start()

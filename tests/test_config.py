@@ -138,3 +138,16 @@ class TestConvImplMap:
         model = build_model(cfg)
         assert model.conv_impl_map == (("conv1", "im2col"),
                                        ("mixed_5c", "fold2d"))
+
+
+@pytest.mark.parametrize("flag", [["--serve.max_delay_ms", "5"],
+                                  ["--serve.continuous_batching", "true"]])
+def test_the_batching_window_options_are_gone(flag, capsys):
+    """ISSUE 30: one batching policy (take what waits when the device is
+    free), so the two options that chose another are refused like any
+    unknown field and a stale launch script fails loudly."""
+    with pytest.raises(SystemExit) as exc:
+        parse_cli(flag)
+    assert exc.value.code == 2
+    assert f"unrecognized arguments: {' '.join(flag)}" in capsys.readouterr().err
+    assert parse_cli(["--serve.max_batch", "8"]).serve.max_batch == 8

@@ -59,8 +59,7 @@ def stack():
     # cache off: ingest changes the right answer, a stale hit would
     # hide exactly the freshness this module pins
     service = RetrievalService(engine, index,
-                               cache=EmbeddingLRUCache(0),
-                               max_delay_ms=2.0)
+                               cache=EmbeddingLRUCache(0))
     yield dict(model=model, variables=variables, mesh=mesh, engine=engine,
                clips=clips, index=index, service=service)
     service.close()

@@ -214,7 +214,7 @@ class TestMakeLock:
 class _FakeEngine:
     """Engine-shaped stand-in: bucket ladder semantics without jax.
     embed_text acquires the dispatch-named sanitized lock so the order
-    graph sees the same batcher-worker -> dispatch shape as production."""
+    graph sees the same device-worker -> dispatch shape as production."""
 
     buckets = (4, 8)
     max_batch = 8
@@ -256,7 +256,7 @@ def test_in_process_service_hammer_under_sanitizer(monkeypatch):
 
         service = RetrievalService(
             _FakeEngine(), None, cache=EmbeddingLRUCache(256),
-            max_delay_ms=1.0, registry=obs_metrics.MetricsRegistry())
+            registry=obs_metrics.MetricsRegistry())
         assert isinstance(service.cache._lock, SanitizedLock)
         assert isinstance(service._batcher._children_lock, SanitizedLock)
         errors = []

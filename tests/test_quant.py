@@ -321,7 +321,7 @@ def mixed_stack(f32_dir, quant_dir):
     corpus_emb = pool.embed_video(clips)
     index = DeviceRetrievalIndex(_mesh(), corpus_emb, k=5,
                                  query_buckets=pool.buckets)
-    service = RetrievalService(pool, index, max_delay_ms=2.0)
+    service = RetrievalService(pool, index)
     yield dict(pool=pool, service=service)
     service.close()
     pool.close()

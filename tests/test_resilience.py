@@ -89,7 +89,8 @@ class TestFaultRegistry:
 class _HangingSource:
     """Synthetic-shaped source whose chosen draws sleep: a stand-in for a
     wedged decode pipe, below the fault-site layer so the watchdog can be
-    unit-tested without a manifest."""
+    unit-tested without a manifest.  The first ``hang_first_n`` draws
+    hang, and EVERY draw of ``hang_idx``."""
 
     def __init__(self, cfg, hang_first_n=0, hang_idx=None, sleep=2.0):
         from milnce_tpu.data.synthetic import SyntheticVideoTextSource
@@ -100,6 +101,8 @@ class _HangingSource:
         self.sleep = sleep
         self._lock = threading.Lock()
         self._calls = 0
+        self._active = 0
+        self._released = threading.Event()
 
     def __len__(self):
         return len(self.inner)
@@ -111,10 +114,30 @@ class _HangingSource:
         with self._lock:
             self._calls += 1
             n = self._calls
-        if n <= self.hang_first_n or (self.hang_idx is not None
-                                      and idx == self.hang_idx):
-            time.sleep(self.sleep)
-        return self.inner.sample(idx, rng)
+            self._active += 1
+        try:
+            if n <= self.hang_first_n or idx == self.hang_idx:
+                self._released.wait(self.sleep)
+            return self.inner.sample(idx, rng)
+        finally:
+            with self._lock:
+                self._active -= 1
+
+    def release(self):
+        """Wake the sleepers and wait them out.  The watchdog abandons a
+        hung reader thread, and one that outlives its test goes on to
+        ``inner.sample``, whose ``decode.raise`` site then counts against
+        whatever registry a LATER test has armed (the lost occurrence of
+        ``test_chaos_host_sites_combined_run_survives`` under six
+        workers: ROADMAP Queue 3 item 11)."""
+        self._released.set()
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline:
+            with self._lock:
+                if not self._active:
+                    return
+            time.sleep(0.005)
+        raise AssertionError("a hung reader thread outlived its test")
 
 
 def test_watchdog_retry_recovers_from_one_hang():
@@ -125,7 +148,12 @@ def test_watchdog_retry_recovers_from_one_hang():
     loader = ShardedLoader(src, 4, seed=0, num_threads=2, process_index=0,
                            process_count=1, sample_timeout=0.2,
                            timeout_retries=2)
-    batch = next(iter(loader.epoch(0)))
+    gen = loader.epoch(0)
+    try:
+        batch = next(gen)
+    finally:
+        gen.close()
+        src.release()
     assert batch["video"].shape[0] == 4
     assert loader.decode_timeouts >= 1
     # the retried decode succeeded: no black-frame fallback needed
@@ -138,22 +166,19 @@ def test_watchdog_escalates_to_black_frame_fallback():
     fallback and the batch still comes out full."""
     from milnce_tpu.data.pipeline import ShardedLoader
 
-    class AlwaysHangOnOne(_HangingSource):
-        def sample(self, idx, rng):
-            if idx == self.hang_idx:
-                time.sleep(self.sleep)
-            return self.inner.sample(idx, rng)
-
     cfg = tiny_preset()
     order = np.arange(32)
     np.random.RandomState(0 + 0).shuffle(order)      # seed + epoch
-    src = AlwaysHangOnOne(cfg.data, hang_idx=int(order[1]), sleep=4.0)
+    src = _HangingSource(cfg.data, hang_idx=int(order[1]), sleep=4.0)
     loader = ShardedLoader(src, 4, seed=0, num_threads=2, process_index=0,
                            process_count=1, sample_timeout=0.1,
                            timeout_retries=1)
     gen = loader.epoch(0)
-    batch = next(gen)
-    gen.close()
+    try:
+        batch = next(gen)
+    finally:
+        gen.close()
+        src.release()
     assert batch["video"].shape[0] == 4
     assert loader.decode_timeouts >= 2  # initial + retry both timed out
     # exactly the wedged row fell back to black frames

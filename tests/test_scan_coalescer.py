@@ -145,7 +145,6 @@ def made():
     def make(index=None, **kw):
         index = _Index() if index is None else index
         ring = obs_spans.SpanRecorder(ring=4096)
-        kw.setdefault("max_delay_ms", 1.0)
         kw.setdefault("cache", EmbeddingLRUCache(64))
         svc = RetrievalService(
             _Engine(index.order, index.query_buckets), index, recorder=ring,
@@ -251,11 +250,9 @@ def test_a_pass_never_carries_more_than_the_top_bucket(made):
 # ---- (b) a lone caller waits for nobody ------------------------------------
 
 def test_a_lone_call_is_scanned_at_once(made):
-    # a text window of a second: a hit never meets it, and the scan has
-    # no window of its own
-    svc, index, ring = made(max_delay_ms=1000.0, continuous=False)
+    svc, index, ring = made()
     rows = _rows(300)
-    svc.query_ids(rows)                       # a miss: pays the window
+    svc.query_ids(rows)                       # a miss: flush, then pass
     t0 = time.monotonic()
     svc.query_ids(rows)                       # a hit: the scan alone
     took_ms = (time.monotonic() - t0) * 1e3
@@ -626,8 +623,8 @@ def test_a_call_of_hits_and_misses_rides_one_pass_one_generation(made):
 
 
 def test_a_lone_callers_miss_is_flushed_with_no_timer(made):
-    """(f): the text window is a minute long and governs nothing here."""
-    svc, index, ring = made(max_delay_ms=60_000.0, continuous=False)
+    """(f): a flush and a pass, in that order, as soon as it arrives."""
+    svc, index, ring = made()
     c = _Caller(svc, _rows(1800))
     assert c.done(10.0).error is None
     assert index.order == [("flush", 1), ("pass", 1)]

@@ -1,19 +1,26 @@
-"""Dynamic micro-batcher edge semantics (ISSUE 4 satellite): flush on
-size and on delay, pad/unpad identity, bucket selection at boundaries,
-deadline-expired -> error (never a silent drop), batch-failure
-propagation.  jax-free by construction — the batcher is numpy-only and
-these tests pin that boundary too (a fake run_batch stands in for the
-engine)."""
+"""Dynamic micro-batcher edge semantics (ISSUE 4 satellite): pad/unpad
+identity, bucket selection at boundaries, deadline-expired -> error
+(never a silent drop), batch-failure propagation, blocks, lanes.  The
+batcher owns no thread (ISSUE 30): the tests are its owner — ``submit``,
+then ``take()`` and ``flush()`` on the test's own thread — and the two
+that need a second thread start :class:`_Owner`.  jax-free by
+construction — the batcher is numpy-only and these tests pin that
+boundary too (a fake run_batch stands in for the engine)."""
 
 import threading
 import time
+from concurrent.futures import Future
 
 import numpy as np
 import pytest
 
+from milnce_tpu.obs import metrics as obs_metrics
+from milnce_tpu.obs import spans as obs_spans
 from milnce_tpu.serving.batcher import DeadlineExpired, DynamicBatcher
 
 _BUCKETS = (4, 8)
+# a deadline that has passed by the time the owner next looks (1 ns)
+_AGED_MS = 1e-6
 
 
 def _bucket_for(n: int) -> int:
@@ -45,45 +52,68 @@ class _FakeEngine:
 
 def _mk(engine, **kw):
     kw.setdefault("max_batch", _BUCKETS[-1])
+    kw.setdefault("wake", lambda: None)
     return DynamicBatcher(engine, _bucket_for, **kw)
+
+
+def _woken(engine, **kw):
+    """A batcher and the list its ``wake`` appends to."""
+    woken = []
+    return _mk(engine, wake=lambda: woken.append(1), **kw), woken
+
+
+def _turn(b) -> list:
+    """One turn of the owner: what waits now, flushed."""
+    batch = b.take()
+    b.flush(batch)
+    return batch
+
+
+class _Owner(threading.Thread):
+    """The owner as service.py has it, for the tests that need the flush
+    on another thread than the submits: sleeps until woken, then takes
+    and flushes until nothing waits."""
+
+    def __init__(self, engine, **kw):
+        super().__init__(daemon=True, name="owner")
+        self._wake, self._done = threading.Event(), False
+        self.b = _mk(engine, wake=self._wake.set, **kw)
+        self.start()
+
+    def run(self):
+        while not self._done:
+            self._wake.wait()
+            self._wake.clear()
+            while _turn(self.b):
+                pass
+
+    def close(self):
+        self._done = True
+        self.b.close()                  # wakes the loop: its take fails
+        self.join(5.0)                  # what was held over, then it ends
+
+
+def _held_async():
+    """``run_batch_async`` whose futures the test resolves by hand."""
+    sent: list[tuple] = []
+
+    def run_async(rows):
+        sent.append((Future(), np.array(rows, copy=True)))
+        return sent[-1][0]
+
+    return run_async, sent
 
 
 def _rows(n, w=3):
     return [np.full((w,), float(i), np.float32) for i in range(n)]
 
 
-def test_flush_on_max_batch_does_not_wait_for_delay():
-    eng = _FakeEngine()
-    b = _mk(eng, max_batch=4, max_delay_ms=10_000)   # delay flush never fires
-    t0 = time.monotonic()
-    futs = [b.submit(r) for r in _rows(4)]
-    out = [f.result(timeout=5) for f in futs]
-    assert time.monotonic() - t0 < 5.0               # well under the 10s delay
-    assert len(eng.batches) == 1 and eng.batches[0].shape == (4, 3)
-    for i, row in enumerate(out):
-        assert np.array_equal(row, np.full((3,), 2.0 * i))
-    occ = b.stats()["occupancy"]["4"]
-    assert occ == {"flushes": 1, "rows": 4, "mean_fill": 1.0}
-    b.close()
-
-
-def test_flush_on_delay_serves_a_lone_request():
-    eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=40)
-    t0 = time.monotonic()
-    row = b.submit(np.ones((3,), np.float32)).result(timeout=5)
-    waited = time.monotonic() - t0
-    assert np.array_equal(row, np.full((3,), 2.0))
-    assert waited >= 0.03                 # did wait for company...
-    assert eng.batches[0].shape == (4, 3)  # ...then padded to the floor bucket
-    b.close()
-
-
 def test_pad_unpad_identity_matches_per_sample_results():
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=30)
+    b = _mk(eng)
     futs = [b.submit(r) for r in _rows(3)]
-    batched = np.stack([f.result(timeout=5) for f in futs])
+    _turn(b)
+    batched = np.stack([f.result(timeout=0) for f in futs])
     assert np.array_equal(batched, np.stack(_rows(3)) * 2.0)
     # the engine really saw ONE padded bucket, zeros in the pad slots
     (batch,) = eng.batches
@@ -95,10 +125,11 @@ def test_pad_unpad_identity_matches_per_sample_results():
 @pytest.mark.parametrize("n,bucket", [(1, 4), (4, 4), (5, 8), (8, 8)])
 def test_bucket_selection_at_boundaries(n, bucket):
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=150)        # plenty to collect all n submits
+    b = _mk(eng)
     futs = [b.submit(r) for r in _rows(n)]
+    _turn(b)
     for f in futs:
-        f.result(timeout=5)
+        f.result(timeout=0)
     assert len(eng.batches) == 1, "expected one flush for the burst"
     assert eng.batches[0].shape == (bucket, 3)
     b.close()
@@ -106,10 +137,11 @@ def test_bucket_selection_at_boundaries(n, bucket):
 
 def test_expired_deadline_is_an_error_not_a_silent_drop():
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=10_000)     # only the deadline can end the wait
-    fut = b.submit(np.ones((3,), np.float32), timeout_ms=40)
+    b = _mk(eng)
+    fut = b.submit(np.ones((3,), np.float32), timeout_ms=_AGED_MS)
+    assert _turn(b) == []
     with pytest.raises(DeadlineExpired):
-        fut.result(timeout=5)             # resolves promptly, NOT after 10s
+        fut.result(timeout=0)
     assert b.stats()["deadline_expired"] == 1
     assert eng.batches == []              # never reached the engine
     b.close()
@@ -117,56 +149,76 @@ def test_expired_deadline_is_an_error_not_a_silent_drop():
 
 def test_live_requests_survive_a_neighbors_expiry():
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=10_000)
-    doomed = b.submit(np.zeros((3,), np.float32), timeout_ms=40)
+    b = _mk(eng)
+    doomed = b.submit(np.zeros((3,), np.float32), timeout_ms=_AGED_MS)
     alive = b.submit(np.ones((3,), np.float32))     # no deadline
+    _turn(b)
     with pytest.raises(DeadlineExpired):
-        doomed.result(timeout=5)
-    assert np.array_equal(alive.result(timeout=5), np.full((3,), 2.0))
+        doomed.result(timeout=0)
+    assert np.array_equal(alive.result(timeout=0), np.full((3,), 2.0))
     b.close()
 
 
-def test_mixed_shape_batch_fails_the_batch_not_the_worker():
-    """A malformed payload mix (np.stack of unequal row shapes raises
-    BEFORE run_batch) must fail that batch's futures and leave the
-    worker alive — a dead worker would strand every later request."""
+def test_a_request_that_ages_between_take_and_flush_is_failed_by_the_flush():
+    b = _mk(_FakeEngine())
+    fut = b.submit(np.ones((3,), np.float32), timeout_ms=30.0)
+    batch = b.take()
+    assert len(batch) == 1
+    batch[0].deadline = time.monotonic() - 1.0      # it aged on the way
+    b.flush(batch)
+    with pytest.raises(DeadlineExpired):
+        fut.result(timeout=0)
+    b.close()
+
+
+def test_mixed_shape_batch_fails_the_batch_not_the_owner():
+    """A malformed payload mix (np.concatenate of unequal row shapes
+    raises BEFORE run_batch) must fail that batch's futures and return
+    to the owner — an exception out of ``flush`` would kill the thread
+    that every later request waits for."""
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=60)
+    b = _mk(eng)
     f1 = b.submit(np.ones((3,), np.float32))
     f2 = b.submit(np.ones((4,), np.float32))      # width mismatch
+    _turn(b)
     for f in (f1, f2):
         with pytest.raises(ValueError):
-            f.result(timeout=5)
+            f.result(timeout=0)
     assert b.stats()["batch_errors"] == 1
-    # the worker survived: a well-formed request still gets served
-    ok = b.submit(np.ones((3,), np.float32)).result(timeout=5)
-    assert np.array_equal(ok, np.full((3,), 2.0))
+    # a well-formed request still gets served
+    ok = b.submit(np.ones((3,), np.float32))
+    _turn(b)
+    assert np.array_equal(ok.result(timeout=0), np.full((3,), 2.0))
     b.close()
 
 
 def test_batch_failure_propagates_to_every_caller():
-    b = _mk(_FakeEngine(fail=True), max_delay_ms=20)
+    b = _mk(_FakeEngine(fail=True))
     futs = [b.submit(r) for r in _rows(2)]
+    _turn(b)
     for f in futs:
         with pytest.raises(ValueError, match="injected batch failure"):
-            f.result(timeout=5)
+            f.result(timeout=0)
     assert b.stats()["batch_errors"] == 1
     b.close()
 
 
 def test_default_timeout_applies_when_submit_passes_none():
-    b = _mk(_FakeEngine(), max_delay_ms=10_000, default_timeout_ms=40)
+    b = _mk(_FakeEngine(), default_timeout_ms=_AGED_MS)
+    fut = b.submit(np.ones((3,), np.float32))
+    _turn(b)
     with pytest.raises(DeadlineExpired):
-        b.submit(np.ones((3,), np.float32)).result(timeout=5)
+        fut.result(timeout=0)
     b.close()
 
 
 def test_explicit_zero_timeout_disables_the_default_deadline():
-    # default deadline (20ms) < delay flush (60ms): a request that kept
-    # the default would expire; timeout_ms=0 opts out and gets served
-    b = _mk(_FakeEngine(), max_delay_ms=60, default_timeout_ms=20)
+    # a request that kept the default would have expired by the owner's
+    # turn; timeout_ms=0 opts out and gets served
+    b = _mk(_FakeEngine(), default_timeout_ms=_AGED_MS)
     fut = b.submit(np.ones((3,), np.float32), timeout_ms=0)
-    assert np.array_equal(fut.result(timeout=5), np.full((3,), 2.0))
+    _turn(b)
+    assert np.array_equal(fut.result(timeout=0), np.full((3,), 2.0))
     b.close()
 
 
@@ -179,21 +231,23 @@ def test_submit_after_close_raises():
 
 def test_stats_shape():
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=20)
-    b.submit(np.ones((3,), np.float32)).result(timeout=5)
+    b = _mk(eng)
+    b.submit(np.ones((3,), np.float32))
+    _turn(b)
     s = b.stats()
     assert s["requests"] == 1 and s["flushes"] == 1
     assert s["deadline_expired"] == 0 and s["batch_errors"] == 0
     assert s["occupancy"]["4"]["mean_fill"] == pytest.approx(0.25)
     b.close()
 
+
 def test_stats_readers_race_flushes_with_exact_final_occupancy():
-    """ISSUE 7 regression: the worker's per-bucket children lookup ran
-    OUTSIDE the children lock while stats() iterated under it
+    """ISSUE 7 regression: the flushing thread's per-bucket children
+    lookup ran OUTSIDE the children lock while stats() iterated under it
     (graftlint GL010) — hammer stats() from readers during a stream of
     flushes; final occupancy totals must be exact."""
-    eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=1)
+    owner = _Owner(_FakeEngine())
+    b = owner.b
     stop = threading.Event()
     errors = []
 
@@ -221,7 +275,7 @@ def test_stats_readers_race_flushes_with_exact_final_occupancy():
     stop.set()
     for t in readers:
         t.join(timeout=10)
-    b.close()
+    owner.close()
     assert not errors, errors
     s = b.stats()
     assert s["requests"] == n
@@ -230,132 +284,104 @@ def test_stats_readers_race_flushes_with_exact_final_occupancy():
                for occ in s["occupancy"].values()) == s["flushes"]
 
 
-def test_continuous_lone_request_skips_the_delay_wait():
-    """Continuous batching (ISSUE 14): a lone request flushes the
-    moment the lane is free — it never pays max_delay_ms waiting for
-    company that isn't coming (the flush-and-wait path's cost)."""
+def test_arrivals_gather_into_bucket_slots_while_the_owner_is_away():
+    """While the owner runs one batch, arrivals wait: its next take hands
+    all of them out as ONE batch — occupancy rises exactly when the
+    device is the bottleneck."""
     eng = _FakeEngine()
-    b = _mk(eng, max_delay_ms=10_000, continuous=True)
-    t0 = time.monotonic()
-    row = b.submit(np.ones((3,), np.float32)).result(timeout=5)
-    waited = time.monotonic() - t0
-    assert np.array_equal(row, np.full((3,), 2.0))
-    assert waited < 2.0, f"continuous mode waited {waited:.3f}s"
-    b.close()
-
-
-def test_continuous_accumulates_into_bucket_slots_while_lane_busy():
-    """While the single lane executes, arrivals accumulate into the
-    forming batch — occupancy rises exactly when the device is the
-    bottleneck (the slot-reuse win over flush-and-wait)."""
-    eng = _FakeEngine(delay_s=0.15)
-    b = _mk(eng, continuous=True)                    # max_batch 8
+    b = _mk(eng)                                     # max_batch 8
     futs = [b.submit(np.full((3,), 0.0, np.float32))]
-    time.sleep(0.03)                 # first flush (1 row) is in flight
+    first = b.take()                 # the owner turns to its 1-row batch
     futs += [b.submit(np.full((3,), float(i), np.float32))
-             for i in range(1, 7)]
+             for i in range(1, 7)]   # ... and is away on the device
+    b.flush(first)
+    _turn(b)
     for f in futs:
-        f.result(timeout=5)
+        f.result(timeout=0)
     b.close()
     sizes = [batch.shape[0] for batch in eng.batches]
     assert sizes == [4, 8], (
-        f"expected the 6 lane-busy arrivals to coalesce: {sizes}")
+        f"expected the 6 arrivals to coalesce: {sizes}")
 
 
-def test_continuous_deadline_expires_promptly_while_lane_busy():
-    """Pipelined continuous mode: a request aging out while the worker
-    is PARKED on a busy lane fails with DeadlineExpired at the
-    lane-wait tick — it never waits for the lane to free first."""
-    from concurrent.futures import Future
+# ---- lanes: asynchronous flushes in flight (ISSUE 30) -----------------------
 
-    slow: list[Future] = []
-
-    def run_async(rows):
-        fut: Future = Future()
-        slow.append(fut)
-        return fut                        # resolved manually, late
-
-    b = _mk(_FakeEngine(), continuous=True, lanes=1,
-            run_batch_async=run_async)
-    blocker = b.submit(np.ones((3,), np.float32))     # occupies the lane
-    deadline = time.monotonic() + 5.0
-    while not slow and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert slow, "the blocker batch never dispatched"
-    doomed = b.submit(np.zeros((3,), np.float32), timeout_ms=60)
-    t0 = time.monotonic()
+def test_a_lane_bound_take_hands_out_nothing_and_a_completion_wakes_the_owner():
+    """One lane, its batch in flight: what arrives stays queued (and
+    counts in ``depth()``), a request that ages meanwhile is failed at
+    the take after the completion — which wakes the owner."""
+    run_async, sent = _held_async()
+    b, woken = _woken(_FakeEngine(), lanes=1, run_batch_async=run_async)
+    blocker = b.submit(np.ones((3,), np.float32))
+    _turn(b)                                        # occupies the lane
+    assert len(sent) == 1 and not blocker.done()
+    doomed = b.submit(np.zeros((3,), np.float32), timeout_ms=_AGED_MS)
+    live = b.submit(np.ones((3,), np.float32))
+    assert b.take() == [] and b.depth() == 2        # lane-bound
+    assert not doomed.done() and len(sent) == 1
+    n = len(woken)
+    sent[0][0].set_result(sent[0][1] * 2.0)         # the pool's worker
+    assert len(woken) == n + 1                      # a lane is free
+    assert np.array_equal(blocker.result(timeout=0), np.full((3,), 2.0))
+    batch = b.take()
+    assert [r.future for r in batch] == [live]
     with pytest.raises(DeadlineExpired):
-        doomed.result(timeout=5)
-    waited = time.monotonic() - t0
-    assert waited < 0.4, (f"expiry took {waited:.3f}s — waited for the "
-                          "lane instead of the deadline")
-    slow[0].set_result(np.ones((4, 3), np.float32) * 2.0)
-    assert np.array_equal(blocker.result(timeout=5), np.full((3,), 2.0))
+        doomed.result(timeout=0)
     assert b.stats()["deadline_expired"] == 1
+    b.flush(batch)
+    sent[1][0].set_result(sent[1][1] * 2.0)
+    assert np.array_equal(live.result(timeout=0), np.full((3,), 2.0))
     b.close()
 
 
-def test_continuous_async_lanes_bound_inflight_batches():
-    """Pipelined continuous mode: at most ``lanes`` batches are ever in
-    flight at once (the semaphore), and every batch still resolves."""
-    from concurrent.futures import Future
+def test_lanes_bound_the_asynchronous_batches_in_flight():
+    """Two lanes: the third batch goes out only when a completion has
+    freed one, and every batch still resolves."""
+    run_async, sent = _held_async()
+    b, woken = _woken(_FakeEngine(), max_batch=4, lanes=2,
+                      run_batch_async=run_async)
+    futs = [b.submit(r) for r in _rows(12)]         # three batches' worth
 
-    inflight = {"now": 0, "max": 0}
-    lock = threading.Lock()
-    pending: list[tuple] = []
+    def unresolved():
+        return sum(not f.done() for f, _ in sent)
 
-    def run_async(rows):
-        fut: Future = Future()
-        with lock:
-            inflight["now"] += 1
-            inflight["max"] = max(inflight["max"], inflight["now"])
-            pending.append((fut, np.array(rows, copy=True)))
-        return fut
+    assert len(_turn(b)) == 4 and len(_turn(b)) == 4
+    assert unresolved() == 2
+    assert b.take() == [] and b.depth() == 4        # both lanes busy
+    fut, rows = sent[0]
+    fut.set_result(rows * 2.0)
+    assert len(_turn(b)) == 4 and unresolved() == 2  # ... and busy again
+    assert b.take() == []
+    for fut, rows in sent[1:]:
+        fut.set_result(rows * 2.0)
+    for i, f in enumerate(futs):
+        assert np.array_equal(f.result(timeout=0), np.full((3,), 2.0 * i))
+    assert b.stats()["flushes"] == 3 and len(sent) == 3
+    b.close()
 
-    def resolver():
-        while not stop.is_set():
-            with lock:
-                item = pending.pop(0) if pending else None
-            if item is None:
-                time.sleep(0.005)
-                continue
-            time.sleep(0.05)                  # the "dispatch"
-            fut, rows = item
-            with lock:
-                inflight["now"] -= 1
-            fut.set_result(rows * 2.0)
 
-    stop = threading.Event()
-    t = threading.Thread(target=resolver, daemon=True)
-    t.start()
-    b = _mk(_FakeEngine(), continuous=True, lanes=2,
-            run_batch_async=run_async)
-    try:
-        futs = []
-        for burst in range(6):                # 6 bursts of 2 rows
-            futs += [b.submit(np.full((3,), float(burst), np.float32))
-                     for _ in range(2)]
-            time.sleep(0.02)
-        out = [f.result(timeout=10) for f in futs]
-        assert all(o.shape == (3,) for o in out)
-        assert inflight["max"] <= 2, (
-            f"{inflight['max']} batches in flight > 2 lanes")
-        assert inflight["max"] >= 2, "lanes never actually pipelined"
-    finally:
-        stop.set()
-        b.close()
-        t.join(timeout=5)
+def test_a_failed_submit_takes_no_lane():
+    def refuse(rows):
+        raise RuntimeError("every replica queue is full")
+
+    b = _mk(_FakeEngine(), lanes=1, run_batch_async=refuse)
+    first = b.submit(np.ones((3,), np.float32))
+    _turn(b)
+    with pytest.raises(RuntimeError, match="queue is full"):
+        first.result(timeout=0)
+    b.submit(np.ones((3,), np.float32))
+    assert len(b.take()) == 1           # the lane was never taken
+    b.close()
 
 
 def test_injected_recorder_receives_flush_spans():
     # an owner that isolates its span stream (recorder=...) must get the
     # flush spans there — not on the process-default recorder, which a
     # co-resident train run can swap out via spans.install()
-    from milnce_tpu.obs.spans import SpanRecorder
-
-    rec = SpanRecorder()
-    b = _mk(_FakeEngine(), max_delay_ms=20, recorder=rec)
-    b.submit(np.ones((3,), np.float32)).result(timeout=5)
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(), recorder=rec)
+    b.submit(np.ones((3,), np.float32))
+    _turn(b)
     b.close()
     spans = [r for r in rec.tail() if r.get("name") == "batcher.flush"]
     assert len(spans) == 1 and spans[0]["rows"] == 1
@@ -367,24 +393,67 @@ def test_on_flush_observer_sees_duration_and_rows():
     — and never for a failed batch."""
     seen = []
     eng = _FakeEngine(delay_s=0.02)
-    b = _mk(eng, max_delay_ms=10,
-            on_flush=lambda dur_ms, rows: seen.append((dur_ms, rows)))
-    futs = [b.submit(r) for r in _rows(3)]
-    for f in futs:
-        f.result(timeout=5)
+    b = _mk(eng, on_flush=lambda dur_ms, rows: seen.append((dur_ms, rows)))
+    for r in _rows(3):
+        b.submit(r)
+    _turn(b)
     b.close()
     assert len(seen) == 1
     dur_ms, rows = seen[0]
     assert rows == 3 and dur_ms >= 20.0 - 1.0   # the engine's delay
 
     seen.clear()
-    bad = _mk(_FakeEngine(fail=True), max_delay_ms=10,
+    bad = _mk(_FakeEngine(fail=True),
               on_flush=lambda dur_ms, rows: seen.append((dur_ms, rows)))
     fut = bad.submit(np.ones((3,), np.float32))
+    _turn(bad)
     with pytest.raises(ValueError, match="injected"):
-        fut.result(timeout=5)
+        fut.result(timeout=0)
     bad.close()
     assert seen == []
+
+
+def test_deadline_expired_hints_the_last_flushs_duration():
+    """``retry_after_ms``: how long the batcher's most recent flush took
+    (0 before the first) — not a window that does not exist."""
+    seen = []
+    b = _mk(_FakeEngine(delay_s=0.01),
+            on_flush=lambda dur_ms, rows: seen.append(dur_ms))
+    early = b.submit(np.ones((3,), np.float32), timeout_ms=_AGED_MS)
+    _turn(b)
+    assert early.exception(timeout=0).retry_after_ms == 0.0
+    b.submit(np.ones((3,), np.float32))
+    _turn(b)
+    late = b.submit(np.ones((3,), np.float32), timeout_ms=_AGED_MS)
+    _turn(b)
+    (dur_ms,) = seen
+    assert dur_ms >= 9.0 and late.exception(timeout=0).retry_after_ms == dur_ms
+    b.close()
+
+
+def test_two_ladders_share_one_registry():
+    """ISSUE 30: the occupancy histogram's edges came from the engine's
+    ladder, so a second service with another ladder could not be built
+    on the process-wide registry."""
+    reg = obs_metrics.MetricsRegistry()
+    small = DynamicBatcher(_FakeEngine(), lambda n: 8, max_batch=8,
+                           wake=lambda: None, name="small", registry=reg)
+    tall = DynamicBatcher(_FakeEngine(), lambda n: 16 if n > 8 else 8,
+                          max_batch=16, wake=lambda: None, name="tall",
+                          registry=reg)
+    for b, n in ((small, 3), (tall, 11)):
+        for r in _rows(n):
+            b.submit(r)
+        _turn(b)
+    assert small.stats()["occupancy"] == {
+        "8": {"flushes": 1, "rows": 3, "mean_fill": 3 / 8}}
+    assert tall.stats()["occupancy"] == {
+        "16": {"flushes": 1, "rows": 11, "mean_fill": 11 / 16}}
+    (fam,) = [f for f in reg.collect()
+              if f.name == "milnce_serve_batch_occupancy"]
+    counts = {labels[0]: child.count for labels, child in fam.items()}
+    assert counts == {"small": 1, "tall": 1}
+    small.close(), tall.close()
 
 
 # ---- blocks, and what a batch returns (ISSUE 27: the scan coalescer) --------
@@ -394,38 +463,39 @@ def _block(first, n, w=3):
                      for i in range(n)])
 
 
-@pytest.mark.parametrize("continuous", [False, True])
-def test_block_resolves_to_its_rows_in_order_beside_lone_rows(continuous):
-    eng = _FakeEngine(delay_s=0.05)
-    b = _mk(eng, max_delay_ms=30.0, continuous=continuous)
+def test_block_resolves_to_its_rows_in_order_beside_lone_rows():
+    eng = _FakeEngine()
+    b = _mk(eng)
     head = b.submit(np.full((3,), 50.0, np.float32))
-    time.sleep(0.02)                    # continuous: head is in flight
+    first = b.take()                    # head's batch is on its way
     blk = b.submit_block(_block(0, 3))
     lone = b.submit(np.full((3,), 7.0, np.float32))
-    assert np.array_equal(blk.result(timeout=5), _block(0, 3) * 2.0)
-    assert np.array_equal(lone.result(timeout=5), np.full((3,), 14.0))
-    assert np.array_equal(head.result(timeout=5), np.full((3,), 100.0))
+    b.flush(first)
+    _turn(b)
+    assert np.array_equal(blk.result(timeout=0), _block(0, 3) * 2.0)
+    assert np.array_equal(lone.result(timeout=0), np.full((3,), 14.0))
+    assert np.array_equal(head.result(timeout=0), np.full((3,), 100.0))
     assert b.stats()["requests"] == 5   # rows, not requests
     b.close()
 
 
-@pytest.mark.parametrize("continuous", [False, True])
-def test_a_block_is_never_split_over_two_batches(continuous):
-    """5 + 5 rows against a top bucket of 8: two batches of 5 — the
-    second block is held over whole and leads the next batch."""
-    eng = _FakeEngine(delay_s=0.1)
-    b = _mk(eng, max_delay_ms=30.0, continuous=continuous)
+def test_a_block_is_never_split_over_two_batches():
+    """1 + 5 + 5 + 1 rows against a top bucket of 8: the second block is
+    held over whole and leads the next batch."""
+    eng = _FakeEngine()
+    b = _mk(eng)
     head = b.submit(np.zeros((3,), np.float32))
-    time.sleep(0.04 if continuous else 0.0)
     blocks = [b.submit_block(_block(10 * (i + 1), 5)) for i in range(2)]
     tail = b.submit(np.ones((3,), np.float32))
+    while _turn(b):
+        pass
     for i, f in enumerate(blocks):
-        assert np.array_equal(f.result(timeout=5),
+        assert np.array_equal(f.result(timeout=0),
                               _block(10 * (i + 1), 5) * 2.0)
-    head.result(timeout=5), tail.result(timeout=5)
+    head.result(timeout=0), tail.result(timeout=0)
     b.close()
     live = [int((batch.sum(axis=1) != 0).sum()) for batch in eng.batches]
-    assert sum(live) == 11 and max(live) <= 8
+    assert live == [5, 6]               # head is a row of zeros
     # the row sums of every block sit in ONE batch
     for i in range(2):
         want = set((_block(10 * (i + 1), 5) * 1.0).sum(axis=1).tolist())
@@ -435,14 +505,16 @@ def test_a_block_is_never_split_over_two_batches(continuous):
 
 def test_a_block_past_the_top_bucket_fails_alone():
     eng = _FakeEngine()
-    b = _mk(eng, continuous=True)
+    b = _mk(eng)
     ok = b.submit(np.ones((3,), np.float32))
     too_big = b.submit_block(_block(0, 9))
     after = b.submit(np.ones((3,), np.float32))
+    while _turn(b):
+        pass
     with pytest.raises(ValueError):
-        too_big.result(timeout=5)
-    assert np.array_equal(ok.result(timeout=5), np.full((3,), 2.0))
-    assert np.array_equal(after.result(timeout=5), np.full((3,), 2.0))
+        too_big.result(timeout=0)
+    assert np.array_equal(ok.result(timeout=0), np.full((3,), 2.0))
+    assert np.array_equal(after.result(timeout=0), np.full((3,), 2.0))
     assert b.stats()["batch_errors"] == 1
     b.close()
 
@@ -456,14 +528,16 @@ def test_take_cuts_each_requests_share_out_of_what_the_batch_returned():
         seen.append(rows.shape[0])
         return rows * 2.0, rows.sum(axis=1).astype(np.int32), len(seen)
 
-    b = DynamicBatcher(run, _bucket_for, max_batch=8, continuous=True,
-                       pad=False, span_name="topk.flush",
-                       take=lambda out, at: (out[0][at], out[1][at], out[2]))
-    doubled, total, stamp = b.submit_block(_block(1, 3)).result(timeout=5)
+    b = _mk(run, pad=False, span_name="topk.flush",
+            take=lambda out, at: (out[0][at], out[1][at], out[2]))
+    blk = b.submit_block(_block(1, 3))
+    _turn(b)
+    doubled, total, stamp = blk.result(timeout=0)
     assert np.array_equal(doubled, _block(1, 3) * 2.0)
     assert total.tolist() == [3, 6, 9] and total.dtype == np.int32
-    doubled, total, stamp2 = b.submit(
-        np.full((3,), 4.0, np.float32)).result(timeout=5)
+    row = b.submit(np.full((3,), 4.0, np.float32))
+    _turn(b)
+    doubled, total, stamp2 = row.result(timeout=0)
     assert doubled.shape == (3,) and total == 12
     assert (stamp, stamp2) == (1, 2)
     assert seen == [3, 1]               # pad=False: the live rows alone
@@ -471,13 +545,12 @@ def test_take_cuts_each_requests_share_out_of_what_the_batch_returned():
 
 
 def test_span_name_and_unpadded_rows_on_the_flush_record():
-    from milnce_tpu.obs import spans as obs_spans
-
     rec = obs_spans.SpanRecorder(ring=64)
     eng = _FakeEngine()
-    b = _mk(eng, continuous=True, pad=False, span_name="topk.flush",
-            recorder=rec, name="topk")
-    b.submit_block(_block(0, 5)).result(timeout=5)
+    b = _mk(eng, pad=False, span_name="topk.flush", recorder=rec,
+            name="topk")
+    b.submit_block(_block(0, 5))
+    _turn(b)
     b.close()
     (flush,) = [r for r in rec.tail() if r["name"] == "topk.flush"]
     assert (flush["rows"], flush["bucket"], flush["batcher"]) == (5, 8,
@@ -487,50 +560,37 @@ def test_span_name_and_unpadded_rows_on_the_flush_record():
     assert eng.batches[0].shape[0] == 5
 
 
-def test_close_fails_the_block_that_was_held_over():
-    """Pipelined continuous mode, the one lane busy: the worker parks
-    with a forming batch and a block held over behind it; both count in
-    ``depth()`` and both are failed, never dropped, at close."""
-    from concurrent.futures import Future
-
-    inflight: list[Future] = []
-
-    def run_async(rows):
-        inflight.append(Future())
-        return inflight[-1]             # never resolved: the lane stays busy
-
-    b = _mk(_FakeEngine(), continuous=True, lanes=1,
-            run_batch_async=run_async)
-    b.submit(np.zeros((3,), np.float32))
-    deadline = time.monotonic() + 5.0
-    while not inflight and time.monotonic() < deadline:
-        time.sleep(0.005)
+def test_close_fails_the_block_that_was_held_over_behind_a_busy_lane():
+    """The one lane busy: a block held over and a block queued behind it
+    both count in ``depth()`` and both are failed, never dropped, at
+    close; the batch in flight still gets its result."""
+    run_async, sent = _held_async()
+    b = _mk(_FakeEngine(), lanes=1, run_batch_async=run_async)
     first = b.submit_block(_block(0, 5))
     held = b.submit_block(_block(5, 5))     # does not fit behind `first`
-    while b.depth() < 10 and time.monotonic() < deadline:
-        time.sleep(0.005)
-    assert b.depth() == 10              # forming rows + the held block
+    _turn(b)                                # `first` is in flight
+    queued = b.submit_block(_block(10, 5))
+    assert b.take() == []                   # lane-bound
+    assert b.depth() == 5 + 1               # the held block's rows + a request
     b.close()
-    for f in (first, held):
+    assert b.take() == []                   # the owner comes round
+    for f in (held, queued):
         with pytest.raises(RuntimeError, match="closed"):
-            f.result(timeout=5)
+            f.result(timeout=0)
+    fut, rows = sent[0]
+    fut.set_result(rows * 2.0)
+    assert np.array_equal(first.result(timeout=0), _block(0, 5) * 2.0)
 
 
-# ---- driven: the owner's thread takes and flushes (ISSUE 29) -----------------
+# ---- the owner's thread takes and flushes (ISSUE 29) -------------------------
 
-def _driven(eng, **kw):
-    woken = []
-    b = _mk(eng, wake=lambda: woken.append(1), max_delay_ms=60_000.0, **kw)
-    return b, woken
-
-
-def test_driven_starts_no_worker_and_wakes_its_owner_at_every_submit():
+def test_it_starts_no_thread_and_wakes_its_owner_at_every_submit():
     eng = _FakeEngine()
-    b, woken = _driven(eng)
+    before = set(threading.enumerate())
+    b, woken = _woken(eng)
     futs = [b.submit(r) for r in _rows(3)]
-    time.sleep(0.05)
     assert len(woken) == 3 and not eng.batches     # nobody flushes for it
-    assert not any(t.name == "batcher-worker" for t in threading.enumerate())
+    assert set(threading.enumerate()) == before
     assert b.depth() == 3
     batch = b.take()                    # every row that waits now
     assert len(batch) == 3 and b.take() == [] and b.depth() == 0
@@ -542,8 +602,8 @@ def test_driven_starts_no_worker_and_wakes_its_owner_at_every_submit():
     b.close()
 
 
-def test_driven_take_hands_out_blocks_whole_up_to_the_top_bucket():
-    b, _ = _driven(_FakeEngine())
+def test_take_hands_out_blocks_whole_up_to_the_top_bucket():
+    b = _mk(_FakeEngine())
     first = b.submit_block(_block(0, 5))
     held = b.submit_block(_block(5, 5))             # 5 + 5 > 8: held over
     tail = b.submit(np.ones((3,), np.float32))
@@ -557,12 +617,11 @@ def test_driven_take_hands_out_blocks_whole_up_to_the_top_bucket():
     b.close()
 
 
-def test_driven_take_fails_what_expired_while_the_owner_was_away():
-    b, _ = _driven(_FakeEngine())
-    late = b.submit(np.ones((3,), np.float32), timeout_ms=10.0)
+def test_take_fails_what_expired_while_the_owner_was_away():
+    b = _mk(_FakeEngine())
+    late = b.submit(np.ones((3,), np.float32), timeout_ms=_AGED_MS)
     live = b.submit(np.ones((3,), np.float32))
-    time.sleep(0.03)                    # the owner is on the device
-    batch = b.take()
+    batch = b.take()                    # the owner is back from the device
     assert len(batch) == 1
     with pytest.raises(DeadlineExpired):
         late.result(timeout=0)
@@ -572,12 +631,9 @@ def test_driven_take_fails_what_expired_while_the_owner_was_away():
     b.close()
 
 
-def test_driven_flush_record_carries_the_owners_attributes():
-    from milnce_tpu.obs import spans as obs_spans
-
+def test_flush_record_carries_the_owners_attributes():
     rec = obs_spans.SpanRecorder(ring=64)
-    b, _ = _driven(_FakeEngine(), recorder=rec, span_name="topk.flush",
-                   name="topk")
+    b = _mk(_FakeEngine(), recorder=rec, span_name="topk.flush", name="topk")
     b.submit(np.ones((3,), np.float32))
     b.flush(b.take(), chained_rows=1)
     b.submit(np.ones((3,), np.float32))
@@ -590,21 +646,19 @@ def test_driven_flush_record_carries_the_owners_attributes():
 def test_a_callers_own_future_is_resolved_on_the_flushing_thread():
     """``future=``: callbacks added before the submit run where the row
     is flushed — never on the submitting thread, however early the flush."""
-    from concurrent.futures import Future
-
     ran_on = []
-    b = _mk(_FakeEngine(), continuous=True)
+    owner = _Owner(_FakeEngine())
     mine: Future = Future()
     mine.add_done_callback(
         lambda f: ran_on.append(threading.current_thread().name))
-    assert b.submit(np.ones((3,), np.float32), future=mine) is mine
+    assert owner.b.submit(np.ones((3,), np.float32), future=mine) is mine
     assert np.array_equal(mine.result(timeout=5), np.full((3,), 2.0))
-    b.close()
-    assert ran_on == ["batcher-worker"]
+    owner.close()
+    assert ran_on == ["owner"]
 
 
-def test_driven_close_fails_the_queue_and_the_next_take_the_held_block():
-    b, woken = _driven(_FakeEngine())
+def test_close_fails_the_queue_and_the_next_take_the_held_block():
+    b, woken = _woken(_FakeEngine())
     b.submit_block(_block(0, 5))
     held = b.submit_block(_block(5, 5))
     b.take()                            # the first leaves; the second is held

@@ -142,40 +142,172 @@ class _TinyIndex:
 
 
 def test_a_pooled_service_hands_a_flushs_rows_over_from_the_pools_worker():
-    """Over a pool the text batcher and the scan coalescer keep a worker
-    each (no device worker: there are several devices); what ISSUE 29
-    took out of the caller's hands falls out of the same callback — the
-    pool worker that resolves a flush puts the call in the scan queue."""
+    """Over a pool the text flush resolves on a replica's worker, and
+    what ISSUE 29 took out of the caller's hands falls out of the same
+    callback — the pool worker that resolves a flush puts the call in
+    the scan queue; the pass runs on the device worker (ISSUE 30: the
+    one loop, no worker per queue)."""
     from milnce_tpu.obs import spans as obs_spans
 
     engines, pool = _fake_pool(2)
     ring = obs_spans.SpanRecorder(ring=256)
-    service = RetrievalService(pool, _TinyIndex(), max_delay_ms=1.0,
-                               recorder=ring,
+    index = _TinyIndex()
+    service = RetrievalService(pool, index, recorder=ring,
                                registry=obs_metrics.MetricsRegistry())
-    handed_over_on = []
-    real = service._scans.submit_block
+    handed_over_on, passes_on = [], []
+    real, real_topk = service._scans.submit_block, index.topk
 
     def spying(rows, timeout_ms=None, future=None):
         handed_over_on.append(threading.current_thread().name)
         return real(rows, timeout_ms, future=future)
 
-    service._scans.submit_block = spying
+    def spying_topk(q):
+        passes_on.append(threading.current_thread().name)
+        return real_topk(q)
+
+    service._scans.submit_block, index.topk = spying, spying_topk
     try:
-        assert service._device_worker is None
         names = {t.name for t in threading.enumerate()}
-        assert {"text-worker", "topk-worker"} <= names
+        assert not {"text-worker", "topk-worker"} & names
         scores, idx = service.query_ids(_rows(3, fill=5))
-        assert idx.shape == (3, 2) and service.index.passes == [3]
+        assert idx.shape == (3, 2) and index.passes == [3]
         assert handed_over_on and all(
             name.startswith("pool-replica") for name in handed_over_on)
+        assert passes_on == ["device-worker"]
         (flush,) = [r for r in ring.tail() if r["name"] == "topk.flush"]
-        assert "chained_rows" not in flush      # the device worker's count
+        assert flush["chained_rows"] == 3       # the same counter
         query = [r for r in ring.tail() if r["name"] == "query"][-1]
         assert query["embed_wait_ms"] > 0.0 and query["topk_ms"] >= 0.0
     finally:
         service.close()
         pool.close()
+
+
+def test_a_pooled_service_has_one_device_worker_and_no_other_thread():
+    engines, pool = _fake_pool(2)
+    before = set(threading.enumerate())
+    service = RetrievalService(pool, _TinyIndex(),
+                               registry=obs_metrics.MetricsRegistry())
+    try:
+        started = set(threading.enumerate()) - before
+        assert [t.name for t in started] == ["device-worker"]
+        assert service._device_worker in started
+    finally:
+        service.close()
+        pool.close()
+    assert not service._device_worker.is_alive()
+
+
+class _HeldPool(FakeEngine):
+    """Pool-shaped stub of two replicas: ``submit_text`` keeps each batch
+    in ``sent`` until the test completes it with ``finish(i)`` (on the
+    test's thread, where a replica's worker would)."""
+
+    replicas = (0, 1)
+
+    def __init__(self):
+        super().__init__()
+        self.sent: list[tuple] = []
+
+    def pool_stats(self):
+        return {}
+
+    def set_on_latency(self, cb):
+        pass
+
+    def submit_text(self, rows):
+        from concurrent.futures import Future
+
+        self.sent.append((Future(), np.array(rows, copy=True)))
+        return self.sent[-1][0]
+
+    def unresolved(self) -> int:
+        return sum(not fut.done() for fut, _ in self.sent)
+
+    def finish(self, i: int) -> None:
+        fut, rows = self.sent[i]
+        fut.set_result(self.embed_text(rows))
+
+
+def _until(cond, what, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+def _held_service_with_three_batches_of_misses():
+    """-> (pool, service, caller threads, their outcomes): three callers
+    of ``max_batch`` fresh rows each against two replicas that finish
+    nothing yet — two batches in flight, the rest waiting."""
+    pool = _HeldPool()
+    service = RetrievalService(pool, None,
+                               registry=obs_metrics.MetricsRegistry())
+    outcomes: list = []
+
+    def call(fill):
+        try:
+            outcomes.append(service.embed_text_ids(
+                np.arange(fill, fill + pool.max_batch * 4, dtype=np.int32)
+                .reshape(pool.max_batch, 4)))
+        except Exception as exc:  # noqa: BLE001 - the outcome IS the test
+            outcomes.append(exc)
+
+    threads = [threading.Thread(target=call, args=(100 * i,), daemon=True)
+               for i in range(3)]
+    for t in threads:
+        t.start()
+    rows = 3 * pool.max_batch
+    _until(lambda: service._batcher.stats()["requests"] == rows
+           and len(pool.sent) == 2, "two batches in flight")
+    return pool, service, threads, outcomes
+
+
+def test_a_pooled_service_keeps_one_batch_a_replica_in_flight():
+    """Three flushes' worth of misses over two replicas: never more than
+    two batches in flight, and the third goes out when a completion
+    wakes the device worker (no poll, no window)."""
+    pool, service, threads, outcomes = \
+        _held_service_with_three_batches_of_misses()
+    try:
+        time.sleep(0.05)                # the worker has nothing to send
+        assert len(pool.sent) == 2 and service._batcher.depth() > 0
+        pool.finish(0)
+        _until(lambda: len(pool.sent) == 3, "the third batch")
+        assert pool.unresolved() == 2
+        done = 1
+        while any(t.is_alive() for t in threads):
+            _until(lambda: len(pool.sent) > done
+                   or not any(t.is_alive() for t in threads),
+                   "the next batch or the last answer")
+            if len(pool.sent) > done:
+                assert pool.unresolved() <= 2
+                pool.finish(done)
+                done += 1
+        assert [getattr(o, "shape", o) for o in outcomes] == [(8, 8)] * 3
+        flushes = service.health()["batcher"]
+        assert flushes["flushes"] == done and flushes["requests"] == 24
+    finally:
+        service.close()
+
+
+def test_closing_a_pooled_service_with_a_flush_in_flight_resolves_every_caller():
+    """What waits behind the busy replicas is failed by ``close`` at
+    once; a batch in flight gets its result from the pool, and its
+    caller an answer — nobody hangs."""
+    pool, service, threads, outcomes = \
+        _held_service_with_three_batches_of_misses()
+    service.close()
+    assert not service._device_worker.is_alive()
+    _until(lambda: outcomes, "a caller whose rows were waiting")
+    assert pool.unresolved() == 2       # nothing has completed yet
+    assert all(isinstance(o, RuntimeError) and "closed" in str(o)
+               for o in outcomes)
+    for i in range(len(pool.sent)):     # as ReplicaPool.close would
+        pool.finish(i)
+    for t in threads:
+        t.join(10.0)
+    assert len(outcomes) == 3 and not any(t.is_alive() for t in threads)
 
 
 # ---------------------------------------------------------------------------
@@ -443,7 +575,7 @@ class TestAdmission:
         apply: a client omitting timeout_ms still gets the service's
         default_timeout_ms judged at admission (a raw None would
         silently disable the check for every default-deadline client)."""
-        service = RetrievalService(FakeEngine(), None, max_delay_ms=1.0,
+        service = RetrievalService(FakeEngine(), None,
                                    default_timeout_ms=123.0,
                                    registry=obs_metrics.MetricsRegistry())
         try:
@@ -481,7 +613,6 @@ class TestAdmission:
                 return {"size": 1}
 
         service = RetrievalService(_SaturatingEngine(), _FakeIndex(),
-                                   max_delay_ms=1.0,
                                    registry=obs_metrics.MetricsRegistry())
         try:
             with pytest.raises(PoolSaturated):
@@ -492,7 +623,7 @@ class TestAdmission:
 
     def test_shed_never_hangs_through_the_service(self):
         slow = FakeEngine(delay_s=1.0)
-        service = RetrievalService(slow, None, max_delay_ms=1.0,
+        service = RetrievalService(slow, None,
                                    registry=obs_metrics.MetricsRegistry(),
                                    max_inflight=1)
         try:
@@ -530,7 +661,7 @@ class TestHTTPErrorContract:
     def test_shed_is_429_with_structured_body_and_header_healthz_never_sheds(
             self):
         slow = FakeEngine(delay_s=1.0)
-        service = RetrievalService(slow, None, max_delay_ms=1.0,
+        service = RetrievalService(slow, None,
                                    registry=obs_metrics.MetricsRegistry(),
                                    max_inflight=1)
         server = serve_http(service, port=0)
@@ -608,7 +739,6 @@ class TestHTTPErrorContract:
 
         service = RetrievalService(pool, None,
                                    cache=EmbeddingLRUCache(64),
-                                   max_delay_ms=1.0,
                                    registry=obs_metrics.MetricsRegistry())
         server = serve_http(service, port=0)
         threading.Thread(target=server.serve_forever, daemon=True).start()

@@ -155,7 +155,7 @@ class TestTierService:
     def test_tier_threads_through_service_and_http_with_429_and_400(self):
         slow = FakeEngine(delay_s=0.6)
         service = RetrievalService(
-            slow, None, max_delay_ms=1.0,
+            slow, None,
             registry=obs_metrics.MetricsRegistry(),
             max_inflight=4, tiers="interactive:1.0,batch:0.25")
         server = serve_http(service, port=0)
@@ -248,16 +248,15 @@ class TestKneeFinder:
 
 # ---------------------------------------------------------------------------
 # ISSUE acceptance: the two-tier chaos bench — interactive + batch
-# backfill, live-index ingest under index.swap_raise@%3, continuous
-# batching on — gated against the committed baseline via obs_report
-# --check (fast-child exemption in test_suite_hygiene.py)
+# backfill, live-index ingest under index.swap_raise@%3 — gated against
+# the committed baseline via obs_report --check (fast-child exemption in test_suite_hygiene.py)
 # ---------------------------------------------------------------------------
 
 TIER_BENCH_ARGS = [
     "--backend", "cpu", "--preset", "tiny", "--duration", "2",
     "--corpus", "12", "--distinct", "0",
     "--max_batch", "8", "--min_bucket", "8", "--cache_capacity", "0",
-    "--timeout_ms", "250", "--continuous", "--live_index",
+    "--timeout_ms", "250", "--live_index",
     "--ingest_rows", "4", "--ingest_interval_s", "0.3",
     "--max_inflight", "8",
     "--tiers", "interactive:25,batch:120",

@@ -51,7 +51,7 @@ def stack():
                                  query_buckets=engine.buckets)
     service = RetrievalService(
         engine, index, tokenizer=Tokenizer(synthetic_vocab(63), _WORDS),
-        cache=EmbeddingLRUCache(128), max_delay_ms=3.0)
+        cache=EmbeddingLRUCache(128))
     yield dict(model=model, variables=variables, mesh=mesh, engine=engine,
                clips=clips, corpus_emb=corpus_emb, index=index,
                service=service)
@@ -310,8 +310,7 @@ class TestService:
             max_batch=8, min_bucket=4,
             registry=obs_metrics.MetricsRegistry())
         service = RetrievalService(pool, index,
-                                   cache=EmbeddingLRUCache(0),
-                                   max_delay_ms=3.0)
+                                   cache=EmbeddingLRUCache(0))
         try:
             results = [None] * _CORPUS
 
@@ -852,9 +851,10 @@ class TestScanCoalescer:
 class TestQueueWait:
     @pytest.mark.parametrize("mode", ["sync", "async"])
     def test_flush_record_carries_the_rows_queue_wait(self, mode):
-        """Two rows 40 ms apart into one flush: the oldest row's wait is
-        the whole delay bound, the mean about 20 ms less — on the span of
-        a synchronous flush and on the event of a pipelined one."""
+        """Two rows 40 ms apart into one flush, taken when the second has
+        arrived: the oldest row's wait is those 40 ms, the mean about 20
+        ms less — on the span of a synchronous flush and on the event of
+        a pipelined one."""
         import time
         from concurrent.futures import Future
 
@@ -868,25 +868,19 @@ class TestQueueWait:
 
         rec = SpanRecorder()
         b = DynamicBatcher(lambda rows: rows * 2.0, lambda n: 4,
-                           max_batch=4, max_delay_ms=120, recorder=rec,
+                           max_batch=4, wake=lambda: None, recorder=rec,
                            run_batch_async=(run_async if mode == "async"
                                             else None))
-        try:
-            first = b.submit(np.ones((3,), np.float32))
-            time.sleep(0.04)
-            second = b.submit(np.ones((3,), np.float32))
-            first.result(timeout=10)
-            second.result(timeout=10)
-            deadline = time.monotonic() + 5.0
-            while not _named(rec, "batcher.flush") \
-                    and time.monotonic() < deadline:
-                time.sleep(0.005)
-        finally:
-            b.close()
+        first = b.submit(np.ones((3,), np.float32))
+        time.sleep(0.04)                # the owner holds off its take
+        second = b.submit(np.ones((3,), np.float32))
+        b.flush(b.take())
+        first.result(timeout=0), second.result(timeout=0)
+        b.close()
         (flush,) = _named(rec, "batcher.flush")
         assert flush["kind"] == ("event" if mode == "async" else "span")
         assert flush["rows"] == 2
-        assert 110.0 <= flush["queue_wait_ms"] < 1000.0
+        assert 40.0 <= flush["queue_wait_ms"] < 1000.0
         assert flush["queue_wait_mean_ms"] <= flush["queue_wait_ms"] - 15.0
         assert flush["queue_wait_mean_ms"] >= flush["queue_wait_ms"] / 2
 

@@ -60,8 +60,8 @@ _FAST_CHILD_EXEMPT = {
     # gate pins it tier-1.
     "test_live_index.py::test_live_index_hammer_subprocess_under_sanitizer",
     # ISSUE 14 acceptance: the two-tier chaos bench (interactive +
-    # batch backfill with live-index ingest under index.swap_raise@%3,
-    # continuous batching on) gated via obs_report --check.  A
+    # batch backfill with live-index ingest under index.swap_raise@%3)
+    # gated via obs_report --check.  A
     # subprocess because the acceptance pin IS the real script + gate
     # end-to-end; tiny preset + the shared persistent compile cache
     # keep it seconds-scale, and the live-index gate pins it tier-1.

@@ -327,7 +327,7 @@ def served_lm(tmp_path_factory):
         "--model.text_tower", "lm", "--text_lm.experts_held", "8",
         "--data.max_words", str(WORDS), "--parallel.platform", "cpu",
         "--serve.max_batch", "16", "--serve.topk", "3",
-        "--serve.port", "0", "--serve.max_delay_ms", "20",
+        "--serve.port", "0",
         "--serve.export_dir", str(work / "export"),
         "--serve.corpus_npz", str(work / "corpus.npz")])
     model, variables = tower_and_params(cfg.text_lm)
