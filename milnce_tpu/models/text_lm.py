@@ -33,9 +33,11 @@ from flax import linen as nn
 from jax import lax
 
 from milnce_tpu.config import TextLMConfig
+from milnce_tpu.ops import grouped_matmul
 
 COUNTERS = "moe_counters"       # the collection the expert layers sow into
-COUNTER_NAMES = ("moe_pairs_held", "moe_expert_max", "moe_pairs_total")
+COUNTER_NAMES = ("moe_pairs_held", "moe_expert_max", "moe_pairs_total",
+                 "moe_tile_rows")
 ROUTING = "moe_routing"         # each token's chosen experts, (B, S, k) an
 #                                 expert layer: sown for whoever applies the
 #                                 tower with this collection mutable (a
@@ -236,43 +238,48 @@ def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
                     first_expert: int, dtype):
     """sum over the chosen experts held here of weight x SwiGLU_e(h), for
     every real token.  w_* are (held, ...) stacks.  -> (out (T, hidden)
-    float32, pairs held, most pairs of one expert).
+    float32, pairs held, most pairs of one expert, rows the grouped
+    products were asked to multiply).
 
     The (token, expert) pairs that meet a held expert are sorted by expert
-    and multiplied group by group (``lax.ragged_dot``), ``T`` pairs at a
-    time for as long as pairs remain: no capacity, so no token is ever
-    dropped; a flush whose tokens all pick the same experts takes more
-    turns (at most ``num_experts_per_tok``), not a larger buffer."""
+    and multiplied group by group (``ops/grouped_matmul.py``: a kernel
+    that walks the row tiles in which a group has a row, so that an expert
+    fed 18 rows costs its matrices' bytes and one tile of rows), ``T / 4``
+    pairs at a time for as long as pairs remain: no capacity, so no token
+    is ever dropped; a flush whose tokens all pick the same experts takes
+    more turns (at most ``4 x num_experts_per_tok``), not a larger
+    buffer."""
     tokens, k = experts.shape
     held = w_gate.shape[0]
     local = experts - first_expert
     mine = (local >= 0) & (local < held) & real[:, None]
     group = jnp.where(mine, local, held).reshape(-1)       # held = "not here"
     order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    token_of = order // k
-    weight_of = weights.reshape(-1)[order]
     counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
     ends = jnp.cumsum(counts)
     n_held = ends[-1]
-    chunk = tokens                      # pairs a turn: twice the mean when
-    #                                     every position is a real token
-
-    # a product of bfloat16 inputs is exact in the float32 it is summed
-    # in: a process-wide "highest" (the tests') has nothing to add, and
-    # the TPU's grouped kernel refuses it
-    precision = (None if jnp.dtype(dtype) == jnp.float32
-                 else lax.Precision.DEFAULT)
+    # pairs a turn, in whole tiles of the grouped product: a quarter of the
+    # slots (a rung served with 7 slots in 10 pads sends ~0.15 pairs a slot
+    # to 12 of 192 experts, 8 a token).  What a turn costs beside the
+    # products (the gather, the 0/1 product) grows with its size, a further
+    # turn costs the groups it touches: so the typical flush, not the
+    # fullest, sizes it
+    quarter = max(1, tokens // 4)
+    tile = grouped_matmul.tiling(quarter, *w_gate.shape[1:], dtype)[0]
+    chunk = -(-quarter // tile) * tile
+    spare = -(tokens * k) % chunk       # the last turn's slice stays inside
+    token_of = jnp.pad(order // k, (0, spare))
+    weight_of = jnp.pad(weights.reshape(-1)[order], (0, spare))
 
     def turn(state):
-        c, acc = state
+        c, acc, tile_rows = state
         lo = c * chunk
         sizes = (jnp.clip(ends, lo, lo + chunk)
                  - jnp.clip(ends - counts, lo, lo + chunk))
 
         def grouped(rows, stack, out_dtype):
-            return lax.ragged_dot(rows, stack.astype(dtype), sizes,
-                                  precision=precision,
-                                  preferred_element_type=out_dtype)
+            return grouped_matmul.grouped_matmul(
+                rows, stack.astype(dtype), sizes, out_dtype=out_dtype)
 
         tok = lax.dynamic_slice(token_of, (lo,), (chunk,))
         wgt = lax.dynamic_slice(weight_of, (lo,), (chunk,))
@@ -281,20 +288,26 @@ def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
         gate = grouped(x, w_gate, dtype)
         up = grouped(x, w_up, dtype)
         y = grouped(nn.silu(gate) * up, w_down, jnp.float32)
+        # a row beyond the groups comes back unspecified (NaN, interpreted):
+        # masked here, ahead of every sum over rows
         y = jnp.where(live[:, None], y * wgt[:, None], 0.0).astype(dtype)
         # back to the tokens: a 0/1 matrix times y on the MXU (exact; a
         # scatter-add of rows is a serial loop on the TPU)
         place = (tok[None, :] == jnp.arange(tokens)[:, None]) & live[None, :]
-        return c + 1, acc + jnp.dot(place.astype(dtype), y,
-                                    preferred_element_type=jnp.float32)
+        visits = grouped_matmul.tile_visits(sizes, chunk, tile)[3]
+        return (c + 1,
+                acc + jnp.dot(place.astype(dtype), y,
+                              preferred_element_type=jnp.float32),
+                tile_rows + visits * tile)
 
     def more(state):
         return state[0] * chunk < n_held
 
-    _, out = lax.while_loop(
+    _, out, tile_rows = lax.while_loop(
         more, turn, (jnp.int32(0),
-                     jnp.zeros((tokens, h.shape[-1]), jnp.float32)))
-    return out, n_held, jnp.max(counts)
+                     jnp.zeros((tokens, h.shape[-1]), jnp.float32),
+                     jnp.int32(0)))
+    return out, n_held, jnp.max(counts), tile_rows
 
 
 class MoE(nn.Module):
@@ -316,12 +329,12 @@ class MoE(nn.Module):
         shared = DenseMLP(width * d.n_shared_experts, dt, name="shared")(h)
         flat, flat_real = h.reshape(-1, hidden), real.reshape(-1)
         experts, weights = route(flat, w_router, d)
-        routed, n_held, most = held_expert_sum(
+        routed, n_held, most, tile_rows = held_expert_sum(
             flat, experts, weights, flat_real, w_gate, w_up, w_down,
             first_expert=d.first_expert, dtype=dt)
         total = jnp.sum(flat_real) * d.num_experts_per_tok
         self.sow(COUNTERS, "layer", jnp.stack(
-            [n_held, most, total.astype(jnp.int32)]))
+            [n_held, most, total.astype(jnp.int32), tile_rows]))
         self.sow(ROUTING, "experts",
                  experts.reshape(h.shape[:2] + experts.shape[-1:]))
         return shared + routed.reshape(h.shape).astype(dt)
@@ -388,6 +401,5 @@ def sum_counters(collection) -> dict:
     blocks = jax.tree_util.tree_leaves(collection)
     stacked = (jnp.stack(blocks) if blocks
                else jnp.zeros((1, len(COUNTER_NAMES)), jnp.int32))
-    held, most, total = stacked[:, 0], stacked[:, 1], stacked[:, 2]
-    return dict(zip(COUNTER_NAMES,
-                    (jnp.sum(held), jnp.max(most), jnp.sum(total))))
+    return {name: (jnp.max if name.endswith("_max") else jnp.sum)(column)
+            for name, column in zip(COUNTER_NAMES, stacked.T)}

@@ -133,7 +133,7 @@ def test_sixteen_shares_of_twelve_add_up_to_the_uncut_layer():
     program_sum, reference_sum, pairs = shared, shared, 0
     for share in range(16):
         lo = 12 * share
-        part, n_held, _most = text_lm.held_expert_sum(
+        part, n_held, _most, _rows = text_lm.held_expert_sum(
             h, experts, weights, real, w["moe/w_gate"][lo:lo + 12],
             w["moe/w_up"][lo:lo + 12], w["moe/w_down"][lo:lo + 12],
             first_expert=lo, dtype=jnp.float32)
@@ -164,7 +164,7 @@ def test_no_token_dropped_when_every_token_picks_the_same_experts():
     experts = jnp.tile(jnp.asarray([[2, 3, 4, 5]], jnp.int32), (tokens, 1))
     weights = jnp.asarray(rng.random((tokens, k)), jnp.float32)
     real = jnp.ones((tokens,), bool).at[7].set(False)
-    out, n_held, most = jax.jit(
+    out, n_held, most, _rows = jax.jit(
         text_lm.held_expert_sum, static_argnames=("first_expert", "dtype"))(
         h, experts, weights, real, gate, up, down, first_expert=1,
         dtype=jnp.float32)
@@ -204,10 +204,12 @@ def test_counters_count_real_pairs_only():
                              mutable=[text_lm.COUNTERS])
     counters = text_lm.sum_counters(sown)
     assert tuple(counters) == text_lm.COUNTER_NAMES
-    held, most, total = (int(v) for v in counters.values())
+    held, most, total, tile_rows = (int(v) for v in counters.values())
     moe_layers = lm.num_hidden_layers - lm.first_k_dense_replace
     assert total == int((ids != 0).sum()) * lm.num_experts_per_tok * moe_layers
     assert 0 < held < total and 0 < most <= int((ids != 0).sum())
+    # every held pair lies in a tile the grouped products visited
+    assert held <= tile_rows and tile_rows % moe_layers == 0
 
 
 @pytest.mark.parametrize("field,value", [

@@ -212,6 +212,34 @@ def test_full_width_train_step_fits_one_v5e(topo):
 
 
 # --------------------------------------------------------------------------
+# the expert layers' grouped product at the served tower's shapes, every rung
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("rows", [16, 32, 64])
+def test_grouped_matmul_compiles_at_each_rungs_turn(one_chip, rows):
+    """``ops/grouped_matmul.py`` at what ``held_expert_sum`` hands it in
+    the ``rows``-row program of ``query-text-axk1-c64``: a turn of
+    ``rows x 32 / 4`` pairs against 12 held experts' 7168 x 2048 (gate,
+    up) and 2048 x 7168 (down) bfloat16 matrices, at the tile the rule
+    gives: Mosaic takes the blocks (4 MB of a matrix a step, double-
+    buffered, beside a float32 accumulator) and the dynamic grid bound."""
+    from milnce_tpu.ops import grouped_matmul as gm
+
+    turn, held, hidden, width = rows * 32 // 4, 12, 7168, 2048
+    sizes = _shape(one_chip, (held,), jnp.int32)
+    for k, n, out_dtype in ((hidden, width, jnp.bfloat16),
+                            (width, hidden, jnp.float32)):
+        tm, tk, tn = gm.tiling(turn, k, n, jnp.bfloat16)
+        assert tm == 128 and turn % tm == 0
+        assert tk * tn * 2 <= gm.BLOCK_BYTES and k % tk == 0 and n % tn == 0
+        _, text = _compile(
+            lambda r, s, g: gm.grouped_matmul(r, s, g, out_dtype=out_dtype),
+            _shape(one_chip, (turn, k), jnp.bfloat16),
+            _shape(one_chip, (held, k, n), jnp.bfloat16), sizes)
+        assert "tpu_custom_call" in text and "grouped_matmul" in text
+
+
+# --------------------------------------------------------------------------
 # the language-model sentence tower at its published widths, top rung
 # --------------------------------------------------------------------------
 
@@ -222,8 +250,10 @@ def test_axk1_sentence_tower_fits_beside_what_the_cell_holds(topo):
     ranks 1536/512, 192-wide router, 12 experts held, 8 layers, bfloat16 —
     at the 64-row rung of 32 tokens, lowered from ``jax.eval_shape``
     shapes onto a one-device mesh of the described topology.  It compiles
-    (the grouped expert products lower to the TPU's ragged-dot kernel),
-    and what it keeps and needs on the device leaves room, inside the
+    (the grouped expert products are ``ops/grouped_matmul.py``'s kernel,
+    lowered through Mosaic as this file's other kernels are: a
+    ``tpu_custom_call`` named ``grouped_matmul``, and no ``ragged-dot``
+    left), and what it keeps and needs on the device leaves room, inside the
     16,909,336,064 bytes the chip's ``memory_stats()`` gives as
     ``bytes_limit`` (PERF.md), for what the cell holds beside it: while
     serving, the index shard and two (64, rows) float32 score blocks; at
@@ -257,7 +287,9 @@ def test_axk1_sentence_tower_fits_beside_what_the_cell_holds(topo):
         {"params": params},
         jax.ShapeDtypeStruct((rows, words), jnp.int32,
                              sharding=data)).compile()
-    assert "ragged-dot" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    assert "ragged-dot" not in text
     mem = compiled.memory_analysis()
     need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
