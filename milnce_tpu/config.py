@@ -98,10 +98,14 @@ class ModelConfig:
     text_tower: str = "bow"             # 'bow': word table -> MLP -> max-pool
                                         # (models/text.py) | 'lm': the causal
                                         # language model of the text_lm group
-                                        # (models/text_lm.py; served only)
+                                        # (models/text_lm.py; served only) |
+                                        # 'hybrid': the state-space /
+                                        # attention language model of the
+                                        # text_hybrid group
+                                        # (models/text_hybrid.py; served only)
 
 
-TEXT_TOWERS = ("bow", "lm")
+TEXT_TOWERS = ("bow", "lm", "hybrid")
 
 
 @dataclass
@@ -142,6 +146,47 @@ class TextLMConfig:
     num_hidden_layers: int = 3
     vocab_size: int = 128
     experts_held: int = 16
+    first_expert: int = 0
+
+
+@dataclass
+class TextHybridConfig:
+    """The language model behind ``model.text_tower = 'hybrid'`` (Mamba-2
+    and attention layers by a list of kinds, routed experts beside a
+    shared MLP in every layer), under the names its published
+    ``config.json`` gives them (a ``granitemoehybrid`` config;
+    ``layer_types`` comma-joined, of which the tower builds the first
+    ``num_hidden_layers``), plus the chip's share of each expert layer:
+    ``experts_held`` experts from ``first_expert`` on (all
+    ``num_local_experts`` from 0: the whole layer).  Defaults: a small
+    model of the same shape, for tests."""
+
+    hidden_size: int = 64
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    layer_types: str = "mamba,attention,mamba"
+    intermediate_size: int = 32         # one routed expert's width
+    shared_intermediate_size: int = 48
+    num_local_experts: int = 8
+    num_experts_per_tok: int = 3
+    mamba_n_heads: int = 8
+    mamba_d_head: int = 16
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 8
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    attention_multiplier: float = 0.0625
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    position_embedding_type: str = "nope"
+    hidden_act: str = "silu"
+    rms_norm_eps: float = 1e-5
+    num_hidden_layers: int = 3
+    vocab_size: int = 128
+    experts_held: int = 8
     first_expert: int = 0
 
 
@@ -584,6 +629,7 @@ class Config:
     data: DataConfig = field(default_factory=DataConfig)
     model: ModelConfig = field(default_factory=ModelConfig)
     text_lm: TextLMConfig = field(default_factory=TextLMConfig)
+    text_hybrid: TextHybridConfig = field(default_factory=TextHybridConfig)
     loss: LossConfig = field(default_factory=LossConfig)
     optim: OptimConfig = field(default_factory=OptimConfig)
     parallel: ParallelConfig = field(default_factory=ParallelConfig)

@@ -12,8 +12,8 @@ import os
 import jax.numpy as jnp
 import numpy as np
 
-from milnce_tpu.config import (TEXT_TOWERS, ModelConfig, TextLMConfig,
-                               parse_conv_impl_map)
+from milnce_tpu.config import (TEXT_TOWERS, ModelConfig, TextHybridConfig,
+                               TextLMConfig, parse_conv_impl_map)
 from milnce_tpu.models.s3dg import S3D
 from milnce_tpu.models.text import word2vec_embedding_init
 
@@ -33,9 +33,10 @@ def load_word2vec_table(path: str) -> np.ndarray:
 
 
 def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
-                text_lm: TextLMConfig | None = None) -> S3D:
-    """``text_lm``: the language model's group, needed (and validated)
-    where ``cfg.text_tower`` is 'lm'."""
+                text_lm: TextLMConfig | None = None,
+                text_hybrid: TextHybridConfig | None = None) -> S3D:
+    """``text_lm`` / ``text_hybrid``: the language model's group, needed
+    (and validated) where ``cfg.text_tower`` is 'lm' / 'hybrid'."""
     if cfg.text_tower not in TEXT_TOWERS:
         raise ValueError(f"model.text_tower={cfg.text_tower!r}: one of "
                          f"{', '.join(TEXT_TOWERS)}")
@@ -47,6 +48,15 @@ def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
             raise ValueError("model.text_tower='lm' needs the text_lm group "
                              "(build_model(cfg.model, text_lm=cfg.text_lm))")
         lm = lm_dims(text_lm)
+    hybrid = None
+    if cfg.text_tower == "hybrid":
+        from milnce_tpu.models.text_hybrid import hybrid_dims
+
+        if text_hybrid is None:
+            raise ValueError(
+                "model.text_tower='hybrid' needs the text_hybrid group "
+                "(build_model(cfg.model, text_hybrid=cfg.text_hybrid))")
+        hybrid = hybrid_dims(text_hybrid)
     embedding_init = None
     vocab_size = cfg.vocab_size
     if cfg.word2vec_path and os.path.exists(cfg.word2vec_path):
@@ -71,5 +81,6 @@ def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
         embedding_init=embedding_init,
         remat=cfg.remat,
         text_lm=lm,
+        text_hybrid=hybrid,
         dtype=jnp.dtype(cfg.dtype),
     )

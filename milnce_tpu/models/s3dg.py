@@ -253,6 +253,8 @@ class S3D(nn.Module):
     text_lm: Optional[Any] = None       # models/text_lm.py LMDims: the
                                         # sentence tower is that language
                                         # model (None: the bag-of-words one)
+    text_hybrid: Optional[Any] = None   # models/text_hybrid.py HybridDims:
+                                        # the sentence tower is that one
     dtype: Any = jnp.float32
 
     def setup(self):
@@ -330,6 +332,14 @@ class S3D(nn.Module):
             self.text_module = TextLM(self.text_lm,
                                       embd_dim=self.num_classes,
                                       dtype=self.dtype, name="text_module")
+            return
+        if self.text_hybrid is not None:
+            from milnce_tpu.models.text_hybrid import TextHybrid
+
+            self.text_module = TextHybrid(self.text_hybrid,
+                                          embd_dim=self.num_classes,
+                                          dtype=self.dtype,
+                                          name="text_module")
             return
         self.text_module = SentenceEmbedding(
             embd_dim=self.num_classes,

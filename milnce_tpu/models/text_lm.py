@@ -395,11 +395,12 @@ class TextLM(nn.Module):
         return _dot(x, proj, dt)
 
 
-def sum_counters(collection) -> dict:
-    """The sown per-layer blocks -> ``COUNTER_NAMES`` -> int32 scalar:
-    pairs summed over the layers, the expert maximum taken over them."""
+def sum_counters(collection, names=COUNTER_NAMES) -> dict:
+    """The sown per-layer blocks (one entry a name, in ``names``' order)
+    -> name -> int32 scalar: summed over the layers, a ``*_max`` taken
+    over them."""
     blocks = jax.tree_util.tree_leaves(collection)
     stacked = (jnp.stack(blocks) if blocks
-               else jnp.zeros((1, len(COUNTER_NAMES)), jnp.int32))
+               else jnp.zeros((1, len(names)), jnp.int32))
     return {name: (jnp.max if name.endswith("_max") else jnp.sum)(column)
-            for name, column in zip(COUNTER_NAMES, stacked.T)}
+            for name, column in zip(names, stacked.T)}

@@ -202,7 +202,8 @@ def load_serving_model(export_dir: str, dtype: str = ""):
     weights resident, dequantize inside the jitted entries, f32
     accumulation.  ``dtype`` overrides are refused for quantized
     artifacts (the stored precision IS the artifact's contract)."""
-    from milnce_tpu.config import ModelConfig, TextLMConfig
+    from milnce_tpu.config import (ModelConfig, TextHybridConfig,
+                                   TextLMConfig)
     from milnce_tpu.models.build import build_model
     from milnce_tpu.serving.export import (QUANT_FORMAT_VERSION,
                                            load_inference_checkpoint,
@@ -225,7 +226,9 @@ def load_serving_model(export_dir: str, dtype: str = ""):
         model_cfg.dtype = dtype
     text_lm = (TextLMConfig(**meta["text_lm"]) if "text_lm" in meta
                else None)
-    model = build_model(model_cfg, text_lm=text_lm)
+    text_hybrid = (TextHybridConfig(**meta["text_hybrid"])
+                   if "text_hybrid" in meta else None)
+    model = build_model(model_cfg, text_lm=text_lm, text_hybrid=text_hybrid)
     if quantized:
         from milnce_tpu.quant.quantize import QuantizedModel
 
@@ -371,6 +374,25 @@ class InferenceEngine:
         ``_video_fn``.  Tracing these does NOT require a warmed engine:
         build with ``precompile=False`` for planning-only use."""
         return {"text": self._text_fn, "video": self._video_fn}
+
+    def program_text(self, entry: str, bucket: int) -> str:
+        """The compiled program of ``entry`` ('text' | 'video') at the
+        ``bucket``-row rung, as HLO text: every instruction with the
+        ``op_name`` that holds its ``jax.named_scope``s.  A device trace
+        names operations by their instruction alone; this is how a reader
+        finds the scope one ran under.  Compiled ahead of time from
+        shapes: neither the jit cache nor :meth:`recompiles` sees it."""
+        shape, dtype = {
+            "text": ((self.text_words,), np.int32),
+            "video": (self.video_shape, np.uint8)}[entry]
+        avals = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            self._variables)
+        rows = jax.ShapeDtypeStruct((int(bucket),) + tuple(shape), dtype,
+                                    sharding=self._batch_sh)
+        return self.jit_entries()[entry].lower(avals, rows).compile() \
+            .as_text()
 
     # ---- warmup + recompile accounting -----------------------------------
 

@@ -559,23 +559,31 @@ def make_video_embed_fn(model, mesh: Mesh, data_axis: str = "data",
 
 def make_text_embed_fn(model, mesh: Mesh, data_axis: str = "data"):
     """Jitted sentence tower: text_ids sharded on dim 0 -> sharded embeds.
-    A language-model tower (``model.text_lm``) is its own program,
-    ``text_lm_tower``, and returns beside the embeddings its expert
-    layers' counters, name -> int32 scalar over all the data shards
-    (``models/text_lm.py COUNTER_NAMES``)."""
-    if getattr(model, "text_lm", None) is not None:
+    A language-model tower is its own program — ``text_lm_tower``
+    (``model.text_lm``) or ``text_hybrid_tower`` (``model.text_hybrid``) —
+    and returns beside the embeddings its layers' counters, name -> int32
+    scalar over all the data shards (the tower's ``COUNTER_NAMES``)."""
+    kind = next((k for k in ("text_lm", "text_hybrid")
+                 if getattr(model, k, None) is not None), None)
+    if kind is not None:
+        from milnce_tpu.models import text_hybrid, text_lm
         from milnce_tpu.models.text_lm import COUNTERS, sum_counters
 
-        def text_lm_tower(variables, text_ids):
+        names = {"text_lm": text_lm, "text_hybrid": text_hybrid}[
+            kind].COUNTER_NAMES
+
+        def tower(variables, text_ids):
             emb, sown = model.apply(variables, None, text_ids, mode="text",
                                     mutable=[COUNTERS])
             return emb, {
                 name: (jax.lax.pmax if name.endswith("_max")
                        else jax.lax.psum)(value, data_axis)
-                for name, value in sum_counters(sown).items()}
+                for name, value in sum_counters(sown, names).items()}
 
+        # the jitted program's name in a trace: ``jit_<name>``
+        tower.__name__ = tower.__qualname__ = f"{kind}_tower"
         return jax.jit(jax.shard_map(
-            text_lm_tower, mesh=mesh, in_specs=(P(), P(data_axis)),
+            tower, mesh=mesh, in_specs=(P(), P(data_axis)),
             out_specs=(P(data_axis), P()), check_vma=False))
 
     def local(variables, text_ids):

@@ -303,3 +303,71 @@ def test_axk1_sentence_tower_fits_beside_what_the_cell_holds(topo):
     assert mem.argument_size_in_bytes + video_warm_up < limit, (
         f"tower's weights {mem.argument_size_in_bytes / 1e9:.2f} GB beside "
         "the video tower's warm-up")
+
+
+# --------------------------------------------------------------------------
+# the hybrid sentence tower at its published widths, top rung
+# --------------------------------------------------------------------------
+
+def test_granite4h_sentence_tower_fits_beside_what_the_cell_holds(topo):
+    """``make_text_embed_fn``'s program (``text_hybrid_tower``) for the
+    ``text_hybrid`` group that ``benchmarks/drivers/serve_tower.py`` makes
+    from ``benchmarks/configs/s3dg-granite4h-text-32f224.json`` — hidden
+    4096, nine Mamba-2 layers (128 heads x 64, state 128, chunks of 256)
+    and one NoPE attention layer (32 query heads over 8), a 72-wide router,
+    36 experts held, bfloat16 — at the 16-row rung of 512 tokens, lowered
+    from ``jax.eval_shape`` shapes onto a one-device mesh of the described
+    topology.  It compiles (the grouped expert products are
+    ``ops/grouped_matmul.py``'s kernel at (4096, 768) and (768, 4096)
+    matrices), and its arguments and temporaries, with the index shard and
+    two (16, rows) float32 score blocks, lie inside the 16,909,336,064
+    bytes the chip's ``memory_stats()`` gives as ``bytes_limit``."""
+    from benchmarks import harness
+    from benchmarks.drivers import serve_tower
+    from milnce_tpu.config import parse_cli
+    from milnce_tpu.models import text_hybrid
+    from milnce_tpu.models.build import build_model
+    from milnce_tpu.train.step import make_text_embed_fn
+
+    cell = harness.load_json(
+        "benchmarks/configs/s3dg-granite4h-text-32f224.json")
+    cfg = parse_cli(serve_tower.group_flags(cell) + [
+        "--model.text_tower", "hybrid", "--model.dtype", "bfloat16"])
+    rows, words = cell["serve"]["max_batch"], cell["data"]["max_words"]
+    group = cfg.text_hybrid
+    assert (group.hidden_size, group.num_local_experts, group.experts_held,
+            group.num_hidden_layers, rows, words) == (4096, 72, 36, 10, 16,
+                                                      512)
+    model = build_model(cfg.model, text_hybrid=group)
+    assert model.text_hybrid.layer_types == ("mamba",) * 5 + (
+        "attention",) + ("mamba",) * 4
+    tower = text_hybrid.TextHybrid(model.text_hybrid, embd_dim=512,
+                                   dtype=jnp.bfloat16)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    repl, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    shapes = jax.eval_shape(tower.init, jax.random.PRNGKey(0),
+                            jnp.zeros((rows, words), jnp.int32))["params"]
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=repl),
+        {"text_module": shapes})
+    held = sum(s.size for s in jax.tree_util.tree_leaves(shapes))
+    assert 4.7e9 < held < 4.85e9            # 4.76 B parameters, 9.5 GB
+    compiled = make_text_embed_fn(model, mesh).lower(
+        {"params": params},
+        jax.ShapeDtypeStruct((rows, words), jnp.int32,
+                             sharding=data)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    assert "text_hybrid/ssd" in text and "text_hybrid_tower" in text
+    mem = compiled.memory_analysis()
+    need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    limit = 16_909_336_064
+    index = cell["index"]["rows"] * cell["index"]["dim"] * 4
+    scores = 2 * rows * cell["index"]["rows"] * 4
+    print(f"granite4h tower: {held} parameters, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert need + index + scores < limit, (
+        f"tower {need / 1e9:.2f} GB + index {index / 1e9:.2f} GB + scores "
+        f"{scores / 1e9:.2f} GB")
