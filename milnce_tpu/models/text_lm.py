@@ -234,6 +234,15 @@ def route(h, w_router, d):
     return experts.astype(jnp.int32), top * d.routed_scaling_factor
 
 
+def rank_block(chunk: int) -> int:
+    """Ranks (real tokens, counted from 0) a step of the put-back takes,
+    from the shapes alone: a turn's pairs, at most 512 (a step's 0/1
+    matrix is at most 512 x ``chunk``: the MXU runs it at ~9/10 of its
+    peak from 256 ranks on, and a flush wastes under a block of its real
+    tokens; PERF.md section 6, PR 33)."""
+    return min(chunk, 512)
+
+
 def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
                     first_expert: int, dtype):
     """sum over the chosen experts held here of weight x SwiGLU_e(h), for
@@ -248,31 +257,54 @@ def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
     pairs at a time for as long as pairs remain: no capacity, so no token
     is ever dropped; a flush whose tokens all pick the same experts takes
     more turns (at most ``4 x num_experts_per_tok``), not a larger
-    buffer."""
+    buffer.
+
+    A turn's weighted rows go back to their tokens (scope ``putback``) at
+    the cost of what the flush holds, not of its slots: every real token
+    has a rank (its place among the real ones), a pair carries its
+    token's rank through the sort, and the turn's rows are added into a
+    float32 buffer of ranks by a 0/1 product, ``rank_block`` ranks a step,
+    for as many steps as the real tokens fill (a value of the program).
+    After the loop one gather of rows lays the ranks back on the slots; a
+    pad reads a row that no rank wrote."""
     tokens, k = experts.shape
-    held = w_gate.shape[0]
+    held, hidden = w_gate.shape[0], h.shape[-1]
     local = experts - first_expert
     mine = (local >= 0) & (local < held) & real[:, None]
     group = jnp.where(mine, local, held).reshape(-1)       # held = "not here"
-    order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    rank = jnp.cumsum(real.astype(jnp.int32)) - 1      # of a real token
+    n_real = rank[-1] + 1
+    # a pair goes through the sort with its token, its weight and its
+    # token's rank: a gather of tokens x k scalars by the sorted order
+    # takes 0.6-0.7 ms a layer on the chip for each of the three, and a
+    # scatter-add of as many ones as long, so the groups are counted by
+    # comparison (PERF.md section 6, PR 33)
+    _, token_of, weight_of, rank_of = lax.sort(
+        (group, jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k),
+         weights.reshape(-1), jnp.repeat(rank, k)),
+        num_keys=1, is_stable=True)
+    counts = jnp.sum(group[None, :] == jnp.arange(held)[:, None], axis=1,
+                     dtype=jnp.int32)
     ends = jnp.cumsum(counts)
     n_held = ends[-1]
     # pairs a turn, in whole tiles of the grouped product: a quarter of the
     # slots (a rung served with 7 slots in 10 pads sends ~0.15 pairs a slot
     # to 12 of 192 experts, 8 a token).  What a turn costs beside the
-    # products (the gather, the 0/1 product) grows with its size, a further
+    # products (the gather, the put-back) grows with its size, a further
     # turn costs the groups it touches: so the typical flush, not the
     # fullest, sizes it
     quarter = max(1, tokens // 4)
     tile = grouped_matmul.tiling(quarter, *w_gate.shape[1:], dtype)[0]
     chunk = -(-quarter // tile) * tile
     spare = -(tokens * k) % chunk       # the last turn's slice stays inside
-    token_of = jnp.pad(order // k, (0, spare))
-    weight_of = jnp.pad(weights.reshape(-1)[order], (0, spare))
+    token_of, weight_of, rank_of = (jnp.pad(of, (0, spare)) for of in
+                                    (token_of, weight_of, rank_of))
+    block = rank_block(chunk)
+    blocks = (n_real + block - 1) // block              # that hold a rank
+    room = -(-tokens // block) * block
 
     def turn(state):
-        c, acc, tile_rows = state
+        c, by_rank, tile_rows = state
         lo = c * chunk
         sizes = (jnp.clip(ends, lo, lo + chunk)
                  - jnp.clip(ends - counts, lo, lo + chunk))
@@ -291,22 +323,40 @@ def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
         # a row beyond the groups comes back unspecified (NaN, interpreted):
         # masked here, ahead of every sum over rows
         y = jnp.where(live[:, None], y * wgt[:, None], 0.0).astype(dtype)
-        # back to the tokens: a 0/1 matrix times y on the MXU (exact; a
-        # scatter-add of rows is a serial loop on the TPU)
-        place = (tok[None, :] == jnp.arange(tokens)[:, None]) & live[None, :]
         visits = grouped_matmul.tile_visits(sizes, chunk, tile)[3]
-        return (c + 1,
-                acc + jnp.dot(place.astype(dtype), y,
-                              preferred_element_type=jnp.float32),
-                tile_rows + visits * tile)
+        with jax.named_scope("putback"):
+            # back to the tokens' ranks: a 0/1 matrix of (a block of ranks)
+            # x (the turn's pairs) times y on the MXU, exact, over the
+            # blocks that hold a real token (a scatter-add of rows is a
+            # serial loop on the TPU; a product over every slot of the
+            # flush pays for its pads)
+            ranks = jnp.where(
+                live, lax.dynamic_slice(rank_of, (lo,), (chunk,)), -1)
+
+            def add(b, by_rank):
+                at = b * block
+                place = ranks[None, :] == (at + jnp.arange(block))[:, None]
+                rows = lax.dynamic_slice(by_rank, (at, 0), (block, hidden))
+                return lax.dynamic_update_slice(
+                    by_rank,
+                    rows + jnp.dot(place.astype(dtype), y,
+                                   preferred_element_type=jnp.float32),
+                    (at, 0))
+
+            by_rank = lax.fori_loop(0, blocks, add, by_rank)
+        return c + 1, by_rank, tile_rows + visits * tile
 
     def more(state):
         return state[0] * chunk < n_held
 
-    _, out, tile_rows = lax.while_loop(
-        more, turn, (jnp.int32(0),
-                     jnp.zeros((tokens, h.shape[-1]), jnp.float32),
+    _, by_rank, tile_rows = lax.while_loop(
+        more, turn, (jnp.int32(0), jnp.zeros((room, hidden), jnp.float32),
                      jnp.int32(0)))
+    with jax.named_scope("putback"):
+        # where there is a pad, row ``n_real`` is the first that no rank
+        # wrote: still zero
+        out = jnp.take(by_rank, jnp.where(real, rank, n_real), axis=0,
+                       mode="clip")
     return out, n_held, jnp.max(counts), tile_rows
 
 

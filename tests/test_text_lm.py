@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import axk1_text as reference
+from milnce_tpu.analysis.trace_invariants import iter_eqns
 from milnce_tpu.config import ModelConfig, TextLMConfig, parse_cli
 from milnce_tpu.models import text_lm
 from milnce_tpu.models.build import build_model
@@ -175,6 +176,104 @@ def test_no_token_dropped_when_every_token_picks_the_same_experts():
         want += np.asarray(weights[:, j:j + 1] * y)
     want[7] = 0.0
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-4)
+
+
+def dense_held_sum(h, experts, weights, real, gate, up, down, first):
+    """Every real token's sum over its held experts of weight x
+    SwiGLU_e(h), expert by expert over every token: no sort, no turns."""
+    experts, weights = np.asarray(experts), np.asarray(weights)
+    out = np.zeros(h.shape, np.float32)
+    for e in range(gate.shape[0]):
+        w = np.sum(weights * (experts == first + e), axis=1)
+        out += w[:, None] * np.asarray(
+            reference.swiglu(h, gate[e], up[e], down[e]))
+    return out * np.asarray(real)[:, None]
+
+
+# 128 slots: a turn is 32 pairs, and so is a block of ranks.  name ->
+# (which slots are real, True where every token picks the SAME held experts)
+PUTBACK_CASES = {
+    "seven_slots_in_ten_are_pads": (lambda rng: rng.random(128) < 0.3, False),
+    "every_slot_is_real": (lambda rng: np.ones(128, bool), False),
+    "no_slot_is_real": (lambda rng: np.zeros(128, bool), False),
+    "every_token_picks_the_same_held_experts": (
+        lambda rng: rng.random(128) < 0.6, True),
+    "the_real_tokens_end_on_a_blocks_edge": (
+        lambda rng: rng.permutation(np.arange(128) < 64), False),
+    "real_tokens_first_then_pads_row_by_row": (
+        lambda rng: (np.arange(32)[None, :] < np.asarray(
+            [3, 32, 0, 17])[:, None]).reshape(-1), False),
+}
+
+
+@pytest.mark.parametrize("k", [8, 10])
+@pytest.mark.parametrize("case", list(PUTBACK_CASES))
+def test_the_routed_sum_against_the_dense_sum_token_by_token(case, k):
+    """``held_expert_sum`` (sorted pairs, turns, the put-back over the
+    real tokens' ranks, the gather back onto the slots) against the plain
+    sum: pads read zero, a block of ranks that holds no real token is
+    never multiplied, one that is full to its edge is, and a flush whose
+    tokens agree takes more turns."""
+    make_real, same = PUTBACK_CASES[case]
+    rng = np.random.default_rng(len(case) + k)
+    tokens, hidden, width, held, first, every = 128, 16, 8, 12, 2, 16
+    assert text_lm.rank_block(32) == 32
+    h = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+    gate, up = (jnp.asarray(rng.standard_normal((held, hidden, width)),
+                            jnp.float32) / 4 for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((held, width, hidden)),
+                       jnp.float32) / 3
+    real = make_real(rng)
+    experts = (np.tile(np.arange(first, first + k), (tokens, 1)) if same
+               else np.stack([rng.permutation(every)[:k]
+                              for _ in range(tokens)]))
+    weights = rng.random((tokens, k)).astype(np.float32)
+    out, n_held, most, _rows = jax.jit(
+        text_lm.held_expert_sum, static_argnames=("first_expert", "dtype"))(
+        h, jnp.asarray(experts, jnp.int32), jnp.asarray(weights),
+        jnp.asarray(real), gate, up, down, first_expert=first,
+        dtype=jnp.float32)
+    here = (experts >= first) & (experts < first + held) & real[:, None]
+    assert int(n_held) == here.sum()
+    assert int(most) == max(int((here & (experts == first + e)).sum())
+                            for e in range(held))
+    if same:
+        assert int(n_held) > 4 * 32           # more turns than the typical
+    out = np.asarray(out)
+    assert out.shape == (tokens, hidden) and out.dtype == np.float32
+    assert (out[~real] == 0).all()
+    np.testing.assert_allclose(
+        out, dense_held_sum(h, experts, weights, real, gate, up, down,
+                            first), rtol=1e-4, atol=1e-4)
+
+
+def test_no_product_of_every_slot_by_a_turns_pairs():
+    """At 8 rows x 512 slots, 10 experts a token: the put-back multiplies
+    a block of ranks (512) by a turn's pairs (1,024), never every slot
+    (4,096) by them, which is what a 0/1 matrix over the slots was; and
+    nothing gathers ``slots x k`` rows of ``hidden``."""
+    tokens, k, hidden, width, held = 8 * 512, 10, 64, 32, 4
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((tokens, hidden), jnp.bfloat16), ((tokens, k), jnp.int32),
+        ((tokens, k), jnp.float32), ((tokens,), jnp.bool_),
+        ((held, hidden, width), jnp.bfloat16),
+        ((held, hidden, width), jnp.bfloat16),
+        ((held, width, hidden), jnp.bfloat16))]
+    jaxpr = jax.make_jaxpr(lambda *a: text_lm.held_expert_sum(
+        *a, first_expert=0, dtype=jnp.bfloat16))(*shapes)
+    chunk, block = tokens // 4, text_lm.rank_block(tokens // 4)
+    assert (chunk, block) == (1024, 512)
+    eqns = list(iter_eqns(jaxpr.jaxpr))
+    products = [tuple(v.aval.shape for v in eqn.invars) for eqn in eqns
+                if eqn.primitive.name == "dot_general"]
+    assert ((block, chunk), (chunk, hidden)) in products
+    assert not [shapes for shapes in products
+                if any(tokens in shape for shape in shapes)]
+    gathered = [eqn.outvars[0].aval.shape for eqn in eqns
+                if eqn.primitive.name == "gather"]
+    assert not [shape for shape in gathered
+                if len(shape) == 2 and shape[0] > tokens
+                and shape[1] == hidden]
 
 
 def test_pads_and_batch_mates_change_nothing():
