@@ -103,9 +103,14 @@ class ModelConfig:
                                         # attention language model of the
                                         # text_hybrid group
                                         # (models/text_hybrid.py; served only)
+                                        # | 'dlm': the block-diffusion
+                                        # language model of the text_dlm
+                                        # group, which writes an expansion of
+                                        # the query before it embeds both
+                                        # (models/text_dlm.py; served only)
 
 
-TEXT_TOWERS = ("bow", "lm", "hybrid")
+TEXT_TOWERS = ("bow", "lm", "hybrid", "dlm")
 
 
 @dataclass
@@ -188,6 +193,45 @@ class TextHybridConfig:
     vocab_size: int = 128
     experts_held: int = 8
     first_expert: int = 0
+
+
+@dataclass
+class TextDLMConfig:
+    """The language model behind ``model.text_tower = 'dlm'`` (rotary
+    grouped-query attention with q/k norms and softmax-routed experts in
+    every layer, generating by diffusion over blocks), under the names its
+    published ``config.json`` gives them (an ``sdar_moe`` config), plus the
+    chip's share of each expert layer (``experts_held`` experts from
+    ``first_expert`` on; all ``num_experts`` from 0: the whole layer) and
+    what the service asks of the generation: ``expand_blocks`` blocks of
+    ``block_length`` positions written after the query, each denoised under
+    ``remasking`` with at least ``block_length / denoising_steps`` positions
+    committed a pass.  Defaults: a small model of the same shape, for
+    tests."""
+
+    hidden_size: int = 64
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 16
+    num_experts: int = 8
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 32
+    decoder_sparse_step: int = 1
+    norm_topk_prob: bool = True
+    attention_bias: bool = False
+    hidden_act: str = "silu"
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    num_hidden_layers: int = 2
+    vocab_size: int = 128
+    experts_held: int = 8
+    first_expert: int = 0
+    expand_blocks: int = 2
+    block_length: int = 4
+    denoising_steps: int = 4
+    remasking: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 127
 
 
 CONV_IMPLS = ("native", "fold2d", "im2col")        # models/conv3d.py
@@ -630,6 +674,7 @@ class Config:
     model: ModelConfig = field(default_factory=ModelConfig)
     text_lm: TextLMConfig = field(default_factory=TextLMConfig)
     text_hybrid: TextHybridConfig = field(default_factory=TextHybridConfig)
+    text_dlm: TextDLMConfig = field(default_factory=TextDLMConfig)
     loss: LossConfig = field(default_factory=LossConfig)
     optim: OptimConfig = field(default_factory=OptimConfig)
     parallel: ParallelConfig = field(default_factory=ParallelConfig)

@@ -12,8 +12,9 @@ import os
 import jax.numpy as jnp
 import numpy as np
 
-from milnce_tpu.config import (TEXT_TOWERS, ModelConfig, TextHybridConfig,
-                               TextLMConfig, parse_conv_impl_map)
+from milnce_tpu.config import (TEXT_TOWERS, ModelConfig, TextDLMConfig,
+                               TextHybridConfig, TextLMConfig,
+                               parse_conv_impl_map)
 from milnce_tpu.models.s3dg import S3D
 from milnce_tpu.models.text import word2vec_embedding_init
 
@@ -34,9 +35,11 @@ def load_word2vec_table(path: str) -> np.ndarray:
 
 def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
                 text_lm: TextLMConfig | None = None,
-                text_hybrid: TextHybridConfig | None = None) -> S3D:
-    """``text_lm`` / ``text_hybrid``: the language model's group, needed
-    (and validated) where ``cfg.text_tower`` is 'lm' / 'hybrid'."""
+                text_hybrid: TextHybridConfig | None = None,
+                text_dlm: TextDLMConfig | None = None) -> S3D:
+    """``text_lm`` / ``text_hybrid`` / ``text_dlm``: the language model's
+    group, needed (and validated) where ``cfg.text_tower`` is 'lm' /
+    'hybrid' / 'dlm'."""
     if cfg.text_tower not in TEXT_TOWERS:
         raise ValueError(f"model.text_tower={cfg.text_tower!r}: one of "
                          f"{', '.join(TEXT_TOWERS)}")
@@ -57,6 +60,15 @@ def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
                 "model.text_tower='hybrid' needs the text_hybrid group "
                 "(build_model(cfg.model, text_hybrid=cfg.text_hybrid))")
         hybrid = hybrid_dims(text_hybrid)
+    dlm = None
+    if cfg.text_tower == "dlm":
+        from milnce_tpu.models.text_dlm import dlm_dims
+
+        if text_dlm is None:
+            raise ValueError(
+                "model.text_tower='dlm' needs the text_dlm group "
+                "(build_model(cfg.model, text_dlm=cfg.text_dlm))")
+        dlm = dlm_dims(text_dlm)
     embedding_init = None
     vocab_size = cfg.vocab_size
     if cfg.word2vec_path and os.path.exists(cfg.word2vec_path):
@@ -82,5 +94,6 @@ def build_model(cfg: ModelConfig, bn_axis_name: str | None = None,
         remat=cfg.remat,
         text_lm=lm,
         text_hybrid=hybrid,
+        text_dlm=dlm,
         dtype=jnp.dtype(cfg.dtype),
     )

@@ -255,6 +255,8 @@ class S3D(nn.Module):
                                         # model (None: the bag-of-words one)
     text_hybrid: Optional[Any] = None   # models/text_hybrid.py HybridDims:
                                         # the sentence tower is that one
+    text_dlm: Optional[Any] = None      # models/text_dlm.py DLMDims: the
+                                        # same
     dtype: Any = jnp.float32
 
     def setup(self):
@@ -340,6 +342,13 @@ class S3D(nn.Module):
                                           embd_dim=self.num_classes,
                                           dtype=self.dtype,
                                           name="text_module")
+            return
+        if self.text_dlm is not None:
+            from milnce_tpu.models.text_dlm import TextDLM
+
+            self.text_module = TextDLM(self.text_dlm,
+                                       embd_dim=self.num_classes,
+                                       dtype=self.dtype, name="text_module")
             return
         self.text_module = SentenceEmbedding(
             embd_dim=self.num_classes,

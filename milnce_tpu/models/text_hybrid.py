@@ -223,7 +223,8 @@ class RoutedExperts(nn.Module):
         experts, weights = route(flat, w_router, d)
         routed, n_held, most, tile_rows = held_expert_sum(
             flat, experts, weights, flat_real, w_gate, w_up, w_down,
-            first_expert=d.first_expert, dtype=dt)
+            first_expert=d.first_expert, dtype=dt,
+            num_experts=d.num_local_experts)
         total = jnp.sum(flat_real) * d.num_experts_per_tok
         self.sow(ROUTING, "experts",
                  experts.reshape(h.shape[:2] + experts.shape[-1:]))

@@ -134,6 +134,14 @@ def _fan_in_normal(key, shape, dtype=jnp.float32):
             / math.sqrt(shape[-2])).astype(dtype)
 
 
+def rms_norm(x, weight, eps: float, dtype):
+    """x over its last axis, statistics in float32, out in ``dtype``."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32)).astype(dtype)
+
+
 class RMSNorm(nn.Module):
     eps: float
     dtype: Any = jnp.float32
@@ -141,10 +149,7 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x):
         w = self.param("weight", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(var + self.eps)
-                * w.astype(jnp.float32)).astype(self.dtype)
+        return rms_norm(x, w, self.eps, self.dtype)
 
 
 def _dot(x, w, dtype):
@@ -243,8 +248,28 @@ def rank_block(chunk: int) -> int:
     return min(chunk, 512)
 
 
+def turn_pairs(tokens: int, k: int, held: int,
+               num_experts: int | None = None) -> int:
+    """Pairs a turn of :func:`held_expert_sum` takes, from the share of
+    the experts held.  Every expert held (``held == num_experts``): every
+    pair of every real token meets a held expert, their number is known to
+    be at most ``tokens x k``, and one turn takes them all (a tower that
+    makes tens of passes a flush, each a few tokens a row, then pays one
+    turn's three kernels a layer a pass and not up to ``4 x k`` of them).
+    A part of them held (or ``num_experts`` not given): a quarter of the
+    slots, which is what a rung served with 7 slots in 10 pads sends to a
+    chip's share (~0.15 pairs a slot to 12 of 192 experts at 8 a token);
+    what a turn costs beside the products (the gather, the put-back) grows
+    with its size, a further turn costs the groups it touches: so the
+    typical flush, not the fullest, sizes it."""
+    if num_experts is not None and held == num_experts:
+        return tokens * k
+    return max(1, tokens // 4)
+
+
 def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
-                    first_expert: int, dtype):
+                    first_expert: int, dtype,
+                    num_experts: int | None = None):
     """sum over the chosen experts held here of weight x SwiGLU_e(h), for
     every real token.  w_* are (held, ...) stacks.  -> (out (T, hidden)
     float32, pairs held, most pairs of one expert, rows the grouped
@@ -253,11 +278,12 @@ def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
     The (token, expert) pairs that meet a held expert are sorted by expert
     and multiplied group by group (``ops/grouped_matmul.py``: a kernel
     that walks the row tiles in which a group has a row, so that an expert
-    fed 18 rows costs its matrices' bytes and one tile of rows), ``T / 4``
-    pairs at a time for as long as pairs remain: no capacity, so no token
-    is ever dropped; a flush whose tokens all pick the same experts takes
-    more turns (at most ``4 x num_experts_per_tok``), not a larger
-    buffer.
+    fed 18 rows costs its matrices' bytes and one tile of rows),
+    :func:`turn_pairs` pairs at a time (every pair at once where
+    ``num_experts`` says that every expert is held, else ``T / 4``) for as
+    long as pairs remain: no capacity, so no token is ever dropped; a
+    flush whose tokens all pick the same experts takes more turns (at most
+    ``4 x num_experts_per_tok``), not a larger buffer.
 
     A turn's weighted rows go back to their tokens (scope ``putback``) at
     the cost of what the flush holds, not of its slots: every real token
@@ -287,15 +313,10 @@ def held_expert_sum(h, experts, weights, real, w_gate, w_up, w_down, *,
                      dtype=jnp.int32)
     ends = jnp.cumsum(counts)
     n_held = ends[-1]
-    # pairs a turn, in whole tiles of the grouped product: a quarter of the
-    # slots (a rung served with 7 slots in 10 pads sends ~0.15 pairs a slot
-    # to 12 of 192 experts, 8 a token).  What a turn costs beside the
-    # products (the gather, the put-back) grows with its size, a further
-    # turn costs the groups it touches: so the typical flush, not the
-    # fullest, sizes it
-    quarter = max(1, tokens // 4)
-    tile = grouped_matmul.tiling(quarter, *w_gate.shape[1:], dtype)[0]
-    chunk = -(-quarter // tile) * tile
+    # pairs a turn, in whole tiles of the grouped product
+    pairs = turn_pairs(tokens, k, held, num_experts)
+    tile = grouped_matmul.tiling(pairs, *w_gate.shape[1:], dtype)[0]
+    chunk = -(-pairs // tile) * tile
     spare = -(tokens * k) % chunk       # the last turn's slice stays inside
     token_of, weight_of, rank_of = (jnp.pad(of, (0, spare)) for of in
                                     (token_of, weight_of, rank_of))
@@ -381,7 +402,8 @@ class MoE(nn.Module):
         experts, weights = route(flat, w_router, d)
         routed, n_held, most, tile_rows = held_expert_sum(
             flat, experts, weights, flat_real, w_gate, w_up, w_down,
-            first_expert=d.first_expert, dtype=dt)
+            first_expert=d.first_expert, dtype=dt,
+            num_experts=d.n_routed_experts)
         total = jnp.sum(flat_real) * d.num_experts_per_tok
         self.sow(COUNTERS, "layer", jnp.stack(
             [n_held, most, total.astype(jnp.int32), tile_rows]))

@@ -202,8 +202,7 @@ def load_serving_model(export_dir: str, dtype: str = ""):
     weights resident, dequantize inside the jitted entries, f32
     accumulation.  ``dtype`` overrides are refused for quantized
     artifacts (the stored precision IS the artifact's contract)."""
-    from milnce_tpu.config import (ModelConfig, TextHybridConfig,
-                                   TextLMConfig)
+    from milnce_tpu import config as program
     from milnce_tpu.models.build import build_model
     from milnce_tpu.serving.export import (QUANT_FORMAT_VERSION,
                                            load_inference_checkpoint,
@@ -221,14 +220,15 @@ def load_serving_model(export_dir: str, dtype: str = ""):
         meta, variables = load_quantized_checkpoint(export_dir)
     else:
         meta, variables = load_inference_checkpoint(export_dir)
-    model_cfg = ModelConfig(**meta["model"])
+    model_cfg = program.ModelConfig(**meta["model"])
     if dtype:
         model_cfg.dtype = dtype
-    text_lm = (TextLMConfig(**meta["text_lm"]) if "text_lm" in meta
-               else None)
-    text_hybrid = (TextHybridConfig(**meta["text_hybrid"])
-                   if "text_hybrid" in meta else None)
-    model = build_model(model_cfg, text_lm=text_lm, text_hybrid=text_hybrid)
+    # the language model's group, where the sentence tower is one
+    groups = {name: group(**meta[name]) for name, group in (
+        ("text_lm", program.TextLMConfig),
+        ("text_hybrid", program.TextHybridConfig),
+        ("text_dlm", program.TextDLMConfig)) if name in meta}
+    model = build_model(model_cfg, **groups)
     if quantized:
         from milnce_tpu.quant.quantize import QuantizedModel
 

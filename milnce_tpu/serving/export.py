@@ -181,7 +181,7 @@ def _unflatten(arrays: dict[str, np.ndarray], prefix: str) -> dict:
 def _artifact_metadata(model_cfg, *, max_words: int, video_shape,
                        step: int, source: str, arrays: dict,
                        format_version: int, text_lm=None,
-                       text_hybrid=None) -> dict:
+                       text_hybrid=None, text_dlm=None) -> dict:
     """Shared metadata assembly for the float and quantized formats:
     sanitized model config (and the language model's group, where the
     sentence tower is one), tokenizer contract, video shape and the
@@ -195,7 +195,8 @@ def _artifact_metadata(model_cfg, *, max_words: int, video_shape,
         f"{s}={i}" for s, i in sorted(impl_map.items()))
     token_dict = model_meta.pop("token_dict_path", "")
     group = {"lm": ("text_lm", text_lm),
-             "hybrid": ("text_hybrid", text_hybrid)}.get(
+             "hybrid": ("text_hybrid", text_hybrid),
+             "dlm": ("text_dlm", text_dlm)}.get(
                  model_meta.get("text_tower"))
     lm_meta = {group[0]: dataclasses.asdict(group[1])} if group else {}
     return {
@@ -222,14 +223,15 @@ def export_inference_checkpoint(out_dir: str, params, batch_stats,
                                 model_cfg, *, max_words: int,
                                 video_shape, step: int = 0,
                                 source: str = "", text_lm=None,
-                                text_hybrid=None) -> str:
+                                text_hybrid=None, text_dlm=None) -> str:
     """Write the frozen artifact; returns ``out_dir``.
 
     ``model_cfg`` is a ``milnce_tpu.config.ModelConfig``; host-specific
     fields (word2vec/token-dict paths, impl-map file paths) are
     sanitized so the artifact is self-contained.  ``text_lm`` /
-    ``text_hybrid``: the ``TextLMConfig`` / ``TextHybridConfig`` of a
-    ``text_tower='lm'`` / ``'hybrid'`` model.  Every leaf is
+    ``text_hybrid`` / ``text_dlm``: the ``TextLMConfig`` /
+    ``TextHybridConfig`` / ``TextDLMConfig`` of a ``text_tower='lm'`` /
+    ``'hybrid'`` / ``'dlm'`` model.  Every leaf is
     written in its own type (module docstring), none copied to another."""
     os.makedirs(out_dir, exist_ok=True)
     arrays = _flatten(params, "params")
@@ -242,7 +244,8 @@ def export_inference_checkpoint(out_dir: str, params, batch_stats,
                               video_shape=video_shape, step=step,
                               source=source, arrays=arrays,
                               format_version=FORMAT_VERSION,
-                              text_lm=text_lm, text_hybrid=text_hybrid)
+                              text_lm=text_lm, text_hybrid=text_hybrid,
+                              text_dlm=text_dlm)
     with open(os.path.join(out_dir, METADATA_FILE), "w") as fh:
         json.dump(meta, fh, indent=2, sort_keys=True)
     return out_dir

@@ -756,7 +756,7 @@ class RetrievalService:
             raise ValueError(
                 "service built without a tokenizer (the repo's is "
                 "word-level: an export whose sentence tower reads sub-word "
-                "ids, model.text_tower='lm' or 'hybrid', has none) — send "
+                "ids, a language model's, has none) — send "
                 "token_ids instead of sentences")
         return self.tokenizer.encode_batch(sentences,
                                            self.engine.text_words)
@@ -1152,14 +1152,14 @@ def build_server(cfg):
         meta = json.load(fh)
     tok_meta = meta.get("tokenizer", {})
     tokenizer = None
-    if meta.get("model", {}).get("text_tower") in ("lm", "hybrid"):
+    if meta.get("model", {}).get("text_tower", "bow") != "bow":
         # the repo's tokenizer is word-level; a language-model tower reads
         # sub-word ids of a vocabulary the repo does not have: the service
         # is built without one and refuses raw sentences
         if s.token_dict_path:
             raise SystemExit("--serve.token_dict_path names a word-level "
                              "dictionary; this export's sentence tower "
-                             "(model.text_tower='lm' or 'hybrid') reads "
+                             "(a language model) reads "
                              "sub-word ids")
     elif s.token_dict_path:
         if not os.path.exists(s.token_dict_path):

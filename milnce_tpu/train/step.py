@@ -560,17 +560,18 @@ def make_video_embed_fn(model, mesh: Mesh, data_axis: str = "data",
 def make_text_embed_fn(model, mesh: Mesh, data_axis: str = "data"):
     """Jitted sentence tower: text_ids sharded on dim 0 -> sharded embeds.
     A language-model tower is its own program — ``text_lm_tower``
-    (``model.text_lm``) or ``text_hybrid_tower`` (``model.text_hybrid``) —
-    and returns beside the embeddings its layers' counters, name -> int32
+    (``model.text_lm``), ``text_hybrid_tower`` (``model.text_hybrid``) or
+    ``text_dlm_tower`` (``model.text_dlm``) — and returns beside the embeddings its layers' counters, name -> int32
     scalar over all the data shards (the tower's ``COUNTER_NAMES``)."""
-    kind = next((k for k in ("text_lm", "text_hybrid")
+    kind = next((k for k in ("text_lm", "text_hybrid", "text_dlm")
                  if getattr(model, k, None) is not None), None)
     if kind is not None:
-        from milnce_tpu.models import text_hybrid, text_lm
+        import importlib
+
         from milnce_tpu.models.text_lm import COUNTERS, sum_counters
 
-        names = {"text_lm": text_lm, "text_hybrid": text_hybrid}[
-            kind].COUNTER_NAMES
+        names = importlib.import_module(
+            f"milnce_tpu.models.{kind}").COUNTER_NAMES
 
         def tower(variables, text_ids):
             emb, sown = model.apply(variables, None, text_ids, mode="text",

@@ -371,3 +371,76 @@ def test_granite4h_sentence_tower_fits_beside_what_the_cell_holds(topo):
     assert need + index + scores < limit, (
         f"tower {need / 1e9:.2f} GB + index {index / 1e9:.2f} GB + scores "
         f"{scores / 1e9:.2f} GB")
+
+
+# --------------------------------------------------------------------------
+# the block-diffusion sentence tower at its published widths, top rung
+# --------------------------------------------------------------------------
+
+def test_sdar_sentence_tower_fits_beside_what_the_cell_holds(topo):
+    """``make_text_embed_fn``'s program (``text_dlm_tower``) for the
+    ``text_dlm`` group that ``benchmarks/drivers/serve_tower.py`` makes
+    from ``benchmarks/configs/s3dg-sdar-text-32f224.json`` — hidden 2048,
+    32 query heads over 4 key/value heads of 128, all 128 experts of 768
+    held, six layers, the whole 151,936-row table and head, bfloat16 — at
+    the 64-row rung of 32 slots: ONE program that prefills, loops four
+    blocks of denoise passes and a commit pass over a 48-position cache,
+    and embeds.  It compiles for a described v5e (the grouped expert
+    products are ``ops/grouped_matmul.py``'s kernel at (2048, 768) and
+    (768, 2048) matrices over 128 groups, a turn of every pair), and its
+    arguments and temporaries, with the index shard and two (64, rows)
+    float32 score blocks, lie inside the 16,909,336,064 bytes the chip's
+    ``memory_stats()`` gives as ``bytes_limit``; at boot its weights stand
+    beside the video tower's 64-row warm-up (4.95 + 0.37 GB, PR 28)."""
+    from benchmarks import harness
+    from benchmarks.drivers import serve_tower
+    from milnce_tpu.config import parse_cli
+    from milnce_tpu.models import text_dlm
+    from milnce_tpu.models.build import build_model
+    from milnce_tpu.train.step import make_text_embed_fn
+
+    cell = harness.load_json("benchmarks/configs/s3dg-sdar-text-32f224.json")
+    cfg = parse_cli(serve_tower.group_flags(cell) + [
+        "--model.text_tower", "dlm", "--model.dtype", "bfloat16"])
+    rows, words = cell["serve"]["max_batch"], cell["data"]["max_words"]
+    group = cfg.text_dlm
+    assert (group.hidden_size, group.num_experts, group.experts_held,
+            group.num_hidden_layers, group.vocab_size, group.expand_blocks,
+            rows, words) == (2048, 128, 128, 6, 151936, 4, 64, 32)
+    model = build_model(cfg.model, text_dlm=group)
+    tower = text_dlm.TextDLM(model.text_dlm, embd_dim=512,
+                             dtype=jnp.bfloat16)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    repl, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    shapes = jax.eval_shape(tower.init, jax.random.PRNGKey(0),
+                            jnp.zeros((rows, words), jnp.int32))["params"]
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=repl),
+        {"text_module": shapes})
+    held = sum(s.size for s in jax.tree_util.tree_leaves(shapes))
+    assert held == 4_362_104_320            # 8.72 GB
+    compiled = make_text_embed_fn(model, mesh).lower(
+        {"params": params},
+        jax.ShapeDtypeStruct((rows, words), jnp.int32,
+                             sharding=data)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    for scope in cell["bench"]["scopes"]:
+        assert scope in text, scope
+    assert "text_dlm_tower" in text
+    mem = compiled.memory_analysis()
+    need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    limit = 16_909_336_064
+    index = cell["index"]["rows"] * cell["index"]["dim"] * 4
+    scores = 2 * rows * cell["index"]["rows"] * 4
+    video_warm_up = 4.95e9 + 0.37e9
+    print(f"sdar tower: {held} parameters, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert need + index + scores < limit, (
+        f"tower {need / 1e9:.2f} GB + index {index / 1e9:.2f} GB + scores "
+        f"{scores / 1e9:.2f} GB")
+    assert mem.argument_size_in_bytes + index + video_warm_up < limit, (
+        f"tower's weights {mem.argument_size_in_bytes / 1e9:.2f} GB and the "
+        "index beside the video tower's warm-up")
