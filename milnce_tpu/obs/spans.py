@@ -6,8 +6,11 @@ run after the fact: ``step`` (hot-loop dispatch), ``decode.timeout`` /
 micro-batches), ``ladder.warmup`` (engine pre-trace sweep),
 ``ckpt.save`` / ``ckpt.restore`` / ``rollback`` (checkpoint lifecycle),
 ``display`` (the train loop's cadenced fetch), ``query`` / ``dispatch``
-(a served call and each hold of the device lock), ``runtime.gc`` (a
-collector pause).
+(a served call and each hold of the device lock), ``worker.turn`` (the
+serving path's device worker, phase by phase: :class:`PhaseClock`),
+``runtime.gc`` / ``runtime.beat`` / ``runtime.stall`` (a collector
+pause, the interpreter's latency a second, one late wake-up:
+:class:`RuntimeWatch`).
 
 Durability has two tiers:
 
@@ -38,6 +41,7 @@ from __future__ import annotations
 import contextlib
 import gc
 import json
+import os
 import sys
 import threading
 import time
@@ -161,6 +165,17 @@ class SpanRecorder:
         records, with ``error`` naming the exception type."""
         return _Span(self, name, attrs)
 
+    def closed_span(self, name: str, t0: float, t1: float, **attrs) -> dict:
+        """The record :meth:`span` writes, for a region whose owner read
+        the clock itself (``t0``, ``t1``: :func:`now` readings — the two
+        ends of a :class:`PhaseClock` phase, so that the phase and the
+        span are ONE pair of readings); ``ts`` is the wall clock at
+        ``t0``.  Returns the record."""
+        rec = {"kind": "span", "name": name, "ts": _wall() - (t1 - t0),
+               **attrs, "dur_ms": round((t1 - t0) * 1e3, 4)}
+        self._record(rec)
+        return rec
+
     # ---- reading / lifecycle --------------------------------------------
 
     def tail(self, n: Optional[int] = None,
@@ -197,35 +212,131 @@ class SpanRecorder:
 
 
 # ---------------------------------------------------------------------------
-# collector pauses
+# one thread's turn, phase by phase
+# ---------------------------------------------------------------------------
+
+class PhaseClock:
+    """One thread's timeline cut into named phases that TILE it: the
+    thread is in exactly one phase at every instant, :meth:`mark` ends
+    the current phase and begins the next on ONE pair of clock readings
+    (wall: the recorder's clock; CPU: ``time.thread_time`` of the calling
+    thread), and :meth:`finish` writes what every phase took since the
+    last record as ONE ``<prefix>.turn`` event: ``<phase>_ms`` (wall),
+    ``<phase>_cpu_ms`` (left out for the ``waits``, phases that only
+    block) and ``dur_ms``, their sum.  Consecutive records leave no
+    stretch of the thread's time uncovered: the phase after a record is
+    ``rest``, begun on the record's last reading.
+
+    In a phase that is pure Python, wall less CPU is the time the thread
+    stood runnable or blocked without running: waiting for the
+    interpreter, or for a core.  (Where the kernel accounts CPU time by
+    the tick — 10 ms on the chip's host — ONE record's CPU reads 0 or a
+    tick: read sums over many records.)  Each phase is also an
+    :func:`annotation` ``<prefix>.<phase>``, so that a profiler session
+    shows the thread's line tiled by its phases on the device trace's
+    clock.  Host time only, no lock: the owner's ONE thread marks it (the
+    first mark starts the clock, on the thread that makes it)."""
+
+    __slots__ = ("_names", "_record", "_rest", "_cpu_of", "_wall", "_cpu",
+                 "_phase", "_t", "_c", "_bridge")
+
+    def __init__(self, prefix: str, phases: tuple, rest: str,
+                 waits: tuple = ()):
+        self._names = {p: f"{prefix}.{p}" for p in phases}
+        self._record = f"{prefix}.turn"
+        self._rest = rest
+        self._cpu_of = tuple(p for p in phases if p not in waits)
+        self._wall = dict.fromkeys(phases, 0.0)
+        self._cpu = dict.fromkeys(phases, 0.0)
+        self._phase = rest
+        self._t: Optional[float] = None
+        self._c = 0.0
+        self._bridge = contextlib.nullcontext()
+
+    def mark(self, phase: str) -> float:
+        """The current phase ends and ``phase`` begins, here -> the wall
+        reading they share (a :func:`now` reading)."""
+        t, c = _now(), time.thread_time()
+        if self._t is not None:
+            self._wall[self._phase] += t - self._t
+            self._cpu[self._phase] += c - self._c
+        self._t, self._c, self._phase = t, c, phase
+        self._bridge.__exit__(None, None, None)
+        self._bridge = annotation(self._names[phase])
+        self._bridge.__enter__()
+        return t
+
+    def finish(self, recorder: "SpanRecorder", **attrs) -> None:
+        """The turn ends here: the current phase ends, ``rest`` begins,
+        and the phases' times since the last record are written, with
+        ``attrs``, as one event on ``recorder``."""
+        self.mark(self._rest)
+        rec = dict(attrs)
+        for phase, s in self._wall.items():
+            rec[phase + "_ms"] = round(s * 1e3, 4)
+        for phase in self._cpu_of:
+            rec[phase + "_cpu_ms"] = round(self._cpu[phase] * 1e3, 4)
+        rec["dur_ms"] = round(sum(self._wall.values()) * 1e3, 4)
+        for phase in self._wall:
+            self._wall[phase] = self._cpu[phase] = 0.0
+        recorder.event(self._record, **rec)
+
+
+# ---------------------------------------------------------------------------
+# the runtime watcher: collector pauses, the interpreter's latency, stalls
 # ---------------------------------------------------------------------------
 
 # A collection that held the interpreter this long is worth a record: a
 # young-generation pass is tens of microseconds, a stall is not.
 GC_PAUSE_MIN_MS = 5.0
-_GC_DRAIN_S = 0.05
+# The watcher asks to be woken this often, and sums up once a report.
+BEAT_S = 0.02
+BEAT_REPORT_S = 1.0
+# A single wake-up this late is written at once, as ``runtime.stall``.
+STALL_MIN_MS = 50.0
 
 
-class GcPauseEvents:
-    """``runtime.gc`` events (``generation``, ``dur_ms``, ``collected``,
-    ``end_mono``) for every collection of :data:`GC_PAUSE_MIN_MS` or
-    more, from a ``gc.callbacks`` hook.
+def _run_queue_s(fd: Optional[int]) -> float:
+    """Seconds the thread behind ``fd`` (its ``schedstat``) has stood
+    runnable without a core so far: the file's second number."""
+    return 0.0 if fd is None else int(os.pread(fd, 128, 0).split()[1]) * 1e-9
 
-    A collection starts wherever an allocation triggers it — also inside
-    the recorder's or the run context's critical section, on the thread
-    that holds the lock — so the hook takes no lock: it reads the clock
-    and appends to a deque.  A daemon thread writes the events out at
-    most :data:`_GC_DRAIN_S` later; ``end_mono`` is the pause's own end
-    on the recorder's clock (the record's ``mono`` is when it was
-    written)."""
+
+class RuntimeWatch:
+    """The process's one runtime watcher: a ``gc.callbacks`` hook and ONE
+    daemon thread that asks to be woken every :data:`BEAT_S`.
+
+    - ``runtime.gc`` (``generation``, ``dur_ms``, ``collected``,
+      ``end_mono``) for every collection of :data:`GC_PAUSE_MIN_MS` or
+      more.  A collection starts wherever an allocation triggers it —
+      also inside the recorder's or the run context's critical section,
+      on the thread that holds the lock — so the hook takes no lock: it
+      reads the clock and appends to a deque, and the thread writes the
+      events out at its next wake-up; ``end_mono`` is the pause's own
+      end on the recorder's clock (the record's ``mono`` is when it was
+      written).
+    - ``runtime.beat``, one every :data:`BEAT_REPORT_S`: how LATE the
+      thread woke (the clock at wake-up less the instant it asked for:
+      what a thread that wants the interpreter, and a core, waits for
+      them) over the ``beats`` of ``dur_ms``: ``late_mean_ms``,
+      ``late_max_ms``; ``proc_cpu_ms``, the process's CPU time over the
+      same stretch (all threads, XLA's too).
+    - ``runtime.stall``, at once, for a single wake-up late by
+      :data:`STALL_MIN_MS` or more: ``late_ms``, ``end_mono`` (the
+      wake-up), ``proc_cpu_ms`` over the wait (the :data:`BEAT_S` asked
+      for and the ``late_ms`` after) and, where the kernel keeps it,
+      ``runq_ms``: how long of that wait this thread stood runnable with
+      no core.  ``proc_cpu_ms`` near 0 says that the whole process stood
+      still; near the wait's length, that one thread held the
+      interpreter; above it, that threads outside the interpreter ran."""
 
     def __init__(self, recorder: Optional["SpanRecorder"] = None):
         self._recorder = recorder       # None = the process default
         self._t0: Optional[float] = None
         self._pauses: deque = deque()
         self._stop = threading.Event()
-        self._writer = threading.Thread(target=self._run, daemon=True,
-                                        name="obs-gc-pauses")
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="obs-runtime-watch")
 
     def _hook(self, phase: str, info: dict) -> None:
         if phase == "start":
@@ -239,32 +350,72 @@ class GcPauseEvents:
             self._pauses.append((end, end - t0, info.get("generation"),
                                  info.get("collected")))
 
-    def _drain(self) -> None:
-        rec = self._recorder if self._recorder is not None \
+    def _rec(self) -> "SpanRecorder":
+        return self._recorder if self._recorder is not None \
             else get_recorder()
+
+    def _drain(self) -> None:
         while self._pauses:
             end, dur, generation, collected = self._pauses.popleft()
-            rec.event("runtime.gc", generation=generation,
-                      dur_ms=round(dur * 1e3, 4), collected=collected,
-                      end_mono=round(end, 6))
+            self._rec().event("runtime.gc", generation=generation,
+                              dur_ms=round(dur * 1e3, 4),
+                              collected=collected, end_mono=round(end, 6))
 
     def _run(self) -> None:
-        while not self._stop.wait(_GC_DRAIN_S):
-            self._drain()
+        try:        # this thread's own; a kernel that keeps none has no file
+            fd = os.open("/proc/thread-self/schedstat", os.O_RDONLY)
+        except OSError:
+            fd = None
+        try:
+            self._watch(fd)
+        finally:
+            if fd is not None:
+                os.close(fd)
 
-    def install(self) -> "GcPauseEvents":
-        """Hook in and start the writer (once per object)."""
+    def _watch(self, fd: Optional[int]) -> None:
+        beats, late_sum, late_max = 0, 0.0, 0.0
+        woke, cpu, queued = _now(), time.process_time(), _run_queue_s(fd)
+        since, cpu_since = woke, cpu
+        while True:
+            asked = _now()      # what the thread did awake is not lateness
+            if self._stop.wait(BEAT_S):
+                return
+            cpu_asked, queued_asked = cpu, queued
+            woke, cpu, queued = _now(), time.process_time(), _run_queue_s(fd)
+            late = max(0.0, woke - asked - BEAT_S)
+            beats, late_sum = beats + 1, late_sum + late
+            late_max = max(late_max, late)
+            if late * 1e3 >= STALL_MIN_MS:
+                more = ({} if fd is None else
+                        {"runq_ms": round((queued - queued_asked) * 1e3, 4)})
+                self._rec().event(
+                    "runtime.stall", late_ms=round(late * 1e3, 4),
+                    end_mono=round(woke, 6),
+                    proc_cpu_ms=round((cpu - cpu_asked) * 1e3, 4), **more)
+            self._drain()
+            if woke - since >= BEAT_REPORT_S:
+                self._rec().event(
+                    "runtime.beat", beats=beats,
+                    late_mean_ms=round(late_sum / beats * 1e3, 4),
+                    late_max_ms=round(late_max * 1e3, 4),
+                    proc_cpu_ms=round((cpu - cpu_since) * 1e3, 4),
+                    dur_ms=round((woke - since) * 1e3, 4))
+                beats, late_sum, late_max = 0, 0.0, 0.0
+                since, cpu_since = woke, cpu
+
+    def install(self) -> "RuntimeWatch":
+        """Hook in and start the thread (once per object)."""
         gc.callbacks.append(self._hook)
-        self._writer.start()
+        self._thread.start()
         return self
 
     def remove(self) -> None:
-        """Unhook, stop the writer and write out what is left."""
+        """Unhook, stop the thread and write out what is left."""
         with contextlib.suppress(ValueError):
             gc.callbacks.remove(self._hook)
         self._stop.set()
-        if self._writer.is_alive():
-            self._writer.join(timeout=5.0)
+        if self._thread.is_alive():
+            self._thread.join(timeout=5.0)
         self._drain()
 
 

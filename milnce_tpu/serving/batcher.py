@@ -37,6 +37,20 @@ a batch returns need not be one array either: ``take`` says how a
 request's rows are cut out of it (the scan coalescer of service.py gets
 scores, indices and the generation that ranked them).
 
+**The owner's turn** (``turns``, an ``obs_spans.PhaseClock``): every
+flush is one turn of the owner's thread, and the turn's phases tile that
+thread's time from the end of its last turn: ``sleep`` (the owner's own
+wait for a wake, between two marks of its own), ``take`` (:meth:`take`,
+and whatever else the owner does between two flushes), ``prepare``
+(``flush`` up to the executor's call: ``_expire``, the queue waits,
+``bucket_for``, ``np.concatenate``, ``pad_rows``), ``run`` (the
+executor; over a pool, the submit), ``scatter`` (``set_result`` on every
+request and whatever its done-callbacks do on this thread), ``account``
+(counters, ``on_flush``).  One ``worker.turn`` record a flush, written
+after its last phase, wall and CPU time a phase (OBSERVABILITY.md); the
+flush record's ``dur_ms`` IS the turn's ``run_ms`` (one pair of clock
+readings).  An owner of several batchers hands them one clock.
+
 numpy-only on purpose: payloads and results are host arrays; every
 device interaction lives behind the injected ``run_batch`` callable.
 Thread safety: ``submit`` may be called from any number of threads; one
@@ -60,6 +74,19 @@ import numpy as np
 from milnce_tpu.analysis.lockrt import make_lock
 from milnce_tpu.obs import metrics as obs_metrics
 from milnce_tpu.obs import spans as obs_spans
+
+# What tiles the owner thread's time, a turn (one flush) at a time: module
+# docstring.  ``sleep`` only blocks, so its CPU time is not read.
+TURN_PHASES = ("sleep", "take", "prepare", "run", "scatter", "account")
+
+
+def turn_clock() -> obs_spans.PhaseClock:
+    """The clock of one owner thread's turns (``worker.turn`` records,
+    ``worker.<phase>`` annotations); between two flushes the owner is in
+    ``take``."""
+    return obs_spans.PhaseClock("worker", TURN_PHASES, rest="take",
+                                waits=("sleep",))
+
 
 class DeadlineExpired(RuntimeError):
     """The request's deadline passed while it was still queued.
@@ -137,6 +164,8 @@ class DynamicBatcher:
       ``bucket_for`` then only names the bucket on the flush record.
     - ``span_name``: the flush record's name, so that two batchers of
       one service can be told apart by it (OBSERVABILITY.md).
+    - ``turns``: the owner thread's phase clock (module docstring), one
+      for all the batchers that thread drives; None = one of its own.
     """
 
     def __init__(self, run_batch: Callable[[np.ndarray], np.ndarray],
@@ -150,8 +179,10 @@ class DynamicBatcher:
                                                     Future]] = None,
                  lanes: int = 1,
                  take: Optional[Callable] = None, pad: bool = True,
-                 span_name: str = "batcher.flush"):
+                 span_name: str = "batcher.flush",
+                 turns: Optional[obs_spans.PhaseClock] = None):
         assert max_batch >= 1
+        self.turns = turns if turns is not None else turn_clock()
         self._run_batch = run_batch
         self._take = take
         self._pad = bool(pad)
@@ -332,10 +363,14 @@ class DynamicBatcher:
 
     def flush(self, batch: list[_Request], **attrs) -> None:
         """Run ``batch`` (what :meth:`take` handed the owner) and scatter
-        what it returns; ``attrs`` join the flush record."""
+        what it returns; ``attrs`` join the flush record, and ``epoch``
+        among them (the owner's count of its turns) the ``worker.turn``
+        record too, so that the two join by it."""
+        turn = self.turns
+        turn.mark("prepare")
         live = self._expire(batch)
         if not live:
-            return
+            return                      # what this took is the next turn's
         n = sum(r.rows for r in live)
         # how long the requests sat queued before this flush began: the
         # oldest one's wait and the mean, on the flush's own record
@@ -344,6 +379,14 @@ class DynamicBatcher:
         waited = {"queue_wait_ms": round(max(waits_ms), 4),
                   "queue_wait_mean_ms": round(sum(waits_ms) / len(live), 4),
                   **attrs}
+        rec = self._recorder if self._recorder is not None \
+            else obs_spans.get_recorder()
+        bucket = ran_from = failed = None
+
+        def end_turn():
+            turn.finish(rec, batcher=self.name, rows=n, bucket=bucket,
+                        epoch=attrs.get("epoch"))
+
         try:
             # the whole batch computation is inside the try: a bad
             # payload (mixed row shapes -> np.concatenate raises) must fail
@@ -357,34 +400,48 @@ class DynamicBatcher:
                 # submit and move on — the pool resolves the batch on
                 # its own worker and the completion callback scatters
                 # results, so the NEXT batch can flush (to another
-                # replica) while this one is still in flight
+                # replica) while this one is still in flight: the
+                # owner's turn ends at the submit
+                turn.mark("run")
                 fut = self._run_batch_async(rows)
                 with self._state_lock:
                     self._inflight += 1
                 fut.add_done_callback(
                     lambda f: self._complete(f, live, bucket, n, t0,
                                              waited))
+                end_turn()
                 return
-            rec = self._recorder if self._recorder is not None \
-                else obs_spans.get_recorder()
-            with rec.span(self._span_name, batcher=self.name,
-                          bucket=bucket, rows=n, **waited) as flush_span:
+            ran_from = turn.mark("run")
+            with obs_spans.annotation(self._span_name):
                 out = self._run_batch(rows)
         except Exception as exc:
+            failed = exc
+        ran_to = turn.mark("scatter")
+        if ran_from is not None:        # the executor ran, or failed
+            error = {} if failed is None else {"error": type(failed).__name__}
+            flush_span = rec.closed_span(
+                self._span_name, ran_from, ran_to, batcher=self.name,
+                bucket=bucket, rows=n, **waited, **error)
+        if failed is not None:
             # batch failure -> every caller sees the error (never a hang)
             for r in live:
-                r.future.set_exception(exc)
+                r.future.set_exception(failed)
+            turn.mark("account")
             self._m_batch_errors.inc()
-            return
-        self._scatter(live, out)
-        self._account_flush(bucket, n, flush_span["dur_ms"])
+        else:
+            self._scatter(live, out)
+            turn.mark("account")
+            self._account_flush(bucket, n, flush_span["dur_ms"])
+        end_turn()
 
     def _complete(self, f: Future, live: list[_Request], bucket: int,
                   n: int, t0: float, waited: dict) -> None:
         """Async-flush completion (runs on the pool's worker thread):
         scatter per-row results / the batch error, then the same
         accounting as a synchronous flush.  The timed record is an
-        ``event`` with ``dur_ms`` (a span cannot straddle threads)."""
+        ``event`` with ``dur_ms`` (a span cannot straddle threads); the
+        completion's ``scatter`` and ``account``, which the owner's turn
+        ended before, ride on it in wall and CPU time of this thread."""
         with self._state_lock:
             self._inflight -= 1
         self._wake()    # a lane is free: the owner may send the NEXT
@@ -395,13 +452,19 @@ class DynamicBatcher:
                 r.future.set_exception(exc)
             self._m_batch_errors.inc()
             return
+        t1, c1 = obs_spans.now(), time.thread_time()
         self._scatter(live, out)
+        t2, c2 = obs_spans.now(), time.thread_time()
         dur_ms = round((time.monotonic() - t0) * 1e3, 4)
+        self._account_flush(bucket, n, dur_ms)
+        t3, c3 = obs_spans.now(), time.thread_time()
         rec = self._recorder if self._recorder is not None \
             else obs_spans.get_recorder()
+        took = {name: round((b - a) * 1e3, 4) for name, a, b in (
+            ("scatter_ms", t1, t2), ("scatter_cpu_ms", c1, c2),
+            ("account_ms", t2, t3), ("account_cpu_ms", c2, c3))}
         rec.event(self._span_name, batcher=self.name, bucket=bucket,
-                  rows=n, dur_ms=dur_ms, **waited)
-        self._account_flush(bucket, n, dur_ms)
+                  rows=n, dur_ms=dur_ms, **took, **waited)
 
     def _scatter(self, live: list[_Request], out) -> None:
         """Each request its share of what the batch returned, in the

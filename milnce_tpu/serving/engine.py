@@ -37,6 +37,7 @@ at construction, optionally cast to bf16 for MXU-rate inference.
 from __future__ import annotations
 
 import contextlib
+import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -125,8 +126,11 @@ def device_dispatch(site: str, *, lock=None, recorder=None, **attrs):
     inside it, and ONE ``dispatch`` span per hold on ``recorder``
     (default: the process recorder) carrying ``site``, the caller's
     ``attrs`` (``rows``, ``bucket``), ``lock_wait_ms`` (call to lock
-    acquired), ``hold_ms`` (acquired to released) and the legs the site
-    marks through the yielded :class:`_Hold`.  The record is written
+    acquired), ``hold_ms`` (acquired to released), ``cpu_ms`` (the
+    holder's ``time.thread_time`` over the hold: a hold far longer than
+    that and the program's device time waited for the interpreter inside
+    ``put`` / ``get``; one near it is Python) and the legs the site marks
+    through the yielded :class:`_Hold`.  The record is written
     after the release, so the lock still nests over nothing; clock reads
     only — no device sync is added (OBSERVABILITY.md).  In a profiler
     session the waiter shows as ``<site>.lock_wait`` and the holder as
@@ -139,13 +143,14 @@ def device_dispatch(site: str, *, lock=None, recorder=None, **attrs):
         with obs_spans.annotation(f"{site}.lock_wait"):
             lock.acquire()
         record["lock_wait_ms"] = obs_spans.ms_since(t0)
-        t1 = obs_spans.now()
+        t1, c1 = obs_spans.now(), time.thread_time()
         try:
             with jax.transfer_guard("disallow"):
                 yield _Hold(site, record)
         finally:
             lock.release()
             record["hold_ms"] = obs_spans.ms_since(t1)
+            record["cpu_ms"] = round((time.thread_time() - c1) * 1e3, 4)
 
 
 def bucket_ladder(n_dev: int, min_bucket: int, max_batch: int) -> tuple:

@@ -506,10 +506,10 @@ class RetrievalService:
         # in the same process — must divert this service's spans and the
         # ``/obs/events`` ring together, never split them
         self._recorder = recorder
-        # build_server's collector-pause hook (obs/spans.py
-        # GcPauseEvents), removed by close_server; None when the service
-        # was built by hand
-        self.gc_pauses = None
+        # build_server's runtime watcher (obs/spans.py RuntimeWatch:
+        # collector pauses, the interpreter's latency, stalls), removed
+        # by close_server; None when the service was built by hand
+        self.runtime_watch = None
         # admission controller (the bounded global queue + feasibility
         # shed): max_inflight=0 keeps the overload bound off but the
         # controller still meters in-flight rows for /healthz
@@ -569,7 +569,7 @@ class RetrievalService:
                 default_timeout_ms=default_timeout_ms, name="topk",
                 registry=self.registry, recorder=recorder, pad=False,
                 take=lambda out, at: (out[0][at], out[1][at], out[2]),
-                span_name="topk.flush")
+                span_name="topk.flush", turns=self._batcher.turns)
         self._default_timeout_ms = float(default_timeout_ms)
         self._m_degraded = self.registry.counter(
             "milnce_serve_degraded_total",
@@ -623,26 +623,35 @@ class RetrievalService:
         pass that the flush immediately before it embedded.  Over a pool
         a flush returns when its batch is submitted and this loop goes
         on, so the same counter gives the rows embedded since the
-        worker's latest turn (a flush sent or a pass run)."""
+        worker's latest turn (a flush sent or a pass run).
+
+        One ``worker.turn`` record a flush or pass, on the two batchers'
+        one phase clock (batcher.py): this thread's time is tiled by
+        ``sleep`` (the wait below), ``take`` (the rest of this loop) and
+        the flush's own four phases, and ``epoch`` joins a turn to its
+        flush record."""
         text, scans = self._batcher, self._scans
+        turn = text.turns
         while True:
             self._wake.clear()
             stopping = self._stop.is_set()      # read AFTER the clear
             blocks = scans.take() if scans is not None else []
             if blocks:
                 epoch = self._epoch
-                scans.flush(blocks, chained_rows=sum(
+                scans.flush(blocks, epoch=epoch, chained_rows=sum(
                     b.future.fresh for b in blocks
                     if b.future.epoch == epoch))
                 self._epoch += 1        # a block held over rides unchained
             rows = text.take()
             if rows:
                 self._epoch += 1
-                text.flush(rows)
+                text.flush(rows, epoch=self._epoch)
             if stopping:                # closed batchers: the takes above
                 return                  # failed whatever waited
             if not (blocks or rows):
+                turn.mark("sleep")
                 self._wake.wait()
+                turn.mark("take")
 
     # ---- embedding path --------------------------------------------------
 
@@ -1231,7 +1240,7 @@ def build_server(cfg):
         registry=obs_metrics.registry(),
         capture=capture, anomaly_ratio=s.anomaly_ratio,
         max_inflight=s.max_inflight, tiers=s.tiers)
-    service.gc_pauses = obs_spans.GcPauseEvents().install()
+    service.runtime_watch = obs_spans.RuntimeWatch().install()
     return serve_http(service, s.host, s.port), service, index, engine
 
 
@@ -1241,9 +1250,9 @@ def close_server(cfg, server, service, index, engine) -> None:
     s = cfg.serve
     server.server_close()
     service.close()
-    if service.gc_pauses is not None:
-        service.gc_pauses.remove()
-        service.gc_pauses = None
+    if service.runtime_watch is not None:
+        service.runtime_watch.remove()
+        service.runtime_watch = None
     if s.live_index and index is not None:
         if s.index_snapshot_dir:
             # checkpoint the grown corpus so the next boot resumes

@@ -359,7 +359,7 @@ class TestSpans:
         assert rec.tail()[-1]["name"] == "ring_only_after_close"
 
 
-class TestGcPauseEvents:
+class TestRuntimeWatch:
     def test_only_a_long_collection_is_an_event(self, monkeypatch):
         """The hook itself, on a clock the test moves: 12 ms is an event
         with its generation, count and the pause's own end; 1 ms is
@@ -369,7 +369,7 @@ class TestGcPauseEvents:
         clock = [100.0]
         monkeypatch.setattr(spans, "_now", lambda: clock[0])
         rec = SpanRecorder()
-        pauses = spans.GcPauseEvents(rec)
+        pauses = spans.RuntimeWatch(rec)
         for dur_s, gen in ((0.012, 2), (0.001, 0)):
             pauses._hook("start", {"generation": gen})
             clock[0] += dur_s
@@ -390,7 +390,7 @@ class TestGcPauseEvents:
 
         rec = SpanRecorder()
         before = list(gc.callbacks)
-        pauses = spans.GcPauseEvents(rec).install()
+        pauses = spans.RuntimeWatch(rec).install()
         try:
             assert len(gc.callbacks) == len(before) + 1
             junk = []
@@ -419,6 +419,80 @@ class TestGcPauseEvents:
 # ---------------------------------------------------------------------------
 # obs_report: summaries + the CI regression gate
 # ---------------------------------------------------------------------------
+
+    def test_a_beat_a_second_says_how_late_the_watcher_woke(self):
+        from milnce_tpu.obs import spans
+
+        rec = SpanRecorder()
+        watch = spans.RuntimeWatch(rec).install()
+        try:
+            deadline = time.monotonic() + 5.0
+            while (len([e for e in rec.tail() if e["name"] == "runtime.beat"])
+                   < 2 and time.monotonic() < deadline):
+                time.sleep(0.05)
+        finally:
+            watch.remove()
+        beats = [e for e in rec.tail() if e["name"] == "runtime.beat"]
+        assert len(beats) >= 2 and beats[0]["kind"] == "event"
+        for e in beats[:2]:
+            # one wake-up every 20 ms, less what each came late
+            assert 10 <= e["beats"] <= 50 and 1000.0 <= e["dur_ms"] < 2000.0
+            assert 0 <= e["late_mean_ms"] <= e["late_max_ms"]
+            assert e["late_mean_ms"] * e["beats"] <= e["dur_ms"]
+            assert e["proc_cpu_ms"] >= 0
+        # a second apart, on the recorder's clock
+        assert 0.9 <= beats[1]["mono"] - beats[0]["mono"] <= 2.0
+        assert (spans.BEAT_S, spans.BEAT_REPORT_S) == (0.02, 1.0)
+
+    def test_one_late_wake_up_is_a_stall_written_at_once(self, monkeypatch):
+        """The recorder's clock jumps 80 ms while the watcher sleeps: it
+        woke that late, as far as it can tell."""
+        from milnce_tpu.obs import spans
+
+        jump = [0.0]
+        monkeypatch.setattr(spans, "_now",
+                            lambda: time.monotonic() + jump[0])
+        rec = SpanRecorder()
+        watch = spans.RuntimeWatch(rec).install()
+        try:
+            time.sleep(0.1)
+            assert not [e for e in rec.tail()
+                        if e["name"] == "runtime.stall"]
+            jump[0] = 0.08
+            deadline = time.monotonic() + 5.0
+            while (not [e for e in rec.tail() if e["name"] == "runtime.stall"]
+                   and time.monotonic() < deadline):
+                time.sleep(0.005)
+            (stall,) = [e for e in rec.tail() if e["name"] == "runtime.stall"]
+            deadline = time.monotonic() + 5.0
+            while (not [e for e in rec.tail() if e["name"] == "runtime.beat"]
+                   and time.monotonic() < deadline):
+                time.sleep(0.05)
+        finally:
+            watch.remove()
+        assert stall["kind"] == "event" and 60.0 <= stall["late_ms"] <= 140.0
+        assert stall["end_mono"] <= stall["mono"]
+        assert stall["proc_cpu_ms"] >= 0
+        if os.path.exists("/proc/thread-self/schedstat"):
+            assert 0 <= stall["runq_ms"] <= stall["late_ms"] + 20.0 + 1.0
+        # the beat of that second carries it as its latest wake-up
+        beat = [e for e in rec.tail() if e["name"] == "runtime.beat"][0]
+        assert beat["late_max_ms"] >= stall["late_ms"]
+        assert spans.STALL_MIN_MS == 50.0
+
+    def test_one_watcher_thread_and_none_after_remove(self):
+        from milnce_tpu.obs import spans
+
+        def watchers():
+            return [t for t in threading.enumerate()
+                    if t.name == "obs-runtime-watch"]
+
+        before = len(watchers())
+        watch = spans.RuntimeWatch(SpanRecorder()).install()
+        assert len(watchers()) == before + 1
+        watch.remove()
+        assert len(watchers()) == before
+
 
 def _run_report(*args):
     proc = subprocess.run([sys.executable, _OBS_REPORT, *args],

@@ -728,3 +728,77 @@ def test_stress_every_caller_gets_its_own_rows_back(made):
     health = svc.health()["scans"]
     assert health["requests"] == sum(answered)
     assert health["flushes"] == len(index.scans)
+
+
+# ---- (h) the device worker's turn, phase by phase (ISSUE 37) ----------------
+
+_PHASES = ("sleep", "take", "prepare", "run", "scatter", "account")
+# a kernel that accounts CPU time by the tick (the chip's host: 10 ms)
+# reads a thread's CPU up to one tick above its wall time
+_TICK_MS = 10.5
+
+
+def _named(ring, name):
+    return [r for r in ring.tail() if r["name"] == name]
+
+
+def test_a_miss_is_two_turns_of_the_worker_each_joined_to_its_flush(made):
+    svc, index, ring = made()
+    svc.query_ids(_rows(900))                 # a miss: flush, then pass
+    _wait_for(lambda: len(_named(ring, "worker.turn")) == 2, "two turns")
+    text, topk = _named(ring, "worker.turn")
+    assert (text["batcher"], topk["batcher"]) == ("text", "topk")
+    assert text["rows"] == topk["rows"] == 1
+    assert (text["bucket"], topk["bucket"]) == (_LADDER[0], _LADDER[0])
+    for turn in (text, topk):
+        assert {p + "_ms" for p in _PHASES} <= set(turn)
+        assert sum(turn[p + "_ms"] for p in _PHASES) == pytest.approx(
+            turn["dur_ms"], abs=1e-3)
+        for p in _PHASES[1:]:
+            assert 0 <= turn[p + "_cpu_ms"] <= turn[p + "_ms"] + _TICK_MS, p
+    assert text["sleep_ms"] > 0               # it slept until the submit
+    # a turn and its flush record join by (epoch, batcher), not by order
+    (flush,) = _named(ring, "batcher.flush")
+    (scan,) = _named(ring, "topk.flush")
+    assert flush["epoch"] == text["epoch"] and flush["dur_ms"] == text["run_ms"]
+    assert scan["epoch"] == topk["epoch"] and scan["dur_ms"] == topk["run_ms"]
+    assert scan["chained_rows"] == 1 and "chained_rows" not in topk
+    # the hand-over to the scan queue is the text turn's scatter: the pass
+    # found the row there
+    assert text["scatter_ms"] > 0 and text["mono"] < scan["mono"]
+
+
+def test_every_flush_record_has_exactly_one_turn_of_its_epoch_and_batcher(
+        made):
+    svc, index, ring = made()
+    callers = [_Caller(svc, _rows(950 + i % 7, n=1 + i % 3))
+               for i in range(24)]
+    for c in callers:
+        assert c.done().error is None, c.error
+    flushes = _named(ring, "batcher.flush") + _named(ring, "topk.flush")
+    _wait_for(lambda: len(_named(ring, "worker.turn")) == len(flushes),
+              "a turn a flush")
+    turns = [(t["epoch"], t["batcher"]) for t in _named(ring, "worker.turn")]
+    assert len(set(turns)) == len(turns)
+    assert sorted(turns) == sorted((f["epoch"], f["batcher"])
+                                   for f in flushes)
+    by_key = {(t["epoch"], t["batcher"]): t
+              for t in _named(ring, "worker.turn")}
+    for f in flushes:
+        assert by_key[f["epoch"], f["batcher"]]["rows"] == f["rows"]
+
+
+def test_the_workers_turns_account_for_its_time(made):
+    """Between the ends of the first and the last turn lies the sum of
+    the turns after the first (each record is written right after its
+    turn's last reading: a millisecond a record of slack)."""
+    svc, index, ring = made()
+    for i in range(12):
+        svc.query_ids(_rows(1000 + i))
+        time.sleep(0.003)                     # the worker sleeps between
+    _wait_for(lambda: len(_named(ring, "worker.turn")) == 24, "24 turns")
+    turns = _named(ring, "worker.turn")
+    covered = sum(t["dur_ms"] for t in turns[1:]) / 1e3
+    assert covered == pytest.approx(turns[-1]["mono"] - turns[0]["mono"],
+                                    abs=0.001 * len(turns))
+    assert sum(t["sleep_ms"] for t in turns[1:]) > 0

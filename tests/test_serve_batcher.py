@@ -674,3 +674,126 @@ def test_close_fails_the_queue_and_the_next_take_the_held_block():
         held.result(timeout=0)
     with pytest.raises(RuntimeError, match="closed"):
         b.submit(np.ones((3,), np.float32))
+
+
+# ---- the owner's turn, phase by phase (ISSUE 37) -----------------------------
+
+_PHASES = ("sleep", "take", "prepare", "run", "scatter", "account")
+# a kernel that accounts CPU time by the tick (the chip's host: 10 ms)
+# reads a thread's CPU up to one tick above its wall time
+_TICK_MS = 10.5
+
+
+def _turns(rec):
+    return [r for r in rec.tail() if r["name"] == "worker.turn"]
+
+
+def test_a_flush_is_one_turn_of_six_phases_that_sum_to_its_length():
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(delay_s=0.01), recorder=rec, name="text")
+    for r in _rows(3):
+        b.submit(r)
+    b.flush(b.take(), epoch=7)
+    b.close()
+    (turn,) = _turns(rec)
+    assert turn["kind"] == "event"
+    assert (turn["batcher"], turn["rows"], turn["bucket"], turn["epoch"]) \
+        == ("text", 3, 4, 7)
+    assert {p + "_ms" for p in _PHASES} <= set(turn)
+    assert {p + "_cpu_ms" for p in _PHASES if p != "sleep"} <= set(turn)
+    assert "sleep_cpu_ms" not in turn           # a wait: its CPU is not read
+    assert sum(turn[p + "_ms"] for p in _PHASES) == pytest.approx(
+        turn["dur_ms"], abs=1e-3)
+    assert turn["run_ms"] >= 10.0 > turn["run_cpu_ms"]      # it slept there
+    # the flush record is timed by the turn's own readings, and joins it
+    (flush,) = [r for r in rec.tail() if r["name"] == "batcher.flush"]
+    assert flush["kind"] == "span" and flush["dur_ms"] == turn["run_ms"]
+    assert flush["epoch"] == turn["epoch"] and flush["mono"] < turn["mono"]
+
+
+def test_consecutive_turns_leave_none_of_the_owners_time_uncovered(
+        monkeypatch):
+    """On a clock that moves 1 ms at every reading: from one turn's end
+    to the next one's lies exactly the next one's ``dur_ms`` — its
+    ``take`` begins where the last ``account`` ended, whatever the owner
+    does in between."""
+    clock = [50.0]
+
+    def tick():
+        clock[0] += 0.001
+        return clock[0]
+
+    monkeypatch.setattr(obs_spans, "_now", tick)
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(), recorder=rec)
+    for i in range(3):
+        b.submit(_rows(1)[0])
+        batch = b.take()
+        for _ in range(i):
+            assert b.take() == []               # an idle take or two
+        b.flush(batch)
+    b.close()
+    first, second, third = _turns(rec)
+    # ``mono`` is stamped one reading after the turn's last (the same
+    # offset on every record)
+    for before, turn in ((first, second), (second, third)):
+        assert turn["mono"] - before["mono"] == pytest.approx(
+            turn["dur_ms"] / 1e3, abs=1e-6)
+        assert turn["take_ms"] > 0 and turn["sleep_ms"] == 0
+
+
+def test_a_scatter_blocked_on_a_lock_shows_wall_far_above_cpu():
+    """A done-callback that waits for a lock held elsewhere: the worker
+    stands in ``scatter`` without running."""
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(), recorder=rec)
+    gate = threading.Lock()
+    gate.acquire()
+    fut = Future()
+    fut.add_done_callback(lambda f: (gate.acquire(), gate.release()))
+    b.submit(_rows(1)[0], future=fut)
+    threading.Timer(0.08, gate.release).start()
+    b.flush(b.take())
+    b.close()
+    (turn,) = _turns(rec)
+    assert turn["scatter_ms"] >= 60.0
+    assert turn["scatter_cpu_ms"] <= turn["scatter_ms"] / 4
+    for p in _PHASES[1:]:
+        assert turn[p + "_cpu_ms"] <= turn[p + "_ms"] + _TICK_MS, p
+
+
+def test_a_failed_batch_is_a_turn_too_and_the_flush_record_names_the_error():
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(fail=True), recorder=rec)
+    fut = b.submit(_rows(1)[0])
+    b.flush(b.take(), epoch=3)
+    b.close()
+    with pytest.raises(ValueError, match="injected"):
+        fut.result(timeout=0)
+    (turn,) = _turns(rec)
+    (flush,) = [r for r in rec.tail() if r["name"] == "batcher.flush"]
+    assert flush["error"] == "ValueError" and flush["epoch"] == 3
+    assert turn["epoch"] == 3 and turn["dur_ms"] > 0
+
+
+def test_a_pooled_flush_ends_the_turn_at_the_submit_and_the_completion_rides_its_event():
+    run_async, sent = _held_async()
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(), lanes=1, run_batch_async=run_async, recorder=rec)
+    futs = [b.submit(r) for r in _rows(2)]
+    b.flush(b.take(), epoch=1)
+    (turn,) = _turns(rec)               # written at the submit
+    assert turn["scatter_ms"] == 0 and turn["account_ms"] == 0
+    assert turn["run_ms"] > 0 and turn["epoch"] == 1
+    assert not [r for r in rec.tail() if r["name"] == "batcher.flush"]
+    fut, rows = sent[0]
+    fut.set_result(rows * 2.0)          # the "pool's worker": this thread
+    assert all(f.done() for f in futs)
+    (flush,) = [r for r in rec.tail() if r["name"] == "batcher.flush"]
+    assert flush["kind"] == "event" and flush["epoch"] == 1
+    for p in ("scatter", "account"):
+        assert 0 <= flush[p + "_cpu_ms"] <= flush[p + "_ms"] + _TICK_MS
+        assert flush[p + "_ms"] <= flush["dur_ms"]
+    assert flush["scatter_ms"] > 0
+    assert len(_turns(rec)) == 1        # the completion is no turn
+    b.close()

@@ -604,6 +604,11 @@ def _fresh_rows(seed, n=1):
         1, 64, (n, _WORDS)).astype(np.int32)
 
 
+# a kernel that accounts CPU time by the tick (the chip's host: 10 ms)
+# reads a thread's CPU up to one tick above its wall time
+_TICK_MS = 10.5
+
+
 class TestDispatchSpans:
     def _drive(self, stack, site):
         """One hold of the lock at ``site`` -> (rows, bucket) it carried."""
@@ -632,6 +637,31 @@ class TestDispatchSpans:
         legs = rec["put_ms"] + rec["call_ms"] + rec["get_ms"]
         assert 0 < legs <= rec["hold_ms"] + 0.01
 
+    @pytest.mark.parametrize("site", ["engine.text", "engine.video",
+                                      "index.topk"])
+    def test_a_hold_carries_its_holders_cpu_time(self, stack, ring, site):
+        """``cpu_ms``: the holder's thread's CPU time from acquire to
+        release, beside ``hold_ms`` (a hold far longer than that and the
+        program's device time waited for the interpreter)."""
+        self._drive(stack, site)
+        (rec,) = _named(ring, "dispatch")
+        assert 0 <= rec["cpu_ms"] <= rec["hold_ms"] + _TICK_MS
+
+    def test_a_waiting_hold_has_little_cpu_time(self):
+        """A holder that blocks inside its hold: wall, no CPU."""
+        import time
+
+        from milnce_tpu.analysis.lockrt import make_lock
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.engine import device_dispatch
+
+        rec = SpanRecorder()
+        with device_dispatch("index.upload", lock=make_lock("test.cpu"),
+                             recorder=rec):
+            time.sleep(0.05)
+        (r,) = _named(rec, "dispatch")
+        assert r["hold_ms"] >= 50.0 and r["cpu_ms"] <= 10.0
+
     def test_live_index_sites_upload_and_topk(self, stack, ring):
         from milnce_tpu.obs.spans import SpanRecorder
         from milnce_tpu.serving.live_index import LiveRetrievalIndex
@@ -642,6 +672,7 @@ class TestDispatchSpans:
         try:
             (up,) = _named(mine, "dispatch", site="index.upload")
             assert up["rows"] == _CORPUS and up["bucket"] >= 5
+            assert 0 <= up["cpu_ms"] <= up["hold_ms"] + _TICK_MS
             assert up["put_ms"] <= up["hold_ms"] + 0.01
             warm = len(_named(mine, "dispatch", site="index.topk"))
             assert warm == 1            # the one query bucket, warmed
@@ -980,6 +1011,114 @@ class TestBuiltServer:
                      for leg in ("lock_wait", "put", "call", "get")}
         assert want <= names, sorted(want - names)
 
+    def test_a_profiler_session_shows_the_workers_phases_on_its_thread(
+            self, served):
+        """No flag: the device worker's line on /host:CPU is tiled by
+        ``worker.<phase>``, the holds' legs nested inside ``worker.run``;
+        a caller's ``query`` lies on another line."""
+        import glob
+        import os
+
+        import jax
+
+        _server, service, _index, _engine = served["built"]
+        trace_dir = str(served["work"] / "trace_worker")
+        jax.profiler.start_trace(trace_dir)
+        try:
+            for seed in (78, 79):       # the first wakes the worker: its
+                rows = np.random.default_rng(seed).integers(    # sleep began
+                    1, 100, (1, served["cfg"].data.max_words)   # untraced
+                ).astype(np.int32)
+                service.query_ids(rows)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        lines = [[(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                  for ev in line.events]
+                 for plane in data.planes if plane.name == "/host:CPU"
+                 for line in plane.lines]
+        (worker,) = [ln for ln in lines
+                     if any(n == "worker.scatter" for n, _, _ in ln)]
+        names = {n for n, _, _ in worker}
+        assert {"worker.sleep", "worker.take", "worker.prepare",
+                "worker.run", "worker.scatter", "worker.account",
+                "batcher.flush", "topk.flush",
+                "engine.text.get", "index.topk.get"} <= names
+        assert "query" not in names         # the callers' line is another
+        runs = [(s, e) for n, s, e in worker if n == "worker.run"]
+        for n, s, e in worker:
+            if n in ("engine.text.get", "index.topk.get"):
+                assert any(rs <= s and e <= re_ for rs, re_ in runs), n
+        # the phases tile the line: no two of them overlap
+        phases = sorted((s, e) for n, s, e in worker
+                        if n.startswith("worker."))
+        for (_, e0), (s1, _) in zip(phases, phases[1:]):
+            assert e0 <= s1
+        # and nobody else's line carries them
+        assert sum(any(n.startswith("worker.") for n, _, _ in ln)
+                   for ln in lines) == 1
+
+    def test_the_idle_split_puts_every_idle_second_under_one_name(
+            self, served):
+        """``scripts/idle_by_worker_phase.py`` on a CPU trace of a few
+        served queries: the gaps, cut at the worker's phases' edges, add
+        up to the idle time, and the holds' legs are told from the rest
+        of ``worker.run``."""
+        import glob
+        import importlib.util
+        import os
+
+        import jax
+
+        from benchmarks import trace_reduce
+
+        spec = importlib.util.spec_from_file_location(
+            "idle_by_worker_phase", os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                "scripts", "idle_by_worker_phase.py"))
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+        _server, service, _index, _engine = served["built"]
+        trace_dir = str(served["work"] / "trace_split")
+        trace_reduce.start_trace(trace_dir)     # no Python frames: they
+        #                           would count as operations on the CPU
+        try:
+            service.query_ids(np.ones((1, served["cfg"].data.max_words),
+                                      np.int32))
+            with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN):
+                for seed in range(80, 86):
+                    service.query_ids(np.random.default_rng(seed).integers(
+                        1, 100, (1, served["cfg"].data.max_words)
+                    ).astype(np.int32))
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True)
+        split = tool.split_idle(path, trace_reduce.CPU_LAYOUT, trace_reduce)
+        assert 0 < split["idle_s"] < split["window_s"]
+        assert sum(split["by_phase"].values()) == pytest.approx(
+            split["idle_s"], rel=1e-6)
+        assert all(sec >= -1e-9 for sec in split["by_phase"].values())
+        names = set(split["by_phase"])
+        assert {"text.scatter", "topk.scatter", "sleep"} <= names
+        assert names & {"engine.text.put", "engine.text.get",
+                        "index.topk.put", "index.topk.get"}
+        assert not any(n.startswith("worker.") for n in names)
+
+    def test_one_watcher_thread_while_the_server_is_up(self, served):
+        import threading
+
+        _server, service, _index, _engine = served["built"]
+        assert not served["closed"]
+        watchers = [t for t in threading.enumerate()
+                    if t.name == "obs-runtime-watch"]
+        assert len(watchers) == 1 and watchers[0].daemon
+        assert service.runtime_watch._thread is watchers[0]
+        assert sum(t.name == "device-worker"
+                   for t in threading.enumerate()) >= 1
+
     def test_collector_pause_recorded_and_hook_gone_after_close(self,
                                                                  served):
         import gc
@@ -988,7 +1127,7 @@ class TestBuiltServer:
         from milnce_tpu.serving import service as serving
 
         _server, service, _index, _engine = served["built"]
-        assert service.gc_pauses is not None
+        assert service.runtime_watch is not None
         assert len(gc.callbacks) == len(served["hooks_before"]) + 1
         junk = []
         for _ in range(400_000):
@@ -1011,4 +1150,15 @@ class TestBuiltServer:
         serving.close_server(served["cfg"], *served["built"])
         served["closed"] = True
         assert gc.callbacks == served["hooks_before"]
-        assert service.gc_pauses is None
+        assert service.runtime_watch is None
+
+    def test_no_watcher_thread_after_close(self, served):
+        import threading
+
+        from milnce_tpu.serving import service as serving
+
+        if not served["closed"]:
+            serving.close_server(served["cfg"], *served["built"])
+            served["closed"] = True
+        assert not [t for t in threading.enumerate()
+                    if t.name == "obs-runtime-watch"]
