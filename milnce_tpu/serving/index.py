@@ -1,5 +1,6 @@
 """Device-resident retrieval index: corpus embeddings sharded over the
-mesh data axis, jitted dot-product + ``lax.top_k`` retrieval.
+mesh data axis, one jitted program that scans each shard and ranks it as
+it reads (``ops/scan_topk.py``), then merges the shards' winners.
 
 Offline eval materializes the full T x V similarity matrix on host
 (eval/retrieval.py) — fine for a 1k-video benchmark, hopeless for a
@@ -10,14 +11,15 @@ with only (Q, k) winners ever crossing back to host.
 The retrieval program (one jitted shard_map, fixed shapes, pinned
 collectives — see the ``serve_index_topk`` trace invariant):
 
-1. each shard scores the replicated query block against its local
-   corpus rows (one (Q, R_local) matmul — MXU work, embarrassingly
-   parallel);
-2. pad rows are masked to -inf and each shard takes a LOCAL top-k,
-   shifting to global row indices via ``axis_index`` — this is the
-   communication win: per shard only (Q, k) survives, not (Q, R_local);
-3. the per-shard candidate lists ride ONE all_gather each for scores
-   and indices (2 total, pinned), and a final top-k over the
+1. each shard streams its local corpus rows through VMEM a tile at a
+   time in ONE Pallas kernel (``ops/scan_topk.scan_topk``): the tile is
+   scored against the replicated query block on the MXU, pad rows are
+   masked to -inf, and each query keeps a running LOCAL top-k, ties to
+   the lower row — the (Q, R_local) scores never reach HBM; the local
+   rows are shifted to global ones via ``axis_index``, so per shard only
+   (Q, k) survives;
+2. the per-shard candidate lists ride ONE all_gather each for scores
+   and indices (2 total, pinned), and a final ``lax.top_k`` over the
    ``n_dev * k`` candidates is exact — every true global winner is
    necessarily some shard's local winner.
 
@@ -40,6 +42,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from milnce_tpu.analysis.lockrt import make_lock
 from milnce_tpu.obs import spans as obs_spans
+from milnce_tpu.ops.scan_topk import scan_topk
 from milnce_tpu.parallel.mesh import batch_sharding, replicated
 from milnce_tpu.serving.batcher import pad_rows
 from milnce_tpu.serving.engine import device_dispatch
@@ -47,20 +50,21 @@ from milnce_tpu.serving.engine import device_dispatch
 
 def make_topk_fn(mesh: Mesh, data_axis: str, k: int):
     """The jitted sharded top-k program (the ``serve_index_topk`` trace
-    invariant's subject): each data shard scores the replicated query
-    block against its local corpus rows, takes a LOCAL top-k, and the
-    per-shard (Q, k) candidate lists ride ONE all_gather each for scores
-    and indices before an exact global top-k.  Shared by the frozen
-    :class:`DeviceRetrievalIndex` and the generation-swapped
+    invariant's subject): each data shard scans its local corpus rows
+    against the replicated query block and keeps a LOCAL top-k in one
+    Pallas kernel (``ops/scan_topk.scan_topk``: the parent's masked
+    product + ``lax.top_k`` without the (Q, R_local) scores, ties to the
+    lower row), and the per-shard (Q, k) candidate lists ride ONE
+    all_gather each for scores and indices before an exact global top-k.
+    Shared by the frozen :class:`DeviceRetrievalIndex` and the
+    generation-swapped
     :class:`~milnce_tpu.serving.live_index.LiveRetrievalIndex` — one
     program, one set of pinned collectives, however the corpus is
-    managed."""
+    managed.  The program's name, ``local_topk``, is what the benchmark's
+    ``index_scan_roofline`` finds it by in a trace (``jit_local_topk``)."""
 
     def local_topk(corpus_l, valid_l, queries):
-        scores = queries @ corpus_l.T                    # (Q, R_local)
-        col = lax.iota(jnp.int32, corpus_l.shape[0])
-        scores = jnp.where(col[None, :] < valid_l[0], scores, -jnp.inf)
-        s, i = lax.top_k(scores, k)                      # local winners
+        s, i = scan_topk(queries, corpus_l, valid_l, k)  # local winners
         gidx = i + lax.axis_index(data_axis) * corpus_l.shape[0]
         s_all = lax.all_gather(s, data_axis, axis=1, tiled=True)
         i_all = lax.all_gather(gidx, data_axis, axis=1, tiled=True)
@@ -118,7 +122,7 @@ class DeviceRetrievalIndex:
         n_data = int(mesh.shape[data_axis])
 
         # Pad the corpus so rows split evenly AND every shard holds at
-        # least k rows (lax.top_k needs k <= local extent).
+        # least k rows (a shard's top-k needs k <= local extent).
         rows = max(-(-self.size // n_data), self.k)
         self._query_sh = replicated(mesh)
         self._fn = make_topk_fn(mesh, data_axis, self.k)

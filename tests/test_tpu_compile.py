@@ -240,6 +240,45 @@ def test_grouped_matmul_compiles_at_each_rungs_turn(one_chip, rows):
 
 
 # --------------------------------------------------------------------------
+# the index shard's scan + top-k at the cells' shards and rungs
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("queries,rows", [(64, 3_000_000), (16, 1_062_500),
+                                          (4, 1_062_500)])
+def test_scan_topk_compiles_at_the_cells_shapes(one_chip, queries, rows):
+    """``ops/scan_topk.py`` over the shards the cells scan (3 M rows on
+    ``query-text-c64``, 1,062,500 on the other three) at the top and
+    bottom rungs of both ladders: Mosaic takes the tile the rule gives
+    (8 MB of the index a step, double-buffered, ragged at the end) and
+    the program holds no (Q, rows) scores."""
+    from milnce_tpu.ops import scan_topk as st
+
+    compiled, text = _compile(
+        lambda q, c, v: st.scan_topk(q, c, v, 10),
+        _shape(one_chip, (queries, 512)), _shape(one_chip, (rows, 512)),
+        _shape(one_chip, (1,), jnp.int32))
+    assert "tpu_custom_call" in text and "scan_topk" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_top_k_program_ranks_without_a_topk_over_the_shard(topo):
+    """``make_topk_fn`` on one described chip at ``query-text-c64``'s
+    shape: the kernel is there, and neither the (64, 3 M) scores nor a
+    ``TopK`` over them is."""
+    from milnce_tpu.serving.index import make_topk_fn
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    rows_sh, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    text = make_topk_fn(mesh, "data", 10).lower(
+        jax.ShapeDtypeStruct((3_000_000, 512), jnp.float32, sharding=rows_sh),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=rows_sh),
+        jax.ShapeDtypeStruct((64, 512), jnp.float32, sharding=rep),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "scan_topk" in text
+    assert "3000000]" not in text.replace("f32[3000000,512]", "")
+
+
+# --------------------------------------------------------------------------
 # the language-model sentence tower at its published widths, top rung
 # --------------------------------------------------------------------------
 
