@@ -170,8 +170,9 @@ class SpanRecorder:
         the clock itself (``t0``, ``t1``: :func:`now` readings — the two
         ends of a :class:`PhaseClock` phase, so that the phase and the
         span are ONE pair of readings); ``ts`` is the wall clock at
-        ``t0``.  Returns the record."""
-        rec = {"kind": "span", "name": name, "ts": _wall() - (t1 - t0),
+        ``t0``, also where the record is written after ``t1``.  Returns
+        the record."""
+        rec = {"kind": "span", "name": name, "ts": _wall() - (_now() - t0),
                **attrs, "dur_ms": round((t1 - t0) * 1e3, 4)}
         self._record(rec)
         return rec
@@ -235,7 +236,14 @@ class PhaseClock:
     :func:`annotation` ``<prefix>.<phase>``, so that a profiler session
     shows the thread's line tiled by its phases on the device trace's
     clock.  Host time only, no lock: the owner's ONE thread marks it (the
-    first mark starts the clock, on the thread that makes it)."""
+    first mark starts the clock, on the thread that makes it).
+
+    A turn may end in two parts: :meth:`set_aside` takes what its phases
+    took so far out of the clock, unwritten, and :meth:`resume` charges
+    a later stretch of the thread's time to it (a flush whose scatter
+    runs inside the next program): its record is written by
+    :meth:`finish` once it is whole, and each instant still belongs to
+    exactly one record."""
 
     __slots__ = ("_names", "_record", "_rest", "_cpu_of", "_wall", "_cpu",
                  "_phase", "_t", "_c", "_bridge")
@@ -266,20 +274,48 @@ class PhaseClock:
         self._bridge.__enter__()
         return t
 
-    def finish(self, recorder: "SpanRecorder", **attrs) -> None:
+    def finish(self, recorder: "SpanRecorder", turn: Optional[tuple] = None,
+               **attrs) -> None:
         """The turn ends here: the current phase ends, ``rest`` begins,
         and the phases' times since the last record are written, with
-        ``attrs``, as one event on ``recorder``."""
-        self.mark(self._rest)
+        ``attrs``, as one event on ``recorder``.  ``turn``: one that
+        :meth:`set_aside` returned, written instead; the clock runs on."""
+        if turn is None:
+            turn, _ = self.set_aside()
+        wall, cpu = turn
         rec = dict(attrs)
-        for phase, s in self._wall.items():
+        for phase, s in wall.items():
             rec[phase + "_ms"] = round(s * 1e3, 4)
         for phase in self._cpu_of:
-            rec[phase + "_cpu_ms"] = round(self._cpu[phase] * 1e3, 4)
-        rec["dur_ms"] = round(sum(self._wall.values()) * 1e3, 4)
-        for phase in self._wall:
-            self._wall[phase] = self._cpu[phase] = 0.0
+            rec[phase + "_cpu_ms"] = round(cpu[phase] * 1e3, 4)
+        rec["dur_ms"] = round(sum(wall.values()) * 1e3, 4)
         recorder.event(self._record, **rec)
+
+    def set_aside(self) -> tuple:
+        """The turn stops here, unwritten: the current phase ends,
+        ``rest`` begins, and what every phase took since the last record
+        leaves the clock -> ``(turn, the reading)``; ``turn`` is what
+        :meth:`resume` charges and :meth:`finish` writes."""
+        t = self.mark(self._rest)
+        wall, cpu = self._wall, self._cpu
+        self._wall = dict.fromkeys(wall, 0.0)
+        self._cpu = dict.fromkeys(cpu, 0.0)
+        return (wall, cpu), t
+
+    @contextlib.contextmanager
+    def resume(self, turn: tuple, phase: str):
+        """Inside: the thread's time goes to ``turn`` (set aside earlier),
+        from ``phase`` on — marks inside go to it too; after: the thread
+        is back in the phase it left, charged to the turn of the moment."""
+        was = self._phase
+        self.mark(phase)
+        mine = self._wall, self._cpu
+        self._wall, self._cpu = turn
+        try:
+            yield
+        finally:
+            self.mark(was)
+            self._wall, self._cpu = mine
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,16 @@ after its last phase, wall and CPU time a phase (OBSERVABILITY.md); the
 flush record's ``dur_ms`` IS the turn's ``run_ms`` (one pair of clock
 readings).  An owner of several batchers hands them one clock.
 
+**A held scatter** (``flush(hold=True)``): the batch runs and its
+results stay in hand, a :class:`HeldScatter` the owner runs later —
+inside the round trip of the next program it dispatches
+(``engine.defer``), so that the callers it answers run while the device
+computes.  The flush's turn is set aside when its ``run`` ends and gets
+its ``scatter`` and ``account`` where the held scatter runs; a program
+that carried one has that stretch taken out of its own ``run`` (its
+flush record's ``dur_ms`` keeps it, and its ``dispatch`` record names it
+``overlap_ms``).
+
 numpy-only on purpose: payloads and results are host arrays; every
 device interaction lives behind the injected ``run_batch`` callable.
 Thread safety: ``submit`` may be called from any number of threads; one
@@ -65,7 +75,7 @@ import collections
 import queue
 import threading
 import time
-from concurrent.futures import Future
+from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -361,11 +371,16 @@ class DynamicBatcher:
         self._m_expired.inc(len(batch) - len(live))
         return live
 
-    def flush(self, batch: list[_Request], **attrs) -> None:
+    def flush(self, batch: list[_Request], *, hold: bool = False,
+              **attrs) -> Optional["HeldScatter"]:
         """Run ``batch`` (what :meth:`take` handed the owner) and scatter
         what it returns; ``attrs`` join the flush record, and ``epoch``
         among them (the owner's count of its turns) the ``worker.turn``
-        record too, so that the two join by it."""
+        record too, so that the two join by it.  ``hold``: a batch that
+        ran is not scattered here — its :class:`HeldScatter` is returned
+        for the owner to run, or to hand to the next program it
+        dispatches; its turn and flush record are written when that ran
+        (``rode`` on the record: where).  Otherwise None."""
         turn = self.turns
         turn.mark("prepare")
         live = self._expire(batch)
@@ -416,7 +431,13 @@ class DynamicBatcher:
                 out = self._run_batch(rows)
         except Exception as exc:
             failed = exc
+        if hold and failed is None:
+            aside, ran_to = turn.set_aside()
+            return HeldScatter(self, live, out, aside, rec,
+                               (ran_from, ran_to), bucket, n, waited)
         ran_to = turn.mark("scatter")
+        if hold:
+            waited["rode"] = "none"
         if ran_from is not None:        # the executor ran, or failed
             error = {} if failed is None else {"error": type(failed).__name__}
             flush_span = rec.closed_span(
@@ -509,8 +530,6 @@ class DynamicBatcher:
 
     @staticmethod
     def _fail_closed(r: _Request) -> None:
-        from concurrent.futures import InvalidStateError
-
         try:
             r.future.set_exception(RuntimeError("batcher closed"))
         except InvalidStateError:
@@ -571,3 +590,44 @@ class DynamicBatcher:
             "batch_errors": int(self._m_batch_errors.value),
             "occupancy": occupancy,
         }
+
+
+class HeldScatter:
+    """A flush that ran and whose results wait to be handed back: what
+    ``flush(hold=True)`` returns.  Called as ``held(site)`` — by the
+    round trip of the next program it rides (``engine.defer``), ``site``
+    that program's, or by the owner between programs with ``""`` — it
+    scatters, accounts and writes the flush record (``rode``: the last
+    part of ``site``, ``"none"`` for ``""``) and the flush's
+    ``worker.turn``, whose ``scatter`` and ``account`` are this call's,
+    wherever it ran.  A scatter that raises fails the requests it had
+    not answered."""
+
+    __slots__ = ("_batcher", "_live", "_out", "_turn", "_rec", "_ran",
+                 "bucket", "rows", "_waited")
+
+    def __init__(self, batcher: DynamicBatcher, live: list, out, turn: tuple,
+                 rec, ran: tuple, bucket: int, rows: int, waited: dict):
+        self._batcher, self._live, self._out = batcher, live, out
+        self._turn, self._rec, self._ran = turn, rec, ran
+        self.bucket, self.rows, self._waited = bucket, rows, waited
+
+    def __call__(self, site: str = "") -> None:
+        b, clock = self._batcher, self._batcher.turns
+        with clock.resume(self._turn, "scatter"):
+            try:
+                b._scatter(self._live, self._out)
+            except Exception as exc:
+                for r in self._live:
+                    try:
+                        r.future.set_exception(exc)
+                    except InvalidStateError:
+                        pass            # answered before the scatter raised
+            clock.mark("account")
+            flush_span = self._rec.closed_span(
+                b._span_name, *self._ran, batcher=b.name, bucket=self.bucket,
+                rows=self.rows, **self._waited,
+                rode=site.rsplit(".", 1)[-1] if site else "none")
+            b._account_flush(self.bucket, self.rows, flush_span["dur_ms"])
+        clock.finish(self._rec, self._turn, batcher=b.name, rows=self.rows,
+                     bucket=self.bucket, epoch=self._waited.get("epoch"))

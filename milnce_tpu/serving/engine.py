@@ -37,6 +37,7 @@ at construction, optionally cast to bf16 for MXU-rate inference.
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from typing import Optional, Sequence
 
@@ -75,6 +76,30 @@ class ReplicaDead(RuntimeError):
 # its name is what exempts device work under it from graftlint GL012.
 DEVICE_DISPATCH_LOCK = make_lock("serving.device_dispatch")
 
+# Host work a thread hands to its next round trip (:func:`defer`): the
+# device worker's held scatter of a pass, run while the next program
+# computes.  Thread-local, so whatever stands between the worker and the
+# hold (a wrapper that replaces ``index.topk`` on the instance) carries
+# it without knowing.
+_DEFERRED = threading.local()
+
+
+def defer(work, rows: int) -> None:
+    """The calling thread's next :meth:`_Hold.round_trip` runs
+    ``work(site)`` between its program's enqueue and the blocking fetch
+    of its result; ``rows``: the rows it answers (``overlap_rows``).  One
+    at a time: the owner takes back what no round trip ran
+    (:func:`take_deferred`) and runs it itself."""
+    _DEFERRED.work = (work, int(rows))
+
+
+def take_deferred():
+    """-> ``(work, rows)`` that the calling thread deferred and no round
+    trip has run, now taken back; None when there is none."""
+    work = getattr(_DEFERRED, "work", None)
+    _DEFERRED.work = None
+    return work
+
 
 class _Hold:
     """What a site gets from :func:`device_dispatch`: :meth:`phase`
@@ -101,7 +126,12 @@ class _Hold:
         a phase: the explicit ``device_put`` of the host ``rows``, the
         jitted call's return (the enqueue), the blocking ``device_get``
         of its result.  Written out, not three :meth:`phase` blocks: this
-        is host time inside the hold, with the device idle."""
+        is host time inside the hold, with the device idle.
+
+        Between the enqueue and the fetch, the work this thread deferred
+        (:func:`defer`) runs, while the program computes: ``overlap_ms``
+        and ``overlap_rows`` on the record.  What it raises is its own
+        (``overlap_error``): the program it rode goes on."""
         site, now = self.site, obs_spans.now
         t0 = now()
         with obs_spans.annotation(site + ".put"):
@@ -110,13 +140,36 @@ class _Hold:
         with obs_spans.annotation(site + ".call"):
             out = fn(*resident, x)
         t2 = now()
+        deferred = take_deferred()
+        if deferred is not None:
+            work, answered = deferred
+            try:
+                work(site)
+            except Exception as exc:    # its callers' to see, not ours
+                self.record["overlap_error"] = type(exc).__name__
+            self.record["overlap_rows"] = answered
+        t3 = now()
         with obs_spans.annotation(site + ".get"):
             out = jax.device_get(out)
-        t3 = now()
+        t4 = now()
         self.record.update(put_ms=round((t1 - t0) * 1e3, 4),
                            call_ms=round((t2 - t1) * 1e3, 4),
-                           get_ms=round((t3 - t2) * 1e3, 4))
+                           get_ms=round((t4 - t3) * 1e3, 4))
+        if deferred is not None:
+            self.record["overlap_ms"] = round((t3 - t2) * 1e3, 4)
         return out
+
+    def device_time(self, fn, rows, sharding, *resident) -> float:
+        """``fn(*resident, rows)`` once, its input on the device first
+        and its output waited for there -> ms from the call to the
+        output's readiness: the program's device time and one enqueue
+        (``device_ms`` on the record).  Warm-up only: a compiled program,
+        no fetch."""
+        x = jax.block_until_ready(jax.device_put(rows, sharding))
+        t0 = obs_spans.now()
+        jax.block_until_ready(fn(*resident, x))
+        self.record["device_ms"] = took = obs_spans.ms_since(t0)
+        return took
 
 
 @contextlib.contextmanager
@@ -294,6 +347,9 @@ class InferenceEngine:
         self._baseline_cache: Optional[dict] = None
         self.embed_dim: Optional[int] = None   # known after the first call
         self._dead = False                     # guarded-by: _stats_lock
+        # rung -> the text program's device time, ms (warm-up; empty
+        # before): what the device worker's turn order compares
+        self.text_device_ms: dict[int, float] = {}
         if precompile:
             self.warmup()
 
@@ -405,12 +461,24 @@ class InferenceEngine:
         """Sweep BOTH entries over the full bucket ladder so every
         executable the engine will ever run exists before the first
         request, then snapshot the jit cache sizes — any later growth is
-        a recompile (:meth:`recompiles`)."""
-        with obs_spans.get_recorder().span("ladder.warmup",
-                                           buckets=list(self.buckets)):
+        a recompile (:meth:`recompiles`).  The text entry runs once more
+        a rung, timed on the device (:attr:`text_device_ms`, and
+        ``text_device_ms`` on the span)."""
+        with obs_spans.get_recorder().span(
+                "ladder.warmup", buckets=list(self.buckets)) as span:
             for b in self.buckets:
                 self.embed_text(np.zeros((b, self.text_words), np.int32))
                 self.embed_video(np.zeros((b,) + self.video_shape, np.uint8))
+            timed = {}
+            for b in self.buckets:
+                with device_dispatch("engine.text", lock=self._dispatch_lock,
+                                     rows=0, bucket=b) as hold:
+                    timed[b] = hold.device_time(
+                        self._text_fn,
+                        np.zeros((b, self.text_words), np.int32),
+                        self._batch_sh, self._variables)
+            span["text_device_ms"] = {str(b): ms for b, ms in timed.items()}
+        self.text_device_ms = timed
         baseline = self._cache_sizes()
         with self._stats_lock:
             self._baseline_cache = baseline

@@ -132,6 +132,9 @@ class DeviceRetrievalIndex:
         self._stats_lock = make_lock("serving.index.stats")
         self._calls = 0
         self._baseline_cache = None
+        # query rung -> the pass's device time, ms (warm-up; empty
+        # before): what the device worker's turn order compares
+        self.device_ms: dict[int, float] = {}
         # the boot log's split of the build (the live index records a
         # span of the same name per generation)
         with obs_spans.get_recorder().span("index.build",
@@ -200,8 +203,17 @@ class DeviceRetrievalIndex:
     # ---- warmup + observability -----------------------------------------
 
     def warmup(self) -> None:
+        """Every query rung compiled and run, then run once more timed on
+        the device (:attr:`device_ms`)."""
         for b in self.query_buckets:
             self.topk(np.zeros((b, self.dim), np.float32))
+        timed = {}
+        for b in self.query_buckets:
+            with device_dispatch("index.topk", rows=0, bucket=b) as hold:
+                timed[b] = hold.device_time(
+                    self._fn, np.zeros((b, self.dim), np.float32),
+                    self._query_sh, self._corpus, self._valid)
+        self.device_ms = timed
         size = getattr(self._fn, "_cache_size", None)
         baseline = int(size()) if size is not None else None
         with self._stats_lock:

@@ -98,6 +98,7 @@ from milnce_tpu.obs import spans as obs_spans
 from milnce_tpu.obs.anomaly import EwmaSpikeDetector
 from milnce_tpu.serving.batcher import DeadlineExpired, DynamicBatcher
 from milnce_tpu.serving.cache import EmbeddingLRUCache, token_key
+from milnce_tpu.serving.engine import defer, take_deferred
 from milnce_tpu.serving.pool import PoolSaturated, PoolUnavailable
 
 log = logging.getLogger(__name__)
@@ -570,6 +571,15 @@ class RetrievalService:
                 registry=self.registry, recorder=recorder, pad=False,
                 take=lambda out, at: (out[0][at], out[1][at], out[2]),
                 span_name="topk.flush", turns=self._batcher.turns)
+        # the two programs' device times by rung, measured at their
+        # warm-up (``engine.text_device_ms``, ``index.device_ms``): what
+        # decides which program carries a pass's held scatter.  None
+        # where either is unknown (a pool, the live index, a stub):
+        # today's order, every scatter at once
+        text_ms = getattr(engine, "text_device_ms", None)
+        pass_ms = getattr(index, "device_ms", None)
+        self._device_ms = ((text_ms, pass_ms) if text_ms and pass_ms
+                           else None)
         self._default_timeout_ms = float(default_timeout_ms)
         self._m_degraded = self.registry.counter(
             "milnce_serve_degraded_total",
@@ -625,6 +635,18 @@ class RetrievalService:
         on, so the same counter gives the rows embedded since the
         worker's latest turn (a flush sent or a pass run).
 
+        Where both programs' device times are known (``_device_ms``), a
+        pass's scatter is held and rides the NEXT program dispatched:
+        it runs inside that program's round trip, between its enqueue
+        and its fetch (``engine.defer``), so that the callers it answers
+        run while the device computes.  The next program is the text
+        flush of the rows that wait where that flush is the longer of
+        the two; otherwise the flush runs alone and the scatter rides
+        the pass after it, which ranks what the flush embedded.  With
+        nothing to dispatch the scatter runs at once: no answer waits
+        for traffic that may not come.  ``rode`` on the ``topk.flush``
+        record says which (``topk``, ``text``, ``none``).
+
         One ``worker.turn`` record a flush or pass, on the two batchers'
         one phase clock (batcher.py): this thread's time is tiled by
         ``sleep`` (the wait below), ``take`` (the rest of this loop) and
@@ -632,26 +654,59 @@ class RetrievalService:
         flush record."""
         text, scans = self._batcher, self._scans
         turn = text.turns
+        held = None                     # a pass's scatter, not yet run
         while True:
             self._wake.clear()
             stopping = self._stop.is_set()      # read AFTER the clear
             blocks = scans.take() if scans is not None else []
             if blocks:
                 epoch = self._epoch
-                scans.flush(blocks, epoch=epoch, chained_rows=sum(
-                    b.future.fresh for b in blocks
-                    if b.future.epoch == epoch))
+                held = self._carried(held, scans.flush, blocks,
+                                     hold=self._device_ms is not None,
+                                     epoch=epoch, chained_rows=sum(
+                                         b.future.fresh for b in blocks
+                                         if b.future.epoch == epoch))
                 self._epoch += 1        # a block held over rides unchained
+            elif held is not None:      # nothing to carry it
+                held("")
+                held = None
             rows = text.take()
             if rows:
                 self._epoch += 1
-                text.flush(rows, epoch=self._epoch)
+                if held is not None and self._flush_is_longer(rows, held):
+                    held = self._carried(held, text.flush, rows,
+                                         epoch=self._epoch)
+                else:
+                    text.flush(rows, epoch=self._epoch)
             if stopping:                # closed batchers: the takes above
-                return                  # failed whatever waited
+                if held is not None:    # failed whatever waited
+                    held("")
+                return
             if not (blocks or rows):
                 turn.mark("sleep")
                 self._wake.wait()
                 turn.mark("take")
+
+    @staticmethod
+    def _carried(held, flush, batch, **attrs):
+        """``flush(batch, **attrs)`` carrying ``held`` (None: nothing)
+        inside its round trip -> what it returns.  A program that failed
+        before its enqueue left ``held`` behind: it runs here, at once."""
+        if held is not None:
+            defer(held, held.rows)
+        out = flush(batch, **attrs)
+        left = take_deferred()
+        if left is not None:
+            left[0]("")
+        return out
+
+    def _flush_is_longer(self, rows: list, held) -> bool:
+        """Whether the text flush of ``rows`` takes the device longer
+        than the pass whose scatter is ``held``, by the warm-up's times
+        at their rungs."""
+        text_ms, pass_ms = self._device_ms
+        rung = self.engine.bucket_for(sum(r.rows for r in rows))
+        return text_ms[rung] >= pass_ms[held.bucket]
 
     # ---- embedding path --------------------------------------------------
 

@@ -525,8 +525,12 @@ def test_64_callers_of_misses_settle_into_two_cohorts():
     flush carries half the callers — where a pass that waits out the
     NEXT cohort's flush makes three."""
     ladder, n_callers, n_calls = (16, 32, 64), 64, 6
-    index = _Index(ladder)
     carried = {}                      # a query's token -> its flush
+    # the worker's takes of the text queue, counted as each returns, and
+    # the count at each pass: a caller answered by a pass sends again
+    # once the worker has come back to the text queue after it (what it
+    # does before a woken caller runs, unless the host is loaded)
+    taken, taken_at_pass = [0], []
 
     class LongFlush(_Engine):
         def embed_text(self, rows):
@@ -541,20 +545,41 @@ def test_64_callers_of_misses_settle_into_two_cohorts():
                 carried[int(r[0])] = flush_no
             expected = int(np.minimum(answered + 1, n_calls).sum())
             deadline = time.monotonic() + 10
-            while _to_embed(svc) < expected:
+            # every query sent so far is taken or IN the queue (a submit
+            # is counted before its row is put there)
+            while len(carried) + svc._batcher.depth() < expected:
                 assert time.monotonic() < deadline, "callers never came"
                 time.sleep(0.0005)
             return out
 
+    class CountedIndex(_Index):
+        def topk(self, q):
+            taken_at_pass.append(taken[0])
+            return super().topk(q)
+
+    index = CountedIndex(ladder)
     engine = LongFlush(index.order, ladder)
     svc = RetrievalService(engine, index, cache=EmbeddingLRUCache(0),
                            registry=obs_metrics.MetricsRegistry(),
                            recorder=obs_spans.SpanRecorder(ring=8192))
+    real_take = svc._batcher.take
+
+    def counted_take():
+        batch = real_take()
+        taken[0] += 1
+        return batch
+
+    svc._batcher.take = counted_take
     sent_at, errors = {}, []
 
     def loop(c):
         try:
             for i in range(n_calls):
+                if i:       # the take after the pass that answered it
+                    deadline = time.monotonic() + 10
+                    while taken[0] <= taken_at_pass[-1]:
+                        assert time.monotonic() < deadline, "no take"
+                        time.sleep(0.0005)
                 token = 1 + c * n_calls + i                 # unique, not 0
                 sent_at[token] = sum(k == "flush" for k, _ in index.order)
                 svc.query_ids(np.full((1, _WORDS), token, np.int32))
@@ -802,3 +827,314 @@ def test_the_workers_turns_account_for_its_time(made):
     assert covered == pytest.approx(turns[-1]["mono"] - turns[0]["mono"],
                                     abs=0.001 * len(turns))
     assert sum(t["sleep_ms"] for t in turns[1:]) > 0
+
+
+# ---- (i) a pass's scatter rides the next program -----------------------------
+
+class _Fetch:
+    """What a timed stub's program returns: ``jax.device_get`` calls
+    ``copy_to_host_async`` first — the stub's fetch, listed in ``order``
+    as ``("got flush" | "got pass", rows)`` and held there like a
+    device still computing — and then ``__array__``."""
+
+    def __init__(self, stub, kind, rows, value):
+        self.stub, self.kind, self.rows, self.value = stub, kind, rows, value
+
+    def copy_to_host_async(self):
+        self.stub.order.append(("got " + self.kind, self.rows))
+        self.stub._wait_here()
+        if self.stub.fail_fetch:
+            self.stub.fail_fetch -= 1
+            raise RuntimeError(f"{self.kind} fetch failed")
+
+    def __array__(self, dtype=None, copy=None):
+        return self.value
+
+
+class _Timed:
+    """A stub whose program runs through a hold's ``round_trip`` on ONE
+    lock shared with the other stub: the call lists ``(kind, rows)`` in
+    ``order`` (or raises, ``fail_call`` times), the fetch is held by
+    ``hold()``.  ``<kind>_ms`` a rung is what warm-up would have timed."""
+
+    fail_call = fail_fetch = 0
+
+    def _round_trip(self, site, kind, rows, value):
+        from milnce_tpu.serving.engine import device_dispatch
+
+        def call(x):
+            self.order.append((kind, rows))
+            if self.fail_call:
+                self.fail_call -= 1
+                raise RuntimeError(f"{kind} call failed")
+            return _Fetch(self, kind, rows, value)
+
+        with device_dispatch(site, lock=self.lock, recorder=self.ring,
+                             rows=rows) as hold:
+            return np.asarray(hold.round_trip(call, value, None))
+
+
+class _TimedEngine(_Timed, _Engine):
+    def __init__(self, order, ladder, ms, lock, ring):
+        _Engine.__init__(self, order, ladder)
+        self.text_device_ms = dict.fromkeys(ladder, ms)
+        self.lock, self.ring = lock, ring
+
+    def embed_text(self, rows):
+        return self._round_trip("engine.text", "flush",
+                                int(rows.any(axis=1).sum()),
+                                rows.astype(np.float32) @ self._w)
+
+
+class _TimedIndex(_Timed, _Index):
+    def __init__(self, ladder, ms, lock, ring):
+        _Index.__init__(self, ladder)
+        self.device_ms = dict.fromkeys(ladder, ms)
+        self.lock, self.ring = lock, ring
+
+    def topk(self, q):
+        self.scans.append(q.shape[0])
+        scores, idx = self.rank(q)
+        out = self._round_trip("index.topk", "pass", q.shape[0],
+                               np.concatenate([scores, idx], axis=1))
+        return out[:, :_K], out[:, _K:].astype(np.int32)
+
+
+@pytest.fixture
+def timed():
+    """-> make(text_ms, pass_ms) -> (service, index, ring): stubs whose
+    rungs have device times, so the service holds each pass's scatter."""
+    services = []
+
+    def make(text_ms, pass_ms):
+        ring = obs_spans.SpanRecorder(ring=4096)
+        lock = threading.Lock()
+        index = _TimedIndex(_LADDER, pass_ms, lock, ring)
+        engine = _TimedEngine(index.order, _LADDER, text_ms, lock, ring)
+        svc = RetrievalService(engine, index, recorder=ring,
+                               cache=EmbeddingLRUCache(64),
+                               registry=obs_metrics.MetricsRegistry())
+        services.append((svc, index))
+        return svc, index, ring
+
+    yield make
+    for svc, index in services:
+        for held in (index, svc.engine):
+            if held._gate is not None:
+                held.release()
+        svc.close()
+
+
+def _ranked(svc, index, rows):
+    """What these rows would be answered alone, off the stubs' order."""
+    return index.rank(rows.astype(np.float32) @ svc.engine._w)
+
+
+def _overlaps(ring, site):
+    return [r.get("overlap_rows", 0) for r in _named(ring, "dispatch")
+            if r["site"] == site]
+
+
+def test_a_lone_caller_is_answered_without_waiting_for_another_program(
+        timed):
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    c = _Caller(svc, _rows(4100))
+    assert c.done().error is None
+    np.testing.assert_array_equal(c.answer[1],
+                                  _ranked(svc, index, _rows(4100))[1])
+    assert index.order == [("flush", 1), ("got flush", 1), ("pass", 1),
+                           ("got pass", 1)]
+    (scan,) = _passes(ring)
+    assert scan["rode"] == "none"           # nothing to carry it: at once
+    assert _overlaps(ring, "index.topk") == _overlaps(ring, "engine.text") \
+        == [0]
+
+
+def test_a_short_flush_runs_alone_and_the_pass_after_it_carries(timed):
+    """The flush is the shorter program: the next pass is CALLED before
+    the previous pass's answers are set, and FETCHED after them."""
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    index.hold()
+    head = _Caller(svc, _rows(4200))
+    _wait_for(index.entered.is_set, "the head's pass at its fetch")
+    rest = [_Caller(svc, _rows(4201 + i)) for i in range(2)]
+    _wait_for(lambda: _to_embed(svc) == 3, "two rows behind the pass")
+    index.step()                # the head's pass is fetched, the next held
+    _wait_for(index.entered.is_set, "the next pass at its fetch")
+    assert head.done().error is None        # answered while it computes
+    assert index.order == [
+        ("flush", 1), ("got flush", 1), ("pass", 1), ("got pass", 1),
+        ("flush", 2), ("got flush", 2), ("pass", 2), ("got pass", 2)]
+    first = _passes(ring)[0]
+    assert (first["rows"], first["rode"]) == (1, "topk")
+    # its scatter ran after the short flush and inside the next pass's
+    # round trip, between the call and the fetch
+    (_, flush2) = _named(ring, "batcher.flush")
+    assert flush2["mono"] < first["mono"]
+    assert first["chained_rows"] == 1
+    index.release()
+    for c in rest:
+        assert c.done().error is None, c.error
+        np.testing.assert_array_equal(
+            c.answer[1], _ranked(svc, index, c.rows)[1])
+    assert _overlaps(ring, "index.topk") == [0, 1]
+    assert [r["rode"] for r in _passes(ring)] == ["topk", "none"]
+    assert [r["chained_rows"] for r in _passes(ring)] == [1, 2]
+    overlap = [r for r in _named(ring, "dispatch") if r.get("overlap_rows")]
+    assert overlap[0]["overlap_ms"] >= 0 and "overlap_error" not in overlap[0]
+
+
+def test_a_long_flush_carries_the_scatter_and_no_answer_waits_for_its_get(
+        timed):
+    svc, index, ring = timed(text_ms=40.0, pass_ms=5.0)
+    engine = svc.engine
+    index.hold()
+    head = _Caller(svc, _rows(4300))
+    _wait_for(index.entered.is_set, "the head's pass at its fetch")
+    rest = [_Caller(svc, _rows(4301 + i)) for i in range(3)]
+    _wait_for(lambda: _to_embed(svc) == 4, "three rows behind the pass")
+    engine.hold()
+    index.release()
+    _wait_for(engine.entered.is_set, "the next flush at its fetch")
+    assert head.done().error is None        # answered before that get
+    assert index.order[4:] == [("flush", 3), ("got flush", 3)]
+    first = _passes(ring)[0]
+    assert (first["rows"], first["rode"]) == (1, "text")
+    engine.release()
+    for c in rest:
+        assert c.done().error is None, c.error
+    assert _overlaps(ring, "engine.text") == [0, 1]
+    assert [r["rode"] for r in _passes(ring)] == ["text", "none"]
+    kinds = ",".join(k for k, _ in index.order if not k.startswith("got"))
+    assert kinds == "flush,pass,flush,pass"
+
+
+def test_a_next_program_that_fails_at_its_call_leaves_the_scatter_at_once(
+        timed):
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    index.hold()
+    head = _Caller(svc, _rows(4400))
+    _wait_for(index.entered.is_set, "the head's pass at its fetch")
+    rest = [_Caller(svc, _rows(4401 + i)) for i in range(2)]
+    _wait_for(lambda: _to_embed(svc) == 3, "two rows behind the pass")
+    index.fail_call = 1                     # the pass that would carry it
+    index.release()
+    assert head.done().error is None
+    for c in rest:
+        assert "pass call failed" in str(c.done().error)
+    # the failed pass's record is written first, then the head's, at once
+    failed, first = _passes(ring)
+    assert (first["rows"], first["rode"]) == (1, "none")
+    assert (failed["rows"], failed["error"]) == (2, "RuntimeError")
+    scores, idx = svc.query_ids(_rows(4401))          # the worker lives
+    np.testing.assert_array_equal(idx, _ranked(svc, index, _rows(4401))[1])
+
+
+def test_a_next_program_that_fails_at_its_fetch_answers_what_it_carried(
+        timed):
+    svc, index, ring = timed(text_ms=40.0, pass_ms=5.0)
+    index.hold()
+    head = _Caller(svc, _rows(4500))
+    _wait_for(index.entered.is_set, "the head's pass at its fetch")
+    rest = [_Caller(svc, _rows(4501 + i)) for i in range(2)]
+    _wait_for(lambda: _to_embed(svc) == 3, "two rows behind the pass")
+    svc.engine.fail_fetch = 1               # the flush that carries it
+    index.release()
+    assert head.done().error is None
+    for c in rest:
+        assert "flush fetch failed" in str(c.done().error)
+    assert _passes(ring)[0]["rode"] == "text"
+    assert svc.health()["batcher"]["batch_errors"] == 1
+
+
+def test_a_pass_that_fails_at_its_fetch_fails_its_own_callers(timed):
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    index.fail_fetch = 1
+    c = _Caller(svc, _rows(4600))
+    assert "pass fetch failed" in str(c.done().error)
+    (scan,) = _passes(ring)
+    assert scan["error"] == "RuntimeError" and scan["rode"] == "none"
+    assert svc.health()["scans"]["batch_errors"] == 1
+    assert svc.query_ids(_rows(4600))[1].shape == (1, _K)
+
+
+def test_close_answers_or_fails_every_caller_of_a_held_scatter(timed):
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    engine = svc.engine
+    index.hold()
+    head = _Caller(svc, _rows(4700))
+    _wait_for(index.entered.is_set, "the head's pass at its fetch")
+    rest = [_Caller(svc, _rows(4701 + i)) for i in range(2)]
+    _wait_for(lambda: _to_embed(svc) == 3, "two rows behind the pass")
+    engine.hold()
+    index.release()             # the head's scatter is held, the flush
+    _wait_for(engine.entered.is_set, "the short flush at its fetch")
+    closer = threading.Thread(target=svc.close, daemon=True)
+    closer.start()
+    _wait_for(lambda: svc._scans._closed.is_set(), "the queues closed")
+    engine.release()
+    closer.join(10)
+    assert not closer.is_alive()
+    assert head.done().error is None        # the held scatter ran
+    for c in rest:
+        c.done()
+        assert c.answer is not None or "closed" in str(c.error)
+    assert _passes(ring)[0]["rode"] == "none"
+
+
+def test_a_scatter_that_raises_fails_its_callers_and_not_the_carrier(
+        timed, monkeypatch):
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    scans, real = svc._scans, svc._scans._scatter
+    index.hold()
+    head = _Caller(svc, _rows(4800))
+    _wait_for(index.entered.is_set, "the head's pass at its fetch")
+    rest = [_Caller(svc, _rows(4801 + i)) for i in range(2)]
+    _wait_for(lambda: _to_embed(svc) == 3, "two rows behind the pass")
+
+    def raising_once(live, out):
+        monkeypatch.setattr(scans, "_scatter", real)
+        raise KeyError("scatter")
+
+    monkeypatch.setattr(scans, "_scatter", raising_once)
+    index.release()
+    assert isinstance(head.done().error, KeyError)
+    for c in rest:
+        assert c.done().error is None, c.error
+    assert _passes(ring)[0]["rode"] == "topk"
+
+
+def test_a_service_without_times_holds_no_scatter(made):
+    """Stubs that cannot give their rungs' device times: today's order,
+    and no pass record says where its scatter rode."""
+    svc, index, ring = made()
+    assert svc._device_ms is None
+    svc.query_ids(_rows(4900, n=2))
+    assert index.order == [("flush", 2), ("pass", 2)]
+    assert all("rode" not in r for r in _passes(ring))
+
+
+def test_the_worker_s_turns_tile_its_time_with_held_scatters(timed):
+    """One ``worker.turn`` a flush or pass, joined by epoch; a pass's
+    scatter counts in its own turn wherever it ran, and between the ends
+    of the first and the last record lies the sum of the others."""
+    svc, index, ring = timed(text_ms=0.2, pass_ms=8.0)
+    callers = [_Caller(svc, _rows(5000 + i % 9, n=1 + i % 2))
+               for i in range(24)]
+    for c in callers:
+        assert c.done().error is None, c.error
+    flushes = _named(ring, "batcher.flush") + _passes(ring)
+    _wait_for(lambda: len(_named(ring, "worker.turn")) == len(flushes),
+              "a turn a flush")
+    turns = _named(ring, "worker.turn")
+    assert sorted((t["epoch"], t["batcher"]) for t in turns) == sorted(
+        (f["epoch"], f["batcher"]) for f in flushes)
+    for t in turns:
+        assert sum(t[p + "_ms"] for p in _PHASES) == pytest.approx(
+            t["dur_ms"], abs=1e-3)
+    rode = [p for p in _passes(ring) if p["rode"] != "none"]
+    by_key = {(t["epoch"], t["batcher"]): t for t in turns}
+    assert all(by_key[p["epoch"], "topk"]["scatter_ms"] > 0 for p in rode)
+    covered = sum(t["dur_ms"] for t in turns[1:]) / 1e3
+    assert covered == pytest.approx(turns[-1]["mono"] - turns[0]["mono"],
+                                    abs=0.001 * len(turns))

@@ -797,3 +797,87 @@ def test_a_pooled_flush_ends_the_turn_at_the_submit_and_the_completion_rides_its
     assert flush["scatter_ms"] > 0
     assert len(_turns(rec)) == 1        # the completion is no turn
     b.close()
+
+
+# ---- a held scatter --------------------------------------------------------------
+
+def test_a_held_flush_scatters_inside_the_next_run_and_keeps_its_turn(
+        monkeypatch):
+    """``flush(hold=True)``: the batch runs, nothing is answered, no
+    record is written; called inside the next flush's executor, it
+    answers, and its turn gets that scatter out of the other's ``run``.
+    On a clock that moves 1 ms a reading the split is exact."""
+    clock = [50.0]
+
+    def tick():
+        clock[0] += 0.001
+        return clock[0]
+
+    monkeypatch.setattr(obs_spans, "_now", tick)
+    rec = obs_spans.SpanRecorder()
+    scans = _mk(_FakeEngine(), recorder=rec, name="topk",
+                span_name="topk.flush")
+    carried = []
+
+    class Carrier(_FakeEngine):
+        def __call__(self, rows):
+            out = super().__call__(rows)
+            carried.pop()("engine.text")    # what round_trip does
+            return out
+
+    text = _mk(Carrier(), recorder=rec, name="text", turns=scans.turns)
+    fut = scans.submit(_rows(1)[0])
+    held = scans.flush(scans.take(), hold=True, epoch=1)
+    assert (held.rows, held.bucket) == (1, 4) and not fut.done()
+    assert not rec.tail()                   # no turn, no flush record yet
+    carried.append(held)
+    other = text.submit(_rows(2)[1])
+    assert text.flush(text.take(), epoch=2) is None
+    np.testing.assert_array_equal(fut.result(timeout=0), _rows(1)[0] * 2)
+    assert other.done()
+    (scan,) = [r for r in rec.tail() if r["name"] == "topk.flush"]
+    (flush,) = [r for r in rec.tail() if r["name"] == "batcher.flush"]
+    assert scan["rode"] == "text" and scan["epoch"] == 1
+    assert "rode" not in flush
+    mine, its = _turns(rec)                 # the held turn is written first
+    assert (mine["batcher"], its["batcher"]) == ("topk", "text")
+    assert mine["scatter_ms"] > 0 and scan["dur_ms"] == mine["run_ms"]
+    # the text flush's record keeps the whole run; its turn gives the
+    # carried scatter and account to the turn they belong to
+    assert flush["dur_ms"] == pytest.approx(
+        its["run_ms"] + mine["scatter_ms"] + mine["account_ms"], abs=1e-3)
+    for turn in (mine, its):
+        assert sum(turn[p + "_ms"] for p in _PHASES) == pytest.approx(
+            turn["dur_ms"], abs=1e-3)
+    scans.close()
+    text.close()
+
+
+def test_a_held_flush_run_at_once_rode_nothing():
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(), recorder=rec, name="topk", span_name="topk.flush")
+    futs = [b.submit(r) for r in _rows(3)]
+    held = b.flush(b.take(), hold=True, epoch=4)
+    held("")
+    assert all(f.done() for f in futs)
+    (scan,) = [r for r in rec.tail() if r["name"] == "topk.flush"]
+    (turn,) = _turns(rec)
+    assert scan["rode"] == "none" and (scan["rows"], turn["rows"]) == (3, 3)
+    assert turn["epoch"] == 4 and turn["scatter_ms"] > 0
+    assert b.stats()["flushes"] == 1
+    b.close()
+
+
+def test_a_held_flush_that_fails_is_scattered_at_once():
+    """Nothing to hold: its callers see the error there and then."""
+    rec = obs_spans.SpanRecorder()
+    b = _mk(_FakeEngine(fail=True), recorder=rec, name="topk",
+            span_name="topk.flush")
+    fut = b.submit(_rows(1)[0])
+    assert b.flush(b.take(), hold=True, epoch=2) is None
+    with pytest.raises(ValueError, match="injected"):
+        fut.result(timeout=0)
+    (scan,) = [r for r in rec.tail() if r["name"] == "topk.flush"]
+    assert scan["error"] == "ValueError" and scan["rode"] == "none"
+    assert len(_turns(rec)) == 1
+    b.close()

@@ -688,28 +688,57 @@ class TestDispatchSpans:
 
     def test_second_contender_waits_out_the_first_hold(self):
         """Two threads ask for one lock while a third holds it: whoever
-        gets it second waited at least as long as the first held it."""
+        gets it second waited at least as long as the first held it.
+        The lock says who waits in it, so the holder lets go only once
+        both do; it lets in the one that asked LAST first, so that the
+        first to get it did not start asking later than the other."""
         import time
 
         from milnce_tpu.obs.spans import SpanRecorder
         from milnce_tpu.serving.engine import device_dispatch
 
-        rec, lock = SpanRecorder(), threading.Lock()
-        asking = threading.Barrier(3)
+        class NewestFirst:
+            """A lock that counts who waits in ``acquire`` and lets the
+            newest of them in first."""
+
+            def __init__(self):
+                self._cv = threading.Condition()
+                self.waiting, self._held = [], False
+
+            def acquire(self):
+                me = object()
+                with self._cv:
+                    self.waiting.append(me)
+                    self._cv.notify_all()
+                    self._cv.wait_for(lambda: not self._held
+                                      and self.waiting[-1] is me)
+                    self.waiting.remove(me)
+                    self._held = True
+
+            def release(self):
+                with self._cv:
+                    self._held = False
+                    self._cv.notify_all()
+
+            def asking(self, n):
+                with self._cv:
+                    assert self._cv.wait_for(
+                        lambda: len(self.waiting) == n, timeout=10)
+
+        rec, lock = SpanRecorder(), NewestFirst()
 
         def contender(i):
-            asking.wait(timeout=10)
             with device_dispatch("test.site", lock=lock, recorder=rec,
                                  rows=i):
                 time.sleep(0.03)
 
         threads = [threading.Thread(target=contender, args=(i,))
                    for i in range(2)]
-        with lock:
-            for t in threads:
-                t.start()
-            asking.wait(timeout=10)
-            time.sleep(0.05)            # both are inside acquire() now
+        lock.acquire()
+        for n, t in enumerate(threads, 1):
+            t.start()
+            lock.asking(n)              # inside acquire() now, seen
+        lock.release()
         for t in threads:
             t.join(timeout=10)
             assert not t.is_alive()
@@ -731,6 +760,117 @@ class TestDispatchSpans:
         (r,) = _named(rec, "dispatch")
         assert r["error"] == "KeyError" and "hold_ms" in r
         assert not lock.locked()
+
+    # ---- deferred host work inside a hold ------------------------------
+
+    @staticmethod
+    def _program(order, value):
+        """A program for ``round_trip`` that lists its call and its fetch
+        (``jax.device_get`` calls ``copy_to_host_async`` first)."""
+
+        class Fetch:
+            def copy_to_host_async(self):
+                order.append("get")
+
+            def __array__(self, dtype=None, copy=None):
+                return value
+
+        def call(x):
+            order.append("call")
+            return Fetch()
+
+        return call
+
+    def test_deferred_work_runs_between_the_call_and_the_get(self):
+        import time
+
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.engine import (defer, device_dispatch,
+                                               take_deferred)
+
+        rec, lock, order = SpanRecorder(), threading.Lock(), []
+
+        def work(site):
+            order.append(("work", site))
+            time.sleep(0.02)
+
+        rows = np.arange(6, dtype=np.float32)
+        defer(work, 3)
+        with device_dispatch("index.topk", lock=lock, recorder=rec) as hold:
+            out = hold.round_trip(self._program(order, rows * 2), rows,
+                                  None)
+        np.testing.assert_array_equal(out, rows * 2)
+        assert order == ["call", ("work", "index.topk"), "get"]
+        (r,) = _named(rec, "dispatch")
+        assert r["overlap_rows"] == 3 and r["overlap_ms"] >= 20.0
+        assert "overlap_error" not in r
+        # the legs and the overlap lie inside the hold, one after another
+        legs = r["put_ms"] + r["call_ms"] + r["overlap_ms"] + r["get_ms"]
+        assert legs <= r["hold_ms"] + 0.01
+        assert r["call_ms"] < 20.0 and r["get_ms"] < 20.0
+        assert take_deferred() is None          # it ran once, and is gone
+        with device_dispatch("index.topk", lock=lock, recorder=rec) as hold:
+            hold.round_trip(self._program(order, rows), rows, None)
+        assert "overlap_ms" not in _named(rec, "dispatch")[-1]
+
+    def test_deferred_work_that_raises_fails_neither_the_hold_nor_its_lock(
+            self):
+        from milnce_tpu.obs.spans import SpanRecorder
+        from milnce_tpu.serving.engine import defer, device_dispatch
+
+        rec, lock, order = SpanRecorder(), threading.Lock(), []
+
+        def work(site):
+            raise KeyError("its callers' to see")
+
+        rows = np.ones(4, np.float32)
+        defer(work, 2)
+        with device_dispatch("engine.text", lock=lock, recorder=rec) as hold:
+            out = hold.round_trip(self._program(order, rows), rows, None)
+        np.testing.assert_array_equal(out, rows)
+        assert order == ["call", "get"] and not lock.locked()
+        (r,) = _named(rec, "dispatch")
+        assert r["overlap_error"] == "KeyError" and r["overlap_rows"] == 2
+        assert "error" not in r and "get_ms" in r
+
+    def test_a_wrapper_that_replaces_index_topk_still_carries_it(self, stack,
+                                                                ring):
+        """The deferred work is the calling thread's: a one-argument
+        wrapper on the instance (the benchmark's traced-run annotation
+        and fault hook do this) passes it on without knowing."""
+        import jax
+
+        from milnce_tpu.serving.engine import defer, take_deferred
+
+        index, ran = stack["index"], []
+        real = index.topk
+
+        def wrapped(queries):
+            with jax.profiler.TraceAnnotation("index.topk"):
+                return real(queries)
+
+        index.topk = wrapped
+        try:
+            defer(ran.append, 2)
+            scores, idx = index.topk(stack["corpus_emb"][:2])
+        finally:
+            del index.topk                      # the class's method again
+        assert ran == ["index.topk"] and take_deferred() is None
+        assert idx.shape == (2, 5)
+        (r,) = _named(ring, "dispatch", site="index.topk")
+        assert r["overlap_rows"] == 2 and r["overlap_ms"] >= 0
+
+    def test_warm_up_times_every_rung_of_both_programs(self, stack):
+        """What the device worker's order compares: the text program's
+        and the pass's device time at every rung, from warm-up."""
+        engine, index = stack["engine"], stack["index"]
+        assert sorted(engine.text_device_ms) == list(engine.buckets)
+        assert sorted(index.device_ms) == list(index.query_buckets)
+        assert all(ms > 0 for ms in engine.text_device_ms.values())
+        assert all(ms > 0 for ms in index.device_ms.values())
+        assert stack["service"]._device_ms == (engine.text_device_ms,
+                                               index.device_ms)
+        assert engine.recompiles() == 0 and index.recompiles() == 0
 
     def test_the_lock_is_taken_only_through_device_dispatch(self):
         """A grep: under milnce_tpu/serving/ nothing enters a dispatch
