@@ -164,16 +164,38 @@ class TextHybridConfig:
     ``num_hidden_layers``), plus the chip's share of each expert layer:
     ``experts_held`` experts from ``first_expert`` on (all
     ``num_local_experts`` from 0: the whole layer).  Defaults: a small
-    model of the same shape, for tests."""
+    model of the same shape, for tests.
+
+    A third kind, ``kda`` (Kimi Delta Attention: the gated delta rule with
+    a decay per channel), reads the ``kda_*`` keys; ``use_gqa_gate``,
+    ``head_dim`` and ``scoring_func`` (with ``norm_topk_prob`` and
+    ``routed_scaling_factor``) reshape the attention layer and the router.
+    Every one of them defaults to the granite family's layers."""
 
     hidden_size: int = 64
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
+    head_dim: int = 0                   # 0: hidden_size / num_attention_heads
+    use_gqa_gate: bool = False          # attention out x sigmoid(W_g x)
     layer_types: str = "mamba,attention,mamba"
     intermediate_size: int = 32         # one routed expert's width
     shared_intermediate_size: int = 48
     num_local_experts: int = 8
     num_experts_per_tok: int = 3
+    scoring_func: str = "softmax"       # 'softmax': over the top-k logits
+    #                                     | 'sigmoid': text_lm.route
+    norm_topk_prob: bool = True         # 'sigmoid' only
+    routed_scaling_factor: float = 1.0  # 'sigmoid' only
+    kda_num_heads: int = 4
+    kda_head_dim: int = 16
+    kda_num_kv_heads: int = 0           # 0: the published null (a key and
+    #                                     a value a head)
+    kda_short_conv_kernel_size: int = 4
+    kda_proj_rank: int = 16             # the decay's and the gate's
+    #                                     low-rank pairs
+    kda_use_full_proj: bool = False     # False only
+    kda_allow_neg_eigval: bool = True   # beta in (0, 2): True only
+    kda_chunk_size: int = 8
     mamba_n_heads: int = 8
     mamba_d_head: int = 16
     mamba_d_state: int = 16

@@ -1,8 +1,9 @@
 """Sentence tower from a hybrid causal language model: token table ->
-layers by a list of kinds (``layer_types``) — a Mamba-2 mixer or a
-grouped-query attention without any position encoding, then routed experts
-beside a shared MLP, in EVERY layer — -> RMSNorm at each row's last real
-token -> projection into the joint space.  The keys of
+layers by a list of kinds (``layer_types``) — a Mamba-2 mixer, Kimi Delta
+Attention (the gated delta rule, ``kda``) or a grouped-query attention
+without any position encoding, then routed experts beside a shared MLP, in
+EVERY layer — -> RMSNorm at each row's last real token -> projection into
+the joint space.  The keys of
 :class:`milnce_tpu.config.TextHybridConfig` carry their published names (a
 ``granitemoehybrid`` ``config.json``), the family's three multipliers among
 them: the table's rows times ``embedding_multiplier``, every sublayer's
@@ -10,8 +11,10 @@ output times ``residual_multiplier`` into the residual stream, the
 attention's scores times ``attention_multiplier``.
 
 A layer: ``x += r Mixer(RMS(x))``, ``h = RMS(x)``, ``x += r (Shared(h) +
-Routed(h))``.  The router takes the ``num_experts_per_tok`` largest LOGITS
-and soft-maxes over those alone.
+Routed(h))``.  The router (``scoring_func``) takes the
+``num_experts_per_tok`` largest LOGITS and soft-maxes over those alone
+(``softmax``, the granite family's), or is ``text_lm.route`` (``sigmoid``:
+sigmoid scores, the top k normalised over themselves and scaled).
 
 Ids, pads and the chip's share of an expert layer are ``text_lm.py``'s
 (0 is the pad, real ids first; ``(first_expert, experts_held)``), and so
@@ -22,7 +25,9 @@ never reads another.
 
 Counters (``COUNTER_NAMES``): ``text_lm``'s four and, over the Mamba
 layers, ``ssm_chunks_run`` (row x chunk blocks the scan computed) and
-``ssm_chunks_real`` (those that held a real token).
+``ssm_chunks_real`` (those that held a real token); a tower with ``kda``
+layers adds the same pair over them, ``kda_chunks_run`` /
+``kda_chunks_real`` (:func:`counter_names`).
 """
 
 from __future__ import annotations
@@ -40,10 +45,14 @@ from milnce_tpu.models import text_lm
 from milnce_tpu.models.text_lm import (COUNTERS, ROUTING, DenseMLP, RMSNorm,
                                        _dot, _fan_in_normal,
                                        attention_mask, held_expert_sum)
+from milnce_tpu.ops.kda import SUB as KDA_SUB
+from milnce_tpu.ops.kda import kda_chunked
 from milnce_tpu.ops.ssd import ssd_scan
 
 COUNTER_NAMES = text_lm.COUNTER_NAMES + ("ssm_chunks_run", "ssm_chunks_real")
-LAYER_KINDS = ("mamba", "attention")
+KDA_COUNTER_NAMES = ("kda_chunks_run", "kda_chunks_real")
+LAYER_KINDS = ("mamba", "attention", "kda")
+ROUTERS = ("softmax", "sigmoid")
 
 # ``TextHybridConfig`` frozen, ``layer_types`` a tuple of the layers built
 HybridDims = dataclasses.make_dataclass(
@@ -77,19 +86,37 @@ def hybrid_dims(cfg: TextHybridConfig):
     if d.hidden_act != "silu":
         refuse(f"hidden_act={d.hidden_act!r}: the tower implements 'silu' "
                "only")
-    if d.mamba_n_groups != 1:
-        refuse(f"mamba_n_groups={d.mamba_n_groups}: the tower implements 1 "
-               "only (B and C shared by all heads)")
-    if d.mamba_proj_bias:
-        refuse("mamba_proj_bias=True: the tower's projections have no bias")
-    if d.mamba_n_heads * d.mamba_d_head != d.mamba_expand * d.hidden_size:
-        refuse(f"mamba_n_heads x mamba_d_head = "
-               f"{d.mamba_n_heads * d.mamba_d_head} is not mamba_expand x "
-               f"hidden_size = {d.mamba_expand * d.hidden_size}")
-    if (d.hidden_size % d.num_attention_heads
+    if d.scoring_func not in ROUTERS:
+        refuse(f"scoring_func={d.scoring_func!r}: the tower implements "
+               f"{ROUTERS} only")
+    if "mamba" in kinds[:d.num_hidden_layers]:
+        if d.mamba_n_groups != 1:
+            refuse(f"mamba_n_groups={d.mamba_n_groups}: the tower implements "
+                   "1 only (B and C shared by all heads)")
+        if d.mamba_proj_bias:
+            refuse("mamba_proj_bias=True: the tower's projections have no "
+                   "bias")
+        if d.mamba_n_heads * d.mamba_d_head != d.mamba_expand * d.hidden_size:
+            refuse(f"mamba_n_heads x mamba_d_head = "
+                   f"{d.mamba_n_heads * d.mamba_d_head} is not mamba_expand x "
+                   f"hidden_size = {d.mamba_expand * d.hidden_size}")
+    if ((not d.head_dim and d.hidden_size % d.num_attention_heads)
             or d.num_attention_heads % d.num_key_value_heads):
-        refuse("num_attention_heads must divide hidden_size, and "
-               "num_key_value_heads num_attention_heads")
+        refuse("num_attention_heads must divide hidden_size (or head_dim be "
+               "given), and num_key_value_heads num_attention_heads")
+    if "kda" in kinds[:d.num_hidden_layers]:
+        if d.kda_use_full_proj:
+            refuse("kda_use_full_proj=True: the tower implements the "
+                   "low-rank decay and gate projections only")
+        if not d.kda_allow_neg_eigval:
+            refuse("kda_allow_neg_eigval=False: the tower implements beta "
+                   "in (0, 2) only")
+        if d.kda_num_kv_heads:
+            refuse(f"kda_num_kv_heads={d.kda_num_kv_heads}: the tower "
+                   "implements null only (a key and a value a head)")
+        if d.kda_chunk_size % min(KDA_SUB, d.kda_chunk_size):
+            refuse(f"kda_chunk_size={d.kda_chunk_size} is no multiple of "
+                   f"the scan's sub-block of {KDA_SUB}")
     if not (0 <= d.first_expert and d.experts_held >= 1
             and d.first_expert + d.experts_held <= d.num_local_experts):
         refuse(f"experts [{d.first_expert}, {d.first_expert} + "
@@ -98,6 +125,14 @@ def hybrid_dims(cfg: TextHybridConfig):
     if d.num_experts_per_tok > d.num_local_experts:
         refuse("num_experts_per_tok exceeds num_local_experts")
     return d
+
+
+def counter_names(dims) -> tuple:
+    """The names of the counters a tower of ``dims`` sows, in order:
+    :data:`COUNTER_NAMES`, and the ``kda`` pair where it has such a
+    layer."""
+    return COUNTER_NAMES + (KDA_COUNTER_NAMES if "kda" in dims.layer_types
+                            else ())
 
 
 # ---- pieces --------------------------------------------------------------
@@ -159,9 +194,12 @@ class Mamba2Mixer(nn.Module):
 
 class GQA(nn.Module):
     """Grouped-query attention over one row's positions with no position
-    term: ``num_key_value_heads`` key/value heads, each serving
-    ``num_attention_heads / num_key_value_heads`` query heads; scores
-    times ``attention_multiplier``; no cache."""
+    term: ``num_key_value_heads`` key/value heads of ``head_dim`` (0:
+    hidden / heads), each serving ``num_attention_heads /
+    num_key_value_heads`` query heads; scores times
+    ``attention_multiplier``; with ``use_gqa_gate`` the heads' outputs
+    times ``sigmoid(h W_g)``, element by element, before ``wo``; no
+    cache."""
     dims: Any
     dtype: Any = jnp.float32
 
@@ -170,7 +208,7 @@ class GQA(nn.Module):
         """h (B, S, hidden), visible (B, S, S) bool -> (B, S, hidden)."""
         d, dt = self.dims, self.dtype
         heads, kv = d.num_attention_heads, d.num_key_value_heads
-        width = d.hidden_size // heads
+        width = d.head_dim or d.hidden_size // heads
         init = _fan_in_normal
         wq = self.param("wq", init, (d.hidden_size, heads * width))
         wk = self.param("wk", init, (d.hidden_size, kv * width))
@@ -187,13 +225,80 @@ class GQA(nn.Module):
         probs = jax.nn.softmax(scores, axis=-1)                 # float32
         out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(dt), v,
                          preferred_element_type=jnp.float32).astype(dt)
-        return _dot(out.reshape(b, s, heads * width), wo, dt)
+        out = out.reshape(b, s, heads * width)
+        if d.use_gqa_gate:
+            wg = self.param("wg", init, (d.hidden_size, heads * width))
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                _dot(h, wg, dt).astype(jnp.float32))).astype(dt)
+        return _dot(out, wo, dt)
+
+
+def l2_normalize(x, eps: float = 1e-6):
+    """x / sqrt(|x|^2 + eps) over the last axis, float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692), ``kda_num_heads`` heads of
+    ``kda_head_dim``: q, k, v by their own projections, each through a
+    causal depthwise conv of ``kda_short_conv_kernel_size`` taps (no bias)
+    and SiLU, q and k then of unit length; a decay per channel
+    ``g = -exp(A_log) softplus(h W_fa W_fb + dt_bias)`` (``A_log`` a head,
+    ``dt_bias`` a channel; a low-rank pair of ``kda_proj_rank``); ``beta =
+    2 sigmoid(h W_b)`` a head (``kda_allow_neg_eigval``); the rule
+    (``ops/kda.py``) at scale ``kda_head_dim^-1/2``; an RMSNorm over each
+    head's ``kda_head_dim`` outputs (one weight shared by the heads) times
+    ``sigmoid(h W_ga W_gb)``, element by element; the output projection."""
+    dims: Any
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        """u (B, S, hidden) -> (B, S, hidden)."""
+        d, dt = self.dims, self.dtype
+        heads, width, rank = d.kda_num_heads, d.kda_head_dim, d.kda_proj_rank
+        inner, taps = heads * width, d.kda_short_conv_kernel_size
+        init = _fan_in_normal
+        conv_init = nn.initializers.normal(taps ** -0.5)
+        b, s, _ = u.shape
+
+        def branch(name):
+            """The projection ``w<name>``, its conv and SiLU -> (B, S,
+            heads, width) float32."""
+            w = self.param(f"w{name}", init, (d.hidden_size, inner))
+            conv = self.param(f"conv_{name}", conv_init, (inner, taps))
+            out = nn.silu(causal_conv(_dot(u, w, dt), conv, None))
+            return out.reshape(b, s, heads, width)
+
+        q, k, v = (branch(name) for name in ("q", "k", "v"))
+        q, k = l2_normalize(q), l2_normalize(k)
+        low = _dot(_dot(u, self.param("f_a", init, (d.hidden_size, rank)), dt),
+                   self.param("f_b", init, (rank, inner)), dt)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (inner,))
+        a_log = self.param("A_log", nn.initializers.zeros, (heads,))
+        g = (-jnp.exp(a_log.astype(jnp.float32))[:, None]
+             * jax.nn.softplus(low.astype(jnp.float32)
+                               + dt_bias.astype(jnp.float32)
+                               ).reshape(b, s, heads, width))
+        beta = 2.0 * jax.nn.sigmoid(_dot(u, self.param(
+            "b", init, (d.hidden_size, heads)), dt).astype(jnp.float32))
+        o = kda_chunked(q.astype(dt), k.astype(dt), v.astype(dt), g, beta,
+                        chunk=d.kda_chunk_size, scale=width ** -0.5)
+        o = RMSNorm(d.rms_norm_eps, jnp.float32, name="norm")(o)
+        gate = _dot(_dot(u, self.param("g_a", init, (d.hidden_size, rank)),
+                         dt), self.param("g_b", init, (rank, inner)), dt)
+        o = o.reshape(b, s, inner) * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return _dot(o, self.param("wo", init, (inner, d.hidden_size)), dt)
 
 
 def route(h, w_router, d):
     """Every token's choice over ALL routed experts.  h (T, hidden) ->
-    (experts (T, k) int32, weights (T, k) float32): logits in float32,
-    the k largest of them, a softmax over those k alone."""
+    (experts (T, k) int32, weights (T, k) float32).  ``softmax``: logits
+    in float32, the k largest of them, a softmax over those k alone;
+    ``sigmoid``: ``text_lm.route``."""
+    if d.scoring_func == "sigmoid":
+        return text_lm.route(h, w_router, d)
     logits = jnp.dot(h, w_router.astype(h.dtype),
                      preferred_element_type=jnp.float32)
     top, experts = lax.top_k(logits, d.num_experts_per_tok)
@@ -233,6 +338,15 @@ class RoutedExperts(nn.Module):
                            tile_rows]))
 
 
+def chunk_counts(real, chunk: int):
+    """(row x chunk blocks a scan computes over ``real`` (B, S), those of
+    them that hold a real token) int32."""
+    per_row = -(-real.shape[1] // chunk)
+    return jnp.stack([
+        jnp.int32(real.shape[0] * per_row),
+        jnp.sum(-(-jnp.sum(real, axis=1) // chunk)).astype(jnp.int32)])
+
+
 class Layer(nn.Module):
     dims: Any
     kind: str
@@ -243,15 +357,15 @@ class Layer(nn.Module):
         d, dt = self.dims, self.dtype
         r = d.residual_multiplier
         h = RMSNorm(d.rms_norm_eps, dt, name="mixer_norm")(x)
-        chunks = jnp.zeros((2,), jnp.int32)
+        chunks = kda_chunks = jnp.zeros((2,), jnp.int32)
         if self.kind == "mamba":
             with jax.named_scope("text_hybrid/mamba"):
                 x = x + r * Mamba2Mixer(d, dt, name="mamba")(h)
-            per_row = -(-x.shape[1] // d.mamba_chunk_size)
-            chunks = jnp.stack([
-                jnp.int32(x.shape[0] * per_row),
-                jnp.sum(-(-jnp.sum(real, axis=1) // d.mamba_chunk_size))
-                .astype(jnp.int32)])
+            chunks = chunk_counts(real, d.mamba_chunk_size)
+        elif self.kind == "kda":
+            with jax.named_scope("text_hybrid/kda"):
+                x = x + r * KDAMixer(d, dt, name="kda")(h)
+            kda_chunks = chunk_counts(real, d.kda_chunk_size)
         else:
             with jax.named_scope("text_hybrid/attn"):
                 x = x + r * GQA(d, dt, name="attn")(h, visible)
@@ -261,7 +375,9 @@ class Layer(nn.Module):
                               name="shared")(h)
         with jax.named_scope("text_hybrid/moe"):
             routed, pairs = RoutedExperts(d, dt, name="moe")(h, real)
-        self.sow(COUNTERS, "layer", jnp.concatenate([pairs, chunks]))
+        blocks = [pairs, chunks] + ([kda_chunks] if "kda" in d.layer_types
+                                    else [])
+        self.sow(COUNTERS, "layer", jnp.concatenate(blocks))
         return x + r * (shared + routed)
 
 

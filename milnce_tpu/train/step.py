@@ -562,7 +562,8 @@ def make_text_embed_fn(model, mesh: Mesh, data_axis: str = "data"):
     A language-model tower is its own program — ``text_lm_tower``
     (``model.text_lm``), ``text_hybrid_tower`` (``model.text_hybrid``) or
     ``text_dlm_tower`` (``model.text_dlm``) — and returns beside the embeddings its layers' counters, name -> int32
-    scalar over all the data shards (the tower's ``COUNTER_NAMES``)."""
+    scalar over all the data shards (the tower's ``counter_names(dims)``
+    where it has one, else its ``COUNTER_NAMES``)."""
     kind = next((k for k in ("text_lm", "text_hybrid", "text_dlm")
                  if getattr(model, k, None) is not None), None)
     if kind is not None:
@@ -570,8 +571,10 @@ def make_text_embed_fn(model, mesh: Mesh, data_axis: str = "data"):
 
         from milnce_tpu.models.text_lm import COUNTERS, sum_counters
 
-        names = importlib.import_module(
-            f"milnce_tpu.models.{kind}").COUNTER_NAMES
+        tower_module = importlib.import_module(f"milnce_tpu.models.{kind}")
+        names = (tower_module.counter_names(getattr(model, kind))
+                 if hasattr(tower_module, "counter_names")
+                 else tower_module.COUNTER_NAMES)
 
         def tower(variables, text_ids):
             emb, sown = model.apply(variables, None, text_ids, mode="text",

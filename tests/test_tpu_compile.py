@@ -412,6 +412,76 @@ def test_granite4h_sentence_tower_fits_beside_what_the_cell_holds(topo):
         f"{scores / 1e9:.2f} GB")
 
 
+def test_solar2_sentence_tower_fits_beside_what_the_cell_holds(topo):
+    """``make_text_embed_fn``'s program (``text_hybrid_tower``) for the
+    ``text_hybrid`` group that ``benchmarks/drivers/serve_tower.py`` makes
+    from ``benchmarks/configs/s3dg-solar2-text-32f224.json`` — hidden
+    4096, one gated NoPE attention layer (64 query heads over 8, heads of
+    128) and three Kimi Delta Attention layers (64 heads of 128, chunks of
+    64), a 320-wide sigmoid router, 40 experts of 1280 held beside a
+    shared one, the whole 196,608-row table, bfloat16 — at the 16-row rung
+    of 512 tokens.  It compiles for a described v5e (the delta rule is
+    ``ops/kda.py``'s chunked form, the expert products
+    ``ops/grouped_matmul.py``'s kernel), and its arguments and
+    temporaries, with the index shard and two (16, rows) float32 score
+    blocks, lie inside the chip's ``bytes_limit``; at boot its weights and
+    the index stand beside the video tower's warm-up."""
+    from benchmarks import harness
+    from benchmarks.drivers import serve_tower
+    from milnce_tpu.config import parse_cli
+    from milnce_tpu.models import text_hybrid
+    from milnce_tpu.models.build import build_model
+    from milnce_tpu.train.step import make_text_embed_fn
+
+    cell = harness.load_json("benchmarks/configs/s3dg-solar2-text-32f224.json")
+    cfg = parse_cli(serve_tower.group_flags(cell) + [
+        "--model.text_tower", "hybrid", "--model.dtype", "bfloat16"])
+    rows, words = cell["serve"]["max_batch"], cell["data"]["max_words"]
+    group = cfg.text_hybrid
+    assert (group.hidden_size, group.num_local_experts, group.experts_held,
+            group.num_hidden_layers, group.kda_num_heads, group.head_dim,
+            rows, words) == (4096, 320, 40, 4, 64, 128, 16, 512)
+    model = build_model(cfg.model, text_hybrid=group)
+    assert model.text_hybrid.layer_types == ("attention", "kda", "kda",
+                                             "kda")
+    tower = text_hybrid.TextHybrid(model.text_hybrid, embd_dim=512,
+                                   dtype=jnp.bfloat16)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    repl, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    shapes = jax.eval_shape(tower.init, jax.random.PRNGKey(0),
+                            jnp.zeros((rows, words), jnp.int32))["params"]
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=repl),
+        {"text_module": shapes})
+    held = sum(s.size for s in jax.tree_util.tree_leaves(shapes))
+    assert held == 3_914_428_992            # 7.83 GB
+    compiled = make_text_embed_fn(model, mesh).lower(
+        {"params": params},
+        jax.ShapeDtypeStruct((rows, words), jnp.int32,
+                             sharding=data)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    for scope in cell["bench"]["scopes"]:
+        assert scope in text, scope
+    assert "text_hybrid_tower" in text
+    mem = compiled.memory_analysis()
+    need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    limit = 16_909_336_064
+    index = cell["index"]["rows"] * cell["index"]["dim"] * 4
+    scores = 2 * rows * cell["index"]["rows"] * 4
+    video_warm_up = 4.95e9 + 0.37e9
+    print(f"solar2 tower: {held} parameters, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert need + index + scores < limit, (
+        f"tower {need / 1e9:.2f} GB + index {index / 1e9:.2f} GB + scores "
+        f"{scores / 1e9:.2f} GB")
+    assert mem.argument_size_in_bytes + index + video_warm_up < limit, (
+        f"tower's weights {mem.argument_size_in_bytes / 1e9:.2f} GB and the "
+        "index beside the video tower's warm-up")
+
+
 # --------------------------------------------------------------------------
 # the block-diffusion sentence tower at its published widths, top rung
 # --------------------------------------------------------------------------
