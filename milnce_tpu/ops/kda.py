@@ -1,5 +1,5 @@
 """Kimi Delta Attention's rule (the gated delta rule with a decay per
-channel), computed in chunks.
+channel), computed in chunks by one Pallas kernel.
 
 Per row and head, with ``q_t``, ``k_t`` in R^K (unit length), ``v_t`` in
 R^V, a decay ``alpha_t = exp(g_t)`` in (0, 1]^K, ``beta_t`` in (0, 2) and a
@@ -27,119 +27,219 @@ passes e^-88.  So the chunk is cut into sub-blocks of ``SUB`` positions
 (secondary chunking): for ``i`` in sub-block ``b`` and ``j`` in an earlier
 one, ``exp(G_i - G_j) = exp(G_i - R_b) exp(R_b - G_j)`` with ``R_b`` the
 log-decay just before ``b`` starts, both factors at most 1, and the sum is
-one product; inside a sub-block the factor is taken pair by pair
-(``sub x sub x K`` a sub-block, never ``C x C x K``).  No exponent this
-module takes is positive: a decay of e^-1000 inside a chunk gives zeros,
-not inf or NaN.
+one product; inside a sub-block the factor is taken pair by pair, one
+diagonal ``j = i - d`` at a time (``C x K`` a diagonal, never ``C x C x
+K``).  No exponent this module takes is positive: a decay of e^-1000 inside
+a chunk gives zeros, not inf or NaN.
 
-Decays, the state, the triangular solve and every product here are float32
-(``Precision.HIGHEST``); q, k, v may come in bfloat16.  The chunks run in a
-``lax.scan``, so a chunk's temporaries are all that is held at once.  A
-sequence that is no multiple of the chunk is padded inside with ``g = 0``,
-``beta = 0`` and zero vectors: the state passes through them unchanged.
-Right padding of a row needs no mask: no real position reads a later one.
+The kernel: the grid walks (row, block of heads, chunk), the chunks of a
+(row, head) in order, and the K x V state stays in VMEM scratch from the
+first chunk to the last; a grid step reads its chunk's q, k, v, g and beta
+tiles and writes its output tile once.  ``G``, ``A``, ``P``, the decays and
+the solve live in VMEM.  The unit-lower system is solved through the
+inverse of ``I + A``, built by doubling: the inverse ``T`` of the diagonal
+blocks of size ``s`` gives that of size ``2s`` as ``T - T A_s T``, with
+``A_s`` the entries of ``A`` that join the two halves of a ``2s`` block
+(the block form of forward substitution; no power series).
+
+Decays, the state, the solve and every product here are float32
+(``Precision.HIGHEST``); q, k, v may come in bfloat16.  A sequence that is
+no multiple of the chunk is padded with ``g = 0``, ``beta = 0`` and zero
+vectors: the state passes through them unchanged.  Right padding of a row
+needs no mask: no real position reads a later one.  Forward only: nothing
+takes a gradient through it, and asking for one raises.  On the CPU the
+kernel runs in Pallas interpret mode (ops/pallas_mode.py).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from milnce_tpu.ops import pallas_mode
 
 SCOPE = "text_hybrid/kda_scan"
 SUB = 16                # positions a sub-block (the secondary chunking)
 HIGHEST = lax.Precision.HIGHEST
+HEADS_A_STEP = 4        # heads a grid step, where they divide the heads
 
 
 def kda_chunked(q, k, v, g, beta, *, chunk: int, scale: float):
     """q, k (R, S, H, K) unit rows; v (R, S, H, V); g (R, S, H, K)
     float32, at most 0 (the log of the decay); beta (R, S, H) -> o (R, S,
     H, V) float32.  ``chunk`` a multiple of :data:`SUB` (or below it)."""
+    if chunk % min(SUB, chunk):
+        raise ValueError(f"kda_chunked: chunk {chunk} is no multiple of "
+                         f"the sub-block {SUB}")
+    s = q.shape[1]
+    pad = -s % chunk
     with jax.named_scope(SCOPE):
-        return _kda(q, k, v, g, beta, chunk, scale, min(SUB, chunk))
+        if pad:
+            q, k, v, g, beta = (
+                jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                for t in (q, k, v, g, beta))
+        return _rule(q, k, v, g, beta, chunk, float(scale))[:, :s]
 
 
-def _mm(spec, a, b):
-    return jnp.einsum(spec, a, b, precision=HIGHEST,
-                      preferred_element_type=jnp.float32)
-
-
-def _pairs(G, kc, qc, chunk: int, sub: int):
-    """-> (A without beta, P) (R, H, C, C) of one chunk: ``A`` strictly
-    lower, ``P`` lower with its diagonal (module docstring)."""
-    at = jnp.arange(sub)
-    incl = at[:, None] >= at[None, :]
-    strict = (at[:, None] > at[None, :]).astype(jnp.float32)
-    a_rows, p_rows = [], []
-    for first in range(0, chunk, sub):
-        last = first + sub
-        gb, kb, qb = G[..., first:last, :], kc[..., first:last, :], \
-            qc[..., first:last, :]
-        # inside the sub-block, pair by pair: exp(G_i - G_j), j <= i
-        e = jnp.exp(jnp.where(incl[:, :, None],
-                              gb[..., :, None, :] - gb[..., None, :, :],
-                              -jnp.inf))
-        a_in = jnp.sum(kb[..., :, None, :] * kb[..., None, :, :] * e,
-                       axis=-1) * strict
-        p_in = jnp.sum(qb[..., :, None, :] * kb[..., None, :, :] * e,
-                       axis=-1)
-        a_parts, p_parts = [a_in], [p_in]
-        if first:
-            # the earlier sub-blocks, through the log-decay just before
-            # this one: both factors at most 1
-            ref = G[..., first - 1:first, :]
-            left = jnp.exp(gb - ref)
-            right = kc[..., :first, :] * jnp.exp(ref - G[..., :first, :])
-            a_parts.insert(0, _mm("...ik,...jk->...ij", kb * left, right))
-            p_parts.insert(0, _mm("...ik,...jk->...ij", qb * left, right))
-        if last < chunk:
-            zeros = jnp.zeros(a_in.shape[:-1] + (chunk - last,),
-                              jnp.float32)
-            a_parts.append(zeros)
-            p_parts.append(zeros)
-        a_rows.append(jnp.concatenate(a_parts, axis=-1))
-        p_rows.append(jnp.concatenate(p_parts, axis=-1))
-    return jnp.concatenate(a_rows, axis=-2), jnp.concatenate(p_rows, axis=-2)
-
-
-def _kda(q, k, v, g, beta, chunk: int, scale: float, sub: int):
+@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, chunk, scale):
+    """The kernel over sequences padded to a multiple of ``chunk``."""
     rows, s, heads, dk = q.shape
     dv = v.shape[-1]
-    if chunk % sub:
-        raise ValueError(f"kda_chunked: chunk {chunk} is no multiple of "
-                         f"the sub-block {sub}")
-    pad = -s % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, g, beta))
-    n = (s + pad) // chunk
+    sub = min(SUB, chunk)
+    n = s // chunk
+    step = max(d for d in range(1, HEADS_A_STEP + 1) if heads % d == 0)
 
-    def chunks(t):
-        """(R, S, H, ...) -> (N, R, H, C, ...)."""
-        t = t.reshape((rows, n, chunk) + t.shape[2:])
-        return jnp.moveaxis(t, (1, 3), (0, 2))
+    def flat(t):
+        """(R, S, H, W) -> (R, S, H W): a head's tile is a block of W
+        lanes."""
+        return t.reshape(rows, s, heads * t.shape[-1])
 
-    def step(state, xs):
-        qc, kc, vc, gc, bc = (t.astype(jnp.float32) for t in xs)
-        G = jnp.cumsum(gc, axis=-2)                         # (R, H, C, K)
-        a, p = _pairs(G, kc, qc, chunk, sub)
-        a = a * bc[..., :, None]
+    def tile(width):
+        return pl.BlockSpec((1, chunk, step * width),
+                            lambda r, h, c: (r, c, h))
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, chunk=chunk, sub=sub, scale=scale,
+                          step=step, dk=dk, dv=dv),
+        out_shape=jax.ShapeDtypeStruct((rows, s, heads * dv),
+                                       jnp.float32),
+        grid=(rows, heads // step, n),
+        in_specs=[tile(dk), tile(dk), tile(dv), tile(dk),
+                  pl.BlockSpec((1, chunk, heads), lambda r, h, c: (r, c, 0))],
+        out_specs=tile(dv),
+        scratch_shapes=[pltpu.VMEM((step, dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=rows * heads * n * _chunk_flops(chunk, dk, dv, sub),
+            transcendentals=rows * heads * n * chunk * dk
+            * (sub + 2 * (chunk // sub) + 1),
+            bytes_accessed=sum(t.size * t.dtype.itemsize
+                               for t in (q, k, v, g, beta))
+            + rows * s * heads * dv * 4),
+        name="kda_chunked",
+        interpret=pallas_mode.interpret(),
+    )(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)),
+      beta.astype(jnp.float32))
+    return out.reshape(rows, s, heads, dv)
+
+
+@_rule.defjvp
+def _forward_only(chunk, scale, primals, tangents):
+    raise NotImplementedError(
+        "kda_chunked is forward only: no gradient is defined through the "
+        "delta rule's kernel")
+
+
+def _chunk_flops(c: int, dk: int, dv: int, sub: int) -> int:
+    """The kernel's products over one chunk of one head, 2 FLOPs a
+    multiply-add: the sub-blocks' cross terms of A and P, the inverse by
+    doubling, [W | U~], the two products with the state, P U and the new
+    state."""
+    doublings = max(c - 1, 0).bit_length() - 1
+    return 2 * ((c // sub - 1) * 2 * c * c * dk + doublings * 2 * c ** 3
+                + c * c * (dk + dv) + 2 * c * dk * dv + c * c * dv
+                + c * dk * dv)
+
+
+def _mm(a, b, contract=((1,), (0,))):
+    return lax.dot_general(a, b, (contract, ((), ())), precision=HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, state, *, chunk, sub,
+            scale, step, dk, dv):
+    """One chunk of ``step`` heads of one row; ``state`` holds each head's
+    S^T (V x K) from the chunk before."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, jnp.float32)
+
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    pos = lax.broadcasted_iota(jnp.int32, (chunk, dk), 0)
+    # the sub-block of a position and its place in it, without a division
+    block = sum((pos >= first).astype(jnp.int32)
+                for first in range(sub, chunk, sub))
+    at = pos - sub * block
+    lane = lax.broadcasted_iota(jnp.int32, b_ref.shape[1:], 1)
+    head0 = pl.program_id(1) * step
+    for j in range(step):
+        q = q_ref[0, :, j * dk:(j + 1) * dk].astype(jnp.float32)
+        k = k_ref[0, :, j * dk:(j + 1) * dk].astype(jnp.float32)
+        v = v_ref[0, :, j * dv:(j + 1) * dv].astype(jnp.float32)
+        beta = jnp.sum(jnp.where(lane == head0 + j, b_ref[0], 0.0), axis=1,
+                       keepdims=True)                            # (C, 1)
+        G = _cumsum(g_ref[0, :, j * dk:(j + 1) * dk], pos, chunk)  # (C, K)
+        a, p = _pairs(G, k, q, row, col, block, at, chunk, sub)
+        t = _unit_lower_inverse(a * beta, row, col, chunk)
         decayed = jnp.exp(G)
-        rhs = bc[..., None] * jnp.concatenate([kc * decayed, vc], axis=-1)
-        solved = lax.linalg.triangular_solve(
-            a, rhs, left_side=True, lower=True, unit_diagonal=True)
-        w, u = solved[..., :dk], solved[..., dk:]
-        u = u - _mm("...ck,...kv->...cv", w, state)
-        out = scale * (_mm("...ck,...kv->...cv", qc * decayed, state)
-                       + _mm("...ij,...jv->...iv", p, u))
-        whole = G[..., -1:, :]                              # (R, H, 1, K)
-        state = (jnp.exp(whole)[..., 0, :, None] * state
-                 + _mm("...ck,...cv->...kv", kc * jnp.exp(whole - G), u))
-        return state, out
+        wu = _mm(t, beta * jnp.concatenate([k * decayed, v], axis=1))
+        w, u = wu[:, :dk], wu[:, dk:]
+        s_t = state[j]                                           # (V, K)
+        by_state = _mm(jnp.concatenate([w, q * decayed], axis=0), s_t,
+                       ((1,), (1,)))                             # (2C, V)
+        u = u - by_state[:chunk]
+        o = by_state[chunk:] + _mm(p, u)
+        o_ref[0, :, j * dv:(j + 1) * dv] = scale * o
+        whole = G[chunk - 1:chunk, :]                            # (1, K)
+        state[j] = (s_t * jnp.exp(whole)
+                    + _mm(u, k * jnp.exp(whole - G), ((0,), (0,))))
 
-    zero = jnp.zeros((rows, heads, dk, dv), jnp.float32)
-    _, out = lax.scan(step, zero, tuple(chunks(t) for t in (q, k, v, g,
-                                                             beta)))
-    out = jnp.moveaxis(out, (0, 2), (1, 3)).reshape(rows, s + pad, heads, dv)
-    return out[:, :s]
+
+def _cumsum(g, pos, chunk: int):
+    """Inclusive sum over the chunk's positions (rows), by doubling."""
+    d = 1
+    while d < chunk:
+        g = g + jnp.where(pos >= d, pltpu.roll(g, d, 0), 0.0)
+        d *= 2
+    return g
+
+
+def _pairs(G, k, q, row, col, block, at, chunk: int, sub: int):
+    """-> (A without beta, P) (C, C) of one chunk: ``A`` strictly lower,
+    ``P`` lower with its diagonal (module docstring)."""
+    a = jnp.zeros((chunk, chunk), jnp.float32)
+    p = jnp.zeros((chunk, chunk), jnp.float32)
+    # inside a sub-block, pair by pair: the diagonal j = i - d at once,
+    # exp(G_i - G_j) of the pairs that share a sub-block
+    for d in range(sub):
+        kd = k if d == 0 else pltpu.roll(k, d, 0)
+        gd = G if d == 0 else pltpu.roll(G, d, 0)
+        e = kd * jnp.exp(jnp.where(at >= d, G - gd, -jnp.inf))
+        on = col == row - d
+        p = jnp.where(on, jnp.sum(q * e, axis=1, keepdims=True), p)
+        if d:
+            a = jnp.where(on, jnp.sum(k * e, axis=1, keepdims=True), a)
+    # the earlier sub-blocks, through the log-decay just before this one:
+    # both factors at most 1
+    for b in range(1, chunk // sub):
+        ref = G[b * sub - 1:b * sub, :]
+        left = jnp.exp(jnp.where(block == b, G - ref, -jnp.inf))
+        right = k * jnp.exp(jnp.where(block < b, ref - G, -jnp.inf))
+        both = _mm(jnp.concatenate([k * left, q * left], axis=0), right,
+                   ((1,), (1,)))
+        a = a + both[:chunk]
+        p = p + both[chunk:]
+    return a, p
+
+
+def _unit_lower_inverse(a, row, col, chunk: int):
+    """(I + a)^-1 for ``a`` strictly lower (C, C), by doubling the size of
+    the diagonal blocks inverted: blocks of ``s`` (aligned, the last one
+    cut) to blocks of ``2s`` by ``T - T a_s T``, ``a_s`` the entries of
+    ``a`` joining the two halves of a block of ``2s``."""
+    apart = row ^ col            # a pair shares an aligned block of n iff < n
+    t = jnp.where(row == col, 1.0, jnp.where(apart < 2, -a, 0.0))
+    s = 2
+    while s < chunk:
+        joins = jnp.where((apart >= s) & (apart < 2 * s), a, 0.0)
+        t = t - _mm(t, _mm(joins, t))
+        s *= 2
+    return t

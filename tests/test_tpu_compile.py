@@ -279,6 +279,37 @@ def test_the_top_k_program_ranks_without_a_topk_over_the_shard(topo):
 
 
 # --------------------------------------------------------------------------
+# the delta rule's kernel at the Solar cell's shapes
+# --------------------------------------------------------------------------
+
+def _kda_kernels(text):
+    """The delta rule's custom calls in a compiled program's text."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "kda_chunked" in line]
+
+
+def test_kda_kernel_compiles_at_the_cells_shapes(one_chip):
+    """``ops/kda.py`` at what a KDA layer of ``query-step-solar2-c32``
+    hands it (16 rows x 512 slots, 64 heads of 128, chunks of 64; q, k, v
+    in bfloat16): Mosaic takes the kernel with its state in VMEM scratch,
+    the call keeps the scope the benchmark reads, and no XLA
+    ``triangular-solve`` is left."""
+    from milnce_tpu.ops.kda import SCOPE, kda_chunked
+
+    rows, slots, heads, width = 16, 512, 64, 128
+    _, text = _compile(
+        lambda q, k, v, g, b: kda_chunked(q, k, v, g, b, chunk=64,
+                                          scale=width ** -0.5),
+        *(_shape(one_chip, (rows, slots, heads, width), jnp.bfloat16),) * 3,
+        _shape(one_chip, (rows, slots, heads, width)),
+        _shape(one_chip, (rows, slots, heads)))
+    (kernel,) = _kda_kernels(text)
+    assert SCOPE in kernel
+    assert "triangular-solve" not in text
+
+
+# --------------------------------------------------------------------------
 # the language-model sentence tower at its published widths, top rung
 # --------------------------------------------------------------------------
 
@@ -421,7 +452,7 @@ def test_solar2_sentence_tower_fits_beside_what_the_cell_holds(topo):
     64), a 320-wide sigmoid router, 40 experts of 1280 held beside a
     shared one, the whole 196,608-row table, bfloat16 — at the 16-row rung
     of 512 tokens.  It compiles for a described v5e (the delta rule is
-    ``ops/kda.py``'s chunked form, the expert products
+    ``ops/kda.py``'s kernel, one a layer, the expert products
     ``ops/grouped_matmul.py``'s kernel), and its arguments and
     temporaries, with the index shard and two (16, rows) float32 score
     blocks, lie inside the chip's ``bytes_limit``; at boot its weights and
@@ -464,6 +495,14 @@ def test_solar2_sentence_tower_fits_beside_what_the_cell_holds(topo):
     for scope in cell["bench"]["scopes"]:
         assert scope in text, scope
     assert "text_hybrid_tower" in text
+    # the delta rule is one kernel a KDA layer, found by the scope the
+    # benchmark's kda_scan_roofline reads; no XLA solve is left
+    kernels = _kda_kernels(text)
+    assert len(kernels) == 3
+    assert all('op_name="' in line and "text_hybrid/kda_scan" in
+               line.split('op_name="', 1)[1].split('"', 1)[0]
+               for line in kernels)
+    assert "triangular-solve" not in text
     mem = compiled.memory_analysis()
     need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
